@@ -10,7 +10,7 @@
 # may not ship them); a missing tool is never a failure.
 set -u
 
-TARGETS="pyrecover_tpu tests tools bench.py __graft_entry__.py"
+TARGETS="pyrecover_tpu tests tools bench.py chip_smoke.py __graft_entry__.py"
 ISORT_ARGS=""
 BLACK_ARGS=""
 if [ "${1:-}" = "--check" ]; then
@@ -40,7 +40,7 @@ fi
 # CI gate that keeps host syncs / PRNG reuse / donation bugs out of the hot
 # path. The JSON report (path overridable via JAXLINT_JSON) gives CI tooling
 # the same machine-readable surface as tools/summarize_telemetry.py.
-python tools/jaxlint.py pyrecover_tpu tools bench.py __graft_entry__.py \
+python tools/jaxlint.py pyrecover_tpu tools bench.py chip_smoke.py __graft_entry__.py \
   --strict --json "${JAXLINT_JSON:-/tmp/jaxlint_report.json}" || rc=1
 
 # concur: static concurrency-safety analysis (pyrecover_tpu/analysis/concur
@@ -52,7 +52,7 @@ python tools/jaxlint.py pyrecover_tpu tools bench.py __graft_entry__.py \
 # lock/emit-free (CC04), daemon writers that own durable commits are
 # joined (CC05), collectives stay pinned to the calling thread (CC06).
 # JSON report beside the jaxlint one (CONCUR_JSON).
-python tools/concur.py pyrecover_tpu tools bench.py __graft_entry__.py \
+python tools/concur.py pyrecover_tpu tools bench.py chip_smoke.py __graft_entry__.py \
   --strict --json "${CONCUR_JSON:-/tmp/concur_report.json}" || rc=1
 
 # distcheck: static multi-host collective-congruence analysis
@@ -65,7 +65,7 @@ python tools/concur.py pyrecover_tpu tools bench.py __graft_entry__.py \
 # exceptions (DC04), every raw multihost wait bounded by a
 # collective_phase (DC05), collective trip counts never driven by
 # host-local state (DC06). JSON report beside the others (DISTCHECK_JSON).
-python tools/distcheck.py pyrecover_tpu tools bench.py __graft_entry__.py \
+python tools/distcheck.py pyrecover_tpu tools bench.py chip_smoke.py __graft_entry__.py \
   --strict --json "${DISTCHECK_JSON:-/tmp/distcheck_report.json}" || rc=1
 
 # obscheck: static observability-contract analysis
@@ -78,7 +78,7 @@ python tools/distcheck.py pyrecover_tpu tools bench.py __graft_entry__.py \
 # tables — catalogs in agreement with each other (OB04), no unconditional
 # emits on the training hot path (OB05), and every consumed metric series
 # registered (OB06). JSON report beside the others (OBSCHECK_JSON).
-python tools/obscheck.py pyrecover_tpu tools bench.py __graft_entry__.py \
+python tools/obscheck.py pyrecover_tpu tools bench.py chip_smoke.py __graft_entry__.py \
   --strict --json "${OBSCHECK_JSON:-/tmp/obscheck_report.json}" || rc=1
 
 # faultcheck: static crash-consistency & fault-coverage analysis
@@ -91,7 +91,7 @@ python tools/obscheck.py pyrecover_tpu tools bench.py __graft_entry__.py \
 # error-path resource leaks on pool blocks / pin leases / subprocesses
 # (FT05), no recovery-path exception swallows (FT06). JSON report beside
 # the others (FAULTCHECK_JSON).
-python tools/faultcheck.py pyrecover_tpu tools bench.py __graft_entry__.py \
+python tools/faultcheck.py pyrecover_tpu tools bench.py chip_smoke.py __graft_entry__.py \
   --strict --json "${FAULTCHECK_JSON:-/tmp/faultcheck_report.json}" || rc=1
 
 # shardcheck: abstract SPMD preflight (pyrecover_tpu/analysis/shardcheck).

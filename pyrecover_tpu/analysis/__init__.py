@@ -5,7 +5,7 @@ and speed hinge on: no hidden host↔device syncs inside the hot loop, no
 PRNG key reuse, no reads of donated buffers, no Python branching on
 traced values or side effects under ``jit``, no unhashable static args,
 no timing spans that measure async dispatch instead of device work, no
-legacy jax spellings that bypass the ``utils/compat.py`` shims, and no
+legacy or private jax spellings, and no
 ``PartitionSpec`` literals naming axes outside the mesh catalog. This
 package codifies them as machine-checked rules. (The semantic layer —
 validating a whole launch configuration abstractly — is the

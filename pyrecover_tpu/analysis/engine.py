@@ -128,8 +128,6 @@ class LintConfig:
          "extend", "update", "join", "wait", "copy", "clear", "emit",
          "reset", "send", "next", "run"}
     )
-    # path suffixes exempt from the legacy-spelling rule (the shim home)
-    compat_exempt: tuple = ("utils/compat.py",)
     # the mesh axis catalog (values of the AXIS_* constants in
     # parallel/mesh.py — mirrored here because the lint engine must stay
     # importable without jax; pinned together by tests/test_jaxlint.py).

@@ -839,14 +839,14 @@ def check_torn_write(module, ctx):
     return out
 
 
-# ---- JX08: legacy jax spellings that bypass utils/compat.py -----------------
+# ---- JX08: legacy/private jax spellings -------------------------------------
+# One installation (ENV_LOCK.txt pins jax 0.9.0): the public spellings all
+# exist, so there is no shim layer and nothing is exempt.
 
 _LEGACY_MODULES = {
-    "jax.experimental.shard_map":
-        "use jax.shard_map — utils/compat.py guarantees it on jax 0.4.x",
+    "jax.experimental.shard_map": "use jax.shard_map",
     "jax.experimental.maps":
-        "the maps/xmap surface is retired; use jax.shard_map via "
-        "utils/compat.py",
+        "the maps/xmap surface is retired; use jax.shard_map",
     "jax.experimental.pjit":
         "pjit is jax.jit now; sharding comes from the mesh context",
 }
@@ -854,12 +854,9 @@ _LEGACY_MODULES = {
 
 @rule(
     "JX08", "legacy-jax-spelling", "error",
-    "legacy/private jax spelling that bypasses the utils/compat.py shims",
+    "legacy or private jax spelling where the installed jax has a public one",
 )
 def check_legacy_spelling(module, ctx):
-    rel = str(module.relpath).replace("\\", "/")
-    if any(rel.endswith(suffix) for suffix in ctx.config.compat_exempt):
-        return []
     out = []
     r = RULES["legacy-jax-spelling"]
 
@@ -870,7 +867,7 @@ def check_legacy_spelling(module, ctx):
         if name == "jax._src" or name.startswith("jax._src."):
             return (
                 "jax._src is private API with no stability guarantee — "
-                "wrap it in a utils/compat.py shim (and pin it with a test)"
+                "use the public spelling"
             )
         return None
 

@@ -1,14 +1,20 @@
 """ctypes binding for the native checkpoint-I/O engine (native/pyrecover_io.cpp).
 
-Auto-builds the shared library with g++ on first use (single translation
-unit, ~1 s) and degrades gracefully: every caller must handle
-``available() == False`` (no compiler / unsupported platform), in which case
-the pure-Python hashlib path in ``vanilla.py`` is used. The binding is
-kept ctypes-only so no build step is required at install time (pybind11 is
-deliberately not a dependency).
+Builds the shared library with g++ on first use (single translation unit,
+~1 s) into the git-ignored ``native/build/`` — the library is never
+committed. Staleness is keyed on the SOURCE'S CONTENT (a digest in the
+library's file name), not on mtimes: after a copy or checkout both
+mtimes are arbitrary, and an mtime rule would load a library built
+elsewhere from other source. Every caller must handle ``available() ==
+False`` (no compiler / unsupported platform), in which case the
+pure-Python hashlib path in ``vanilla.py`` is used — and the failed build
+is logged at WARNING once, with the compiler's output, so a slow save is
+never a silent one. The binding is kept ctypes-only so no build step is
+required at install time (pybind11 is deliberately not a dependency).
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,16 +28,35 @@ _tried = False
 
 _SRC = Path(__file__).resolve().parent.parent.parent / "native" / "pyrecover_io.cpp"
 _BUILD_DIR = _SRC.parent / "build"
-_SO = _BUILD_DIR / "libpyrecover_io.so"
 
 
-def _build():
+def _so_path():
+    """The library path for the CURRENT source: its content digest is in
+    the name, so a changed source never finds an old build."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _BUILD_DIR / f"libpyrecover_io.{digest}.so"
+
+
+def _build(so):  # faultcheck: tear-ok -- a build product, rebuilt on demand
     _BUILD_DIR.mkdir(parents=True, exist_ok=True)
+    # build to a private name, then rename: a concurrent process either
+    # sees no library (and builds its own) or a complete one
+    tmp = so.with_name(f".{so.name}.{os.getpid()}.tmp")
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
-        "-o", str(_SO), str(_SRC),
+        "-o", str(tmp), str(_SRC),
     ]
-    subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        # jaxlint: disable-next=torn-write -- the rename is for atomicity
+        # against a concurrent builder, not durability: a library lost to
+        # a crash is simply compiled again
+        os.replace(tmp, so)
+    finally:
+        tmp.unlink(missing_ok=True)
+    for stale in _BUILD_DIR.glob("libpyrecover_io*.so"):
+        if stale != so:
+            stale.unlink(missing_ok=True)
 
 
 def _load():
@@ -41,12 +66,15 @@ def _load():
             return _lib
         _tried = True
         try:
-            if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
-                # concur: disable-next=blocking-under-lock -- one-time lazy
-                # g++ build, guarded by exactly this lock to prevent a
-                # double compile; it completes before the first save can
-                _build()
-            lib = ctypes.CDLL(str(_SO))
+            # concur: disable-next=blocking-under-lock -- one-time lazy
+            # source digest + g++ build, guarded by exactly this lock to
+            # prevent a double compile; it completes before the first
+            # save can
+            so = _so_path()
+            if not so.exists():
+                # concur: disable-next=blocking-under-lock -- (as above)
+                _build(so)
+            lib = ctypes.CDLL(str(so))
             lib.pr_xxh64.restype = ctypes.c_uint64
             lib.pr_xxh64.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
             lib.pr_tree_hash.restype = ctypes.c_uint64
@@ -73,7 +101,21 @@ def _load():
                 ctypes.c_char_p, ctypes.POINTER(ctypes.c_int)
             ]
             _lib = lib
-        except Exception:
+        except Exception as e:
+            # once per process (_tried): saves fall back to the hashlib
+            # path, which is correct but slow — say so, with the reason
+            detail = getattr(e, "stderr", None) or b""
+            if isinstance(detail, bytes):
+                detail = detail.decode("utf-8", "replace")
+            from pyrecover_tpu.utils.logging import get_logger
+
+            get_logger().warning(
+                "native checkpoint-I/O engine unavailable (%s: %s); "
+                "checkpoints use the pure-Python hashing path%s",
+                type(e).__name__, e,
+                f" — compiler output:\n{detail.strip()[-2000:]}"
+                if detail.strip() else "",
+            )
             _lib = None
         return _lib
 

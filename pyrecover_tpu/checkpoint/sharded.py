@@ -28,6 +28,17 @@ from pyrecover_tpu.checkpoint.vanilla import CheckpointStructureError
 from pyrecover_tpu.resilience import faults
 from pyrecover_tpu.utils.logging import log_host0
 
+# No file of a checkpoint grows with the model: every array is cut into
+# chunks of at most CHUNK_BYTES and OCDBT starts a new data file once one
+# passes DATA_FILE_BYTES, so the largest file is about their sum (92 MiB
+# among the 107 files of the 7.6 GB llama-1b state). Orbax's defaults are
+# one chunk per array shard (587 MB for a stacked llama-1b FFN leaf) in
+# data files of 2 GiB, and a host with a file-size ceiling fails such a
+# write with EFBIG — as the vanilla engine's single 7.6 GB file did on the
+# machine that checks chip_smoke.py.
+DATA_FILE_BYTES = 64 * 1024 * 1024
+CHUNK_BYTES = 32 * 1024 * 1024
+
 
 def _params_leaf_digests(state):  # jaxlint: host-only
     """``{manifest path: BLAKE2b-128 hex}`` over the fully-addressable
@@ -101,7 +112,14 @@ class ShardedCheckpointer:
             self._ckptr.save(
                 path,
                 args=ocp.args.Composite(
-                    state=ocp.args.PyTreeSave(state),
+                    state=ocp.args.PyTreeSave(
+                        state,
+                        save_args=jax.tree_util.tree_map(
+                            lambda _: ocp.SaveArgs(chunk_byte_size=CHUNK_BYTES),
+                            state,
+                        ),
+                        ocdbt_target_data_file_size=DATA_FILE_BYTES,
+                    ),
                     meta=ocp.args.JsonSave(meta),
                 ),
                 force=True,

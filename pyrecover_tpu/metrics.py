@@ -122,7 +122,11 @@ class ThroughputMeter:
         tokens_per_sec = self._positions / dt
         flops = self.flop_per_token * self._positions
         tflops = flops / dt / 1e12
-        mfu = flops / dt / (self.peak_flops * self.n_devices) * 100.0
+        # unknown device kind -> no peak -> no MFU (None), never a number
+        mfu = (
+            flops / dt / (self.peak_flops * self.n_devices) * 100.0
+            if self.peak_flops else None
+        )
         training_pct = 100.0 * self._tokens / max(self._positions, 1)
         return {
             "tokens_per_sec": tokens_per_sec,
@@ -138,10 +142,11 @@ class ThroughputMeter:
         snap = self.snapshot()
         log_host0(
             "step %d | epoch %d | loss %.4f | %.0f tok/s (%.0f/chip) | "
-            "%.1f%% training tokens | %.2f TFLOP/s | MFU %.2f%%",
+            "%.1f%% training tokens | %.2f TFLOP/s | MFU %s",
             step, epoch, loss,
             snap["tokens_per_sec"], snap["tokens_per_sec_per_chip"],
-            snap["training_tokens_pct"], snap["tflops"], snap["mfu_pct"],
+            snap["training_tokens_pct"], snap["tflops"],
+            "n/a" if snap["mfu_pct"] is None else f"{snap['mfu_pct']:.2f}%",
         )
         self.reset()
         return snap

@@ -165,8 +165,7 @@ def moe_ffn(h, router_w, w1, w3, w2, config):
     choice = config.moe_dispatch
     if choice == "auto" and ep == 1:
         # Grouped ragged GEMMs whenever the expert axis is unsharded: the
-        # expert FFNs run as dense per-expert matmuls on the MXU — built to
-        # close the 34.5%-active-MFU shortfall BENCH_r03 exposed. The flat
+        # expert FFNs run as dense per-expert matmuls on the MXU. The flat
         # sort is batch-global, so on a sharded batch the shard-local
         # manual form is used instead (same math, sort/gather stay on-
         # shard; ep=1 degenerates its expert split away) — EXCEPT under

@@ -29,10 +29,13 @@ The only remaining fallback is a malformed GQA config (q heads not a
 multiple of kv heads), and it is LOUD (log_host0), never silent.
 
 Set ``PYRECOVER_PALLAS_INTERPRET=1`` to run in the Pallas interpreter
-(CPU tests — SURVEY §4's fake-backend role).
+(CPU tests — SURVEY §4's fake-backend role). On any other backend the
+variable is an ERROR: an interpreted kernel on a chip is a silent 100×
+slowdown under the kernel's name.
 """
 
 import functools
+import math
 import os
 
 import jax
@@ -40,6 +43,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from pyrecover_tpu.parallel.mesh import (
+    AXIS_DATA,
+    AXIS_FSDP,
+    AXIS_TENSOR,
+    nonmanual_axes,
+)
 
 NEG_INF = -1e30
 LANES = 128  # TPU lane width: scratch vectors are (bq, 128) replicated
@@ -50,7 +61,15 @@ LSE_LANES = 8
 
 
 def _interpret():
-    return os.environ.get("PYRECOVER_PALLAS_INTERPRET", "0") == "1"
+    on = os.environ.get("PYRECOVER_PALLAS_INTERPRET", "0") == "1"
+    backend = jax.default_backend()
+    if on and backend != "cpu":
+        raise RuntimeError(
+            f"PYRECOVER_PALLAS_INTERPRET=1 on the {backend!r} backend: "
+            "interpret mode is the CPU test aid; on an accelerator the "
+            "flash kernel must compile (unset the variable)"
+        )
+    return on
 
 
 def _score_mask(iq, ik, *, block_q, block_kv, causal, seq_q, seq_kv,
@@ -484,40 +503,42 @@ def _bwd(causal, scale, block_q, block_kv, res, g):
 
 # Per-device-kind default (block_q, block_kv) tilings, measured with
 # tools/bench_flash_blocks.py at the flagship bench shape (seq 2048,
-# head_dim 128, bf16, fwd+bwd). The v5e row is the r03/BENCH sweep result
-# (1024×1024 beats 512×512 by ~6% MFU at 1B); the other generations are
-# seeded from it scaled by their VMEM headroom — REPLACE a row by
-# re-running the sweep on that hardware, then pin it in
+# head_dim 128, bf16, fwd+bwd). The other generations are seeded from the
+# v5e row scaled by their VMEM headroom — REPLACE a row by re-running the
+# sweep on that hardware, then pin it in
 # tests/test_flash_attention.py::test_default_blocks_table. Matched by
 # substring against the lowered jax ``device_kind`` (the tpu_peak_flops
-# convention); unknown kinds get the conservative fallback.
+# convention); a kind the table does not know is an error, like a kind
+# the peak table does not know — not a default.
 DEFAULT_BLOCKS = {
     "v3": (256, 512),       # 16G HBM, small VMEM: conservative tiles
     "v4": (512, 1024),
-    "v5e": (1024, 1024),    # measured (bench_flash_blocks, r03 sweep)
+    "v5e": (1024, 1024),
     "v5litepod": (1024, 1024),
     "v5 lite": (1024, 1024),
     "v5p": (1024, 1024),
     "v6e": (1024, 2048),    # Trillium: 2× VMEM of v5e, deeper kv tiles
     "cpu": (512, 512),      # interpret mode — tile size is test speed
 }
-_FALLBACK_BLOCKS = (1024, 1024)  # the pre-table tuned default
 
 
 def default_blocks(device_kind=None):
     """``(block_q, block_kv)`` for a device kind (the local device's when
     None). Consumed by the model's attention builder whenever
-    ``flash_block_q/kv`` is 0 (= auto); explicit values always win."""
+    ``flash_block_q/kv`` is 0 (= auto); explicit values always win. An
+    unknown kind raises: a tile nobody measured on that hardware may not
+    even fit its VMEM."""
     if device_kind is None:
-        try:
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            return _FALLBACK_BLOCKS
+        device_kind = jax.devices()[0].device_kind
     kind = str(device_kind).lower()
     for key, blocks in DEFAULT_BLOCKS.items():
         if key in kind:
             return blocks
-    return _FALLBACK_BLOCKS
+    raise ValueError(
+        f"device kind {device_kind!r} has no row in the flash-attention "
+        "DEFAULT_BLOCKS table: pass explicit --flash-block-q/--flash-block-kv "
+        "or add a row measured with tools/bench_flash_blocks.py"
+    )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -554,7 +575,17 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
     seq) restricts attention to within-segment for packed sequences.
     There is NO silent fallback: every valid GQA config runs in the
     kernel, and a malformed one (q heads not a multiple of kv heads)
-    raises exactly like ``sdpa_attention`` does."""
+    raises exactly like ``sdpa_attention`` does.
+
+    Under a mesh the kernel runs PER SHARD inside a ``shard_map`` over the
+    batch axes (data, fsdp) and the head axis (tensor) — the layout the
+    model constrains q/k/v to. The compiled Mosaic call is opaque to the
+    SPMD partitioner: on a real multi-chip mesh the bare call does not
+    lower at all ("Mosaic kernels cannot be automatically partitioned.
+    Please wrap the call in a shard_map" — the CPU tests never saw it,
+    the interpreted kernel being ordinary HLO). Attention is independent
+    per (batch row, kv-head group), so the manual region needs no
+    collective."""
     b, s, hq, d = q.shape
     _, sk, hkv, _ = k.shape
     if scale is None:
@@ -569,4 +600,44 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
         segment_ids = segment_ids.astype(jnp.int32)
     bq = min(block_q, s)
     bk = min(block_kv, sk)
-    return _flash(q, k, v, segment_ids, causal, scale, bq, bk)
+
+    def local(q, k, v, seg):
+        return _flash(q, k, v, seg, causal, scale, bq, bk)
+
+    spec = _shard_spec(b, hq, hkv)
+    if spec is None:
+        return local(q, k, v, segment_ids)
+    mesh, qkv_spec, seg_spec = spec
+    # segment_ids=None is an empty pytree: its spec then binds nothing
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(qkv_spec,) * 3 + (seg_spec,),
+        out_specs=qkv_spec, check_vma=False,
+    )(q, k, v, segment_ids)
+
+
+def _shard_spec(batch, hq, hkv):
+    """``(mesh, qkv_spec, seg_spec)`` for the per-shard kernel call, or
+    None when there is nothing to shard over: no mesh in scope, no
+    batch/head axis larger than 1, a dimension the axes do not divide
+    (GSPMD keeps such a value replicated; the call stays global), or an
+    enclosing manual region (the pipeline stage ``shard_map`` — its
+    values are already per-stage local)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty:
+        return None
+    if len(nonmanual_axes(mesh)) != len(mesh.axis_names):
+        return None
+    batch_axes = tuple(
+        a for a in (AXIS_DATA, AXIS_FSDP) if mesh.shape.get(a, 1) > 1
+    )
+    if batch % math.prod(mesh.shape[a] for a in batch_axes):
+        batch_axes = ()
+    tp = mesh.shape.get(AXIS_TENSOR, 1)
+    head_axis = AXIS_TENSOR if tp > 1 and not (hq % tp or hkv % tp) else None
+    if not batch_axes and head_axis is None:
+        return None
+    return (
+        mesh,
+        P(batch_axes or None, None, head_axis, None),
+        P(batch_axes or None, None),
+    )

@@ -153,10 +153,8 @@ def _ring_fwd_local(q, k, v, seg, *, axis_name, causal, scale, block_kv):
     g = hq // hkv
     ring = jax.lax.axis_size(axis_name)
     # positions only feed the causal mask (segment masks compare ids, the
-    # ragged-tail mask uses local indices): without causality, skip
-    # axis_index entirely — its PartitionId lowering is what legacy XLA
-    # (jax 0.4.x) refuses to SPMD-partition, and a dead PartitionId used
-    # to make the whole non-causal ring a capability skip
+    # ragged-tail mask uses local indices): without causality there is no
+    # use for axis_index, so it is not traced at all
     my = jax.lax.axis_index(axis_name) if causal else 0
     q_start = my * sq
 

@@ -104,8 +104,10 @@ def create_mesh(config=None, devices=None):
         is the standard TPU multislice recipe (scaling-book). Requires
         ``data`` divisible by the slice count.
 
-    Both degrade to a plain reshape when the helpers can't map the
-    topology (e.g. virtual CPU devices in tests).
+    Only virtual CPU devices (tests, dry runs) have no topology to map and
+    take a plain reshape. On an accelerator a mapping failure RAISES: a
+    flat device order would put inner axes across the slowest links and
+    the job would "work" at a fraction of its speed.
     """
     if config is None:
         config = MeshConfig()
@@ -117,7 +119,7 @@ def create_mesh(config=None, devices=None):
     if n_slices > 1:
         data_idx = MESH_AXES.index(AXIS_DATA)
         if shape[data_idx] % n_slices != 0:
-            # fail fast: the flat-reshape fallback would span model axes
+            # fail fast: a flat device order would span model axes
             # across DCN and the job would "work" at a fraction of the speed
             raise ValueError(
                 f"data axis {shape[data_idx]} not divisible by "
@@ -134,20 +136,13 @@ def create_mesh(config=None, devices=None):
             per_slice, dcn, devices=devices, allow_split_physical_axes=True
         )
         return Mesh(dev_array, MESH_AXES)
-    try:
-        from jax.experimental import mesh_utils
+    if all(d.platform == "cpu" for d in devices):
+        return Mesh(np.asarray(devices).reshape(shape), MESH_AXES)
+    from jax.experimental import mesh_utils
 
-        dev_array = mesh_utils.create_device_mesh(
-            shape, devices=devices, allow_split_physical_axes=True
-        )
-    except Exception as e:  # virtual/test devices with no topology info
-        from pyrecover_tpu.utils.logging import log_host0
-
-        log_host0(
-            "topology-aware mesh mapping unavailable (%s); using flat "
-            "device order", e,
-        )
-        dev_array = np.asarray(devices).reshape(shape)
+    dev_array = mesh_utils.create_device_mesh(
+        shape, devices=devices, allow_split_physical_axes=True
+    )
     return Mesh(dev_array, MESH_AXES)
 
 
@@ -336,16 +331,8 @@ def initialize_distributed(coordinator_address=None, num_processes=None,
     """
     # IMPORTANT: don't touch jax.devices()/process_count() here — that would
     # initialize the local backend and make distributed init impossible.
-    try:
-        # jaxlint: disable-next=legacy-jax-spelling -- jax 0.4.x has no
-        # public jax.distributed.is_initialized(); guarded by try/except
-        # so a private-API rename degrades to re-init, not a crash
-        from jax._src import distributed as _dist
-
-        if getattr(_dist.global_state, "client", None) is not None:
-            return  # already initialized (e.g. by a launcher/test harness)
-    except Exception:
-        pass
+    if jax.distributed.is_initialized():
+        return  # already initialized (e.g. by a launcher/test harness)
     kwargs = {}
     if coordinator_address is not None:
         kwargs = dict(

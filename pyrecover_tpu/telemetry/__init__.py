@@ -207,7 +207,7 @@ Core event names across the stack (fields beyond the envelope):
     data_stall        wait_s, depth, batch
     loader_stall_timeout  wait_s, timeout_s, batch (stall watchdog tripped)
     fault_injected    type, site, ... (resilience.faults fired an injection)
-    mfu_peak_unknown  device_kind, fallback_flops
+    mfu_peak_unknown  device_kind (not in the peak table: mfu_pct is null)
     hang_detected     silent_s, window_s, sources{} (run-health watchdog:
                       no heartbeat progress for a full window)
     flight_dump       reason, path, last_step (a postmortem bundle was
@@ -216,9 +216,9 @@ Core event names across the stack (fields beyond the envelope):
                       genuine retrace; recompile_total counter rides along)
     implicit_transfer fn, step, error (jax.transfer_guard tripped inside
                       the dispatch under --transfer-guard disallow)
-    platform_fallback reason, resolved, expected (run is on CPU when an
-                      accelerator was expected — perf numbers are not
-                      accelerator numbers)
+    platform_fallback reason, resolved, expected (jax resolved CPU when
+                      $PYRECOVER_EXPECT_ACCELERATOR declared an accelerator
+                      — the trainer raises right after emitting it)
     spec_axis_dropped axis, mesh_axes (a sharding spec named a missing axis)
     ckpt_manifest_dtype_drift  path, detail (resume will cast the leaf)
     run_summary       status, step, + WallTimeTotals.as_dict() (goodput)

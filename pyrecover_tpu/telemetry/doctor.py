@@ -15,7 +15,7 @@ Output: one classification —
                       TopologyMismatchError (--elastic-resume off) or every
                       candidate rejected by the elastic preflight (SC11/SC05)
     platform_fallback the run executed on CPU when an accelerator was
-                      expected (probe fallback / $PYRECOVER_EXPECT_ACCELERATOR)
+                      expected ($PYRECOVER_EXPECT_ACCELERATOR; the trainer refuses)
     recompile_storm   repeated train-step retraces silently ate throughput
     unknown           no readable evidence
 

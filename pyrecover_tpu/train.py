@@ -844,8 +844,8 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
         training_steps=config.training_steps,
         resume=resume_requested,
     )
-    # loud platform_fallback when an accelerator was expected but jax
-    # resolved cpu (probe fallback marker / $PYRECOVER_EXPECT_ACCELERATOR)
+    # a declared accelerator ($PYRECOVER_EXPECT_ACCELERATOR) that resolved
+    # to cpu is an error: platform_fallback event, then raise
     detectors.check_expected_accelerator()
 
     sharded_ckptr = (
@@ -1056,8 +1056,7 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
     csv_logger = None
     # run-health watchdog: created now, STARTED only after the first
     # completed step of this attempt — the first step carries jit compile,
-    # an arbitrarily long legitimate silence (init-time deadlocks are the
-    # accelerator probe's job, not this watchdog's)
+    # an arbitrarily long legitimate silence
     hang_watchdog = (
         telemetry.watchdog.Watchdog(config.hang_watchdog_timeout)
         if config.hang_watchdog_timeout > 0 else None

@@ -36,18 +36,19 @@ TPU_HBM_BYTES = {
     "v6e": 32 * 2**30,
 }
 
-_CPU_FALLBACK_PEAK = 1e12  # arbitrary stand-in so MFU math never divides by 0
-
 _warned_unknown_kinds = set()
 
 
 def tpu_peak_flops(device=None):
-    """Best-effort peak bf16 FLOP/s for the local accelerator.
+    """Peak bf16 FLOP/s for the local accelerator, or ``None`` when its
+    device kind is not in the table.
 
-    An unrecognized device kind falls back to an arbitrary 1e12 — but
-    LOUDLY (one warning + telemetry event per kind per process), because
-    every MFU/TFLOP-utilization number derived from the fallback is
-    meaningless and must not be silently trusted on new hardware."""
+    An unknown device (CPU included) yields NO peak — so no MFU — rather
+    than a stand-in: a utilization computed against an invented
+    denominator reads like a device number and is not one. Announced once
+    per kind per process (warning + ``mfu_peak_unknown`` event) so a new
+    TPU generation missing from the table is noticed, not silently
+    MFU-less."""
     if device is None:
         device = jax.devices()[0]
     kind = getattr(device, "device_kind", "").lower()
@@ -60,22 +61,17 @@ def tpu_peak_flops(device=None):
         from pyrecover_tpu.utils.logging import log_host0
 
         log_host0(
-            "device kind %r is not in the TPU peak-FLOPs table; using the "
-            "%.0e FLOP/s stand-in — MFU/TFLOP utilization numbers for this "
-            "run are MEANINGLESS", kind, _CPU_FALLBACK_PEAK,
+            "device kind %r is not in the TPU peak-FLOPs table; MFU is "
+            "not reported for this run (n/a)", kind,
             level=30,  # WARNING
         )
-        telemetry.emit(
-            "mfu_peak_unknown", device_kind=kind,
-            fallback_flops=_CPU_FALLBACK_PEAK,
-        )
-    return _CPU_FALLBACK_PEAK
+        telemetry.emit("mfu_peak_unknown", device_kind=kind)
+    return None
 
 
 def tpu_hbm_bytes(device_kind=None, device=None):
     """HBM bytes for a device kind (or the local accelerator), or None
-    when unknown. Unlike :func:`tpu_peak_flops` this does NOT fall back
-    to a stand-in: callers (the shardcheck budget) treat None as
+    when unknown: callers (the shardcheck budget) treat None as
     "capacity unknown, report without judging"."""
     if device_kind is None:
         if device is None:

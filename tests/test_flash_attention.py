@@ -227,11 +227,10 @@ def test_model_level_flash_matches_sdpa():
 def test_default_blocks_table():
     """Pin the per-device-kind default tilings (fed by
     tools/bench_flash_blocks.py sweeps): every known generation has a
-    row, the v5e row is the measured r03 sweep winner, resolution is
-    substring-based against the jax device_kind string, and an unknown
-    kind gets the conservative pre-table fallback."""
+    row, resolution is substring-based against the jax device_kind
+    string (the chip reports "TPU v5 lite"), and an unknown kind is an
+    ERROR — no default tile for hardware nobody measured."""
     from pyrecover_tpu.ops.flash_attention import (
-        _FALLBACK_BLOCKS,
         DEFAULT_BLOCKS,
         default_blocks,
     )
@@ -246,14 +245,28 @@ def test_default_blocks_table():
         "v6e": (1024, 2048),
         "cpu": (512, 512),
     }
-    assert _FALLBACK_BLOCKS == (1024, 1024)
     # jax-style device_kind strings resolve by substring, case-insensitive
     assert default_blocks("TPU v5e") == (1024, 1024)
     assert default_blocks("TPU v5 lite") == (1024, 1024)
     assert default_blocks("TPU v6e") == (1024, 2048)
-    assert default_blocks("warp-drive-9000") == _FALLBACK_BLOCKS
+    with pytest.raises(ValueError, match="DEFAULT_BLOCKS"):
+        default_blocks("warp-drive-9000")
     # the local (virtual CPU) device resolves through the cpu row
     assert default_blocks() == (512, 512)
+
+
+def test_interpret_mode_on_a_non_cpu_backend_raises(monkeypatch):
+    """PYRECOVER_PALLAS_INTERPRET is the CPU test aid: set on any other
+    backend it is an error, never a silently interpreted kernel."""
+    from pyrecover_tpu.ops import flash_attention as fa
+
+    monkeypatch.setenv("PYRECOVER_PALLAS_INTERPRET", "1")
+    assert fa._interpret() is True  # this suite runs on the CPU backend
+    monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="interpret mode is the CPU"):
+        fa._interpret()
+    monkeypatch.setenv("PYRECOVER_PALLAS_INTERPRET", "0")
+    assert fa._interpret() is False
 
 
 def test_attention_fn_consumes_default_blocks(monkeypatch):
@@ -275,3 +288,43 @@ def test_attention_fn_consumes_default_blocks(monkeypatch):
     )
     fn = llama_mod._attention_fn(cfg)
     assert (fn.keywords["block_q"], fn.keywords["block_kv"]) == (2048, 512)
+
+
+def test_kernel_runs_per_shard_under_a_mesh(devices8):
+    """Under a mesh the kernel call sits inside a shard_map over the batch
+    axes (data, fsdp) and the head axis (tensor): a compiled Mosaic call is
+    opaque to the SPMD partitioner, which would otherwise feed it the
+    all-gathered GLOBAL batch on every chip. Values and gradients equal
+    the unsharded call; with segment ids too; and a batch the axes do not
+    divide keeps the global call."""
+    from pyrecover_tpu.ops.flash_attention import _shard_spec
+    from pyrecover_tpu.parallel.mesh import MeshConfig, create_mesh
+
+    q, k, v = make_qkv(b=4, s=128, hq=4, hkv=2, d=64)
+    seg = jnp.broadcast_to(
+        (jnp.arange(128) >= 50).astype(jnp.int32), (4, 128)
+    )
+
+    def loss(q, k, v, seg):
+        o = flash_attention(q, k, v, causal=True, block_q=64, block_kv=64,
+                            segment_ids=seg)
+        return jnp.sum(o * jnp.cos(o))
+
+    mesh = create_mesh(MeshConfig(data=2, fsdp=2, tensor=2), devices=devices8)
+    for s_ids in (None, seg):
+        want = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v, s_ids)
+        with jax.sharding.set_mesh(mesh):
+            fn = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+            got = fn(q, k, v, s_ids)
+            assert "shard_map" in str(fn.trace(q, k, v, s_ids).jaxpr)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-5
+            )
+    with jax.sharding.set_mesh(mesh):
+        _, qkv_spec, seg_spec = _shard_spec(4, 4, 2)
+        assert tuple(qkv_spec) == (("data", "fsdp"), None, "tensor", None)
+        assert tuple(seg_spec) == (("data", "fsdp"), None)
+        # batch 3 is not divisible by data×fsdp, heads 3 not by tensor
+        assert _shard_spec(3, 3, 3) is None
+    assert _shard_spec(4, 4, 2) is None  # no mesh in scope

@@ -38,7 +38,7 @@ def test_tpu_peak_flops_table():
     class Unknown:
         device_kind = "cpu"
 
-    assert tpu_peak_flops(Unknown()) == 1e12  # fallback, never zero
+    assert tpu_peak_flops(Unknown()) is None  # no stand-in peak
 
 
 def test_throughput_meter_counts():
@@ -50,6 +50,13 @@ def test_throughput_meter_counts():
     assert snap["steps"] == 1
     assert snap["tokens_per_sec"] > 0
     assert snap["tokens_per_sec_per_chip"] * 2 == snap["tokens_per_sec"]
+    # the CPU this test runs on is not in the peak table: no MFU, and the
+    # log line says so instead of printing a number
+    assert snap["mfu_pct"] is None
+    meter.log(1, 0, 2.5)
+    meter.peak_flops = 197e12
+    meter.update(n_tokens=48, batch_size=2)
+    assert meter.snapshot()["mfu_pct"] > 0
 
 
 def test_loss_csv_logger(tmp_path):

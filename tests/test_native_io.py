@@ -87,3 +87,77 @@ def test_vanilla_ckpt_cross_implementation_verify(tmp_path, monkeypatch):
     for a, b in zip(jax.tree_util.tree_leaves(state),
                     jax.tree_util.tree_leaves(restored)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---- the library is BUILT from source, never found ------------------------
+
+
+@pytest.fixture()
+def fresh_loader(monkeypatch, tmp_path):
+    """native_io with its load-once state reset and its source/build
+    paths pointed at a private copy (restored afterwards)."""
+    import shutil
+
+    src = tmp_path / "native" / "pyrecover_io.cpp"
+    src.parent.mkdir()
+    shutil.copy2(native_io._SRC, src)
+    monkeypatch.setattr(native_io, "_SRC", src)
+    monkeypatch.setattr(native_io, "_BUILD_DIR", src.parent / "build")
+    monkeypatch.setattr(native_io, "_lib", None)
+    monkeypatch.setattr(native_io, "_tried", False)
+    return src
+
+
+def test_library_builds_from_source_keyed_on_content(fresh_loader):
+    """No committed binary: first use compiles native/pyrecover_io.cpp
+    into the (git-ignored) build dir, under a name carrying the source's
+    content digest — so a changed source can never load a stale build,
+    whatever a copy or checkout did to the mtimes."""
+    import os
+
+    src = fresh_loader
+    build = src.parent / "build"
+    assert not build.exists()
+    first = native_io._so_path()
+    assert native_io.available()
+    assert [p.name for p in build.iterdir()] == [first.name]
+    assert native_io.xxh64(b"") == 0xEF46DB3751D8E999
+
+    # same content, arbitrary mtimes (what a checkout does): same library
+    os.utime(src, (1, 1))
+    assert native_io._so_path() == first
+    # changed content: a different library name, and the rebuild drops
+    # the stale one
+    src.write_text(src.read_text() + "\n// edited\n")
+    second = native_io._so_path()
+    assert second != first
+    native_io._tried = False
+    native_io._lib = None
+    assert native_io.available()
+    assert [p.name for p in build.iterdir()] == [second.name]
+
+
+def test_failed_build_is_a_warning_with_the_compilers_output(
+    fresh_loader, caplog
+):
+    """A failed g++ build used to be swallowed (saves silently took the
+    hashlib path). It is logged at WARNING, once, with the compiler's
+    stderr — and callers still get available() == False."""
+    import logging
+
+    from pyrecover_tpu.utils.logging import get_logger
+
+    fresh_loader.write_text("this is not C++ {\n")
+    logger = get_logger()
+    logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.WARNING, logger=logger.name):
+            assert not native_io.available()
+            assert not native_io.available()  # cached verdict, no second log
+    finally:
+        logger.removeHandler(caplog.handler)
+    warned = [r for r in caplog.records if r.levelno == logging.WARNING]
+    assert len(warned) == 1
+    msg = warned[0].getMessage()
+    assert "native checkpoint-I/O engine unavailable" in msg
+    assert "compiler output" in msg and "error" in msg

@@ -36,7 +36,8 @@ from pyrecover_tpu.analysis.report import render_json
 REPO = Path(__file__).resolve().parent.parent
 GATE_PATHS = [
     str(REPO / "pyrecover_tpu"), str(REPO / "tools"),
-    str(REPO / "bench.py"), str(REPO / "__graft_entry__.py"),
+    str(REPO / "bench.py"), str(REPO / "chip_smoke.py"),
+    str(REPO / "__graft_entry__.py"),
 ]
 
 

@@ -14,12 +14,8 @@ from pyrecover_tpu.ops.attention import sdpa_attention
 from pyrecover_tpu.ops.ring_attention import ring_attention
 from pyrecover_tpu.parallel.mesh import MeshConfig, create_mesh
 
-# No capability skips: the non-causal ring used to be unpartitionable on
-# legacy XLA (jax 0.4.x rejected the PartitionId lowering of a DEAD
-# axis_index — positions only feed the causal mask), which made four of
-# these tests capability skips. ops/ring_attention.py now skips the
-# axis_index entirely when causal=False, so --sp is a supported
-# configuration on both XLA generations and every case below runs.
+# No capability skips: causal and non-causal rings both partition on the
+# one installed jax (0.9.0), so every case below runs.
 
 
 def make_qkv(b=4, s=64, hq=4, hkv=2, d=32, seed=0):

@@ -275,8 +275,8 @@ def test_mfu_unknown_device_kind_emits_warning_event():
     class Unknown:
         device_kind = "quantum-abacus-9000"
 
-    assert perf.tpu_peak_flops(Unknown()) == perf._CPU_FALLBACK_PEAK
-    assert perf.tpu_peak_flops(Unknown()) == perf._CPU_FALLBACK_PEAK
+    assert perf.tpu_peak_flops(Unknown()) is None  # no stand-in, no MFU
+    assert perf.tpu_peak_flops(Unknown()) is None
     evs = [e for e in sink.events if e["event"] == "mfu_peak_unknown"]
     assert len(evs) == 1  # once per kind, not per call
     assert evs[0]["device_kind"] == "quantum-abacus-9000"

@@ -66,6 +66,10 @@ def load_checkpoint(path):
     return load_sharded(p) if p.is_dir() else load_vanilla(p)
 
 
+def _as_bytes(x):
+    return np.ascontiguousarray(x).reshape(-1).view(np.uint8)
+
+
 def compare(a, b, tolerance, params_only=True, verbose=True):
     """Returns True if equal within tolerance (reference compare_weights,
     check_weights_equality.py:121-192: key-set → shape → max-abs-diff)."""
@@ -89,9 +93,16 @@ def compare(a, b, tolerance, params_only=True, verbose=True):
             if verbose:
                 print(f"SHAPE mismatch {k}: {va.shape} vs {vb.shape}")
             continue
-        diff = float(
-            np.max(np.abs(va.astype(np.float64) - vb.astype(np.float64)))
-        ) if va.size else 0.0
+        if va.dtype == vb.dtype and np.array_equal(
+            _as_bytes(va), _as_bytes(vb)
+        ):
+            # byte-identical (the bit-exact-resume case): no float64
+            # round trip — that conversion is minutes at a 7.6 GB state
+            diff = 0.0
+        else:
+            diff = float(
+                np.max(np.abs(va.astype(np.float64) - vb.astype(np.float64)))
+            ) if va.size else 0.0
         if diff > worst[0]:
             worst = (diff, k)
         if diff > tolerance:
