@@ -16,6 +16,7 @@ reference's `metadata={epoch,step}` planner state (checkpoint.py:254-258).
 """
 
 import json
+import threading
 import time
 from pathlib import Path
 
@@ -40,21 +41,52 @@ DATA_FILE_BYTES = 64 * 1024 * 1024
 CHUNK_BYTES = 32 * 1024 * 1024
 
 
-def _params_leaf_digests(state):  # jaxlint: host-only
-    """``{manifest path: BLAKE2b-128 hex}`` over the fully-addressable
-    ``.params`` leaves — the serving restore's tamper gate (non-
-    addressable pod shards are skipped: no gathers in the save path)."""
-    from pyrecover_tpu.checkpoint.zerostall.chunkstore import leaf_digest
+# Orbax's commit thread reports its own life (start to commit) under this
+# name when it ends; the listener below turns it into a span on that thread
+_BACKGROUND_WRITE_EVENT = "/jax/checkpoint/write/async/thread_duration_sec"
+_listener_lock = threading.Lock()
+_listener_registered = False
 
-    digests = {}
+
+def _on_duration_event(event, secs, **_):  # jaxlint: host-only
+    if event != _BACKGROUND_WRITE_EVENT:
+        return
+    now = time.monotonic()
+    telemetry.record_span(
+        "ckpt_write_background", now - secs, now, engine="sharded",
+        metric="ckpt_sharded_background_write_s",
+    )
+
+
+def _register_background_write_listener():  # jaxlint: host-only
+    """Once a process: ``jax.monitoring`` keeps listeners for its life."""
+    global _listener_registered
+    with _listener_lock:
+        if not _listener_registered:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration_event
+            )
+            _listener_registered = True
+
+
+def _digestable_params(state):  # jaxlint: host-only
+    """``[(manifest path, leaf)]`` of the fully-addressable ``.params``
+    leaves, whose BLAKE2b-128 digests are the serving restore's tamper
+    gate (non-addressable pod shards are skipped: no gathers in the save
+    path)."""
+    out = []
     for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
         key = jax.tree_util.keystr(path)
         if not key.startswith(".params"):
             continue
         if isinstance(leaf, jax.Array) and not leaf.is_fully_addressable:
             continue
-        digests[key] = leaf_digest(leaf)
-    return digests
+        out.append((key, leaf))
+    return out
+
+
+def _nbytes(leaves):  # jaxlint: host-only
+    return int(sum(getattr(leaf, "nbytes", 0) for leaf in leaves))
 
 
 class ShardedCheckpointer:
@@ -66,15 +98,24 @@ class ShardedCheckpointer:
         handler = ocp.CompositeCheckpointHandler()
         if use_async:
             self._ckptr = ocp.AsyncCheckpointer(handler)
+            _register_background_write_listener()
         else:
             self._ckptr = ocp.Checkpointer(handler)
 
     def save(self, path, state, sampler_state=None, *, max_keep=None,
              extra_meta=None):
         """Start (async) or perform (sync) a sharded save. Returns wall
-        seconds spent blocking the training loop."""
+        seconds spent blocking the training loop.
+
+        The blocking seconds lie under four spans, in this order:
+        ``ckpt_digest``, ``ckpt_wait_previous`` (async only),
+        ``ckpt_serialize``, ``ckpt_prune``; the manifest, the topology and
+        the fault seams read metadata only and stay outside them. The
+        write that goes on after the return is ``ckpt_write_background``,
+        recorded by Orbax's commit thread when it ends."""
         t0 = time.monotonic()
         path = Path(path).absolute()
+        step = (extra_meta or {}).get("step")
         telemetry.emit(
             "ckpt_save_start", engine="sharded", path=str(path),
             async_=self.use_async,
@@ -83,7 +124,7 @@ class ShardedCheckpointer:
         # same schema manifest the vanilla engine embeds (one schema,
         # two producers): preflight/resume diff it without tensor reads
         from pyrecover_tpu.analysis.shardcheck.manifest import state_manifest
-
+        from pyrecover_tpu.checkpoint.zerostall.chunkstore import leaf_digest
         from pyrecover_tpu.parallel.mesh import state_topology
 
         meta = {
@@ -92,22 +133,45 @@ class ShardedCheckpointer:
             # saved topology: the elastic-resume gate (checkpoint/elastic.py)
             # diffs this against the live mesh before any tensor read
             "topology": state_topology(state),
-            # per-params-leaf content digests: Orbax's raw (target-free)
-            # read verifies nothing, so the serving restore needs its own
-            # tamper gate. Fully-addressable leaves only — digesting a
-            # pod-sharded leaf would force the allgather this engine
-            # exists to avoid; a leaf without a digest is simply not
-            # verifiable on that path (single-process covers them all).
-            "leaf_digests": _params_leaf_digests(state),
         }
+        # per-params-leaf content digests: Orbax's raw (target-free)
+        # read verifies nothing, so the serving restore needs its own
+        # tamper gate. Fully-addressable leaves only — digesting a
+        # pod-sharded leaf would force the allgather this engine
+        # exists to avoid; a leaf without a digest is simply not
+        # verifiable on that path (single-process covers them all).
+        digestable = _digestable_params(state)
+        with telemetry.span(
+            "ckpt_digest", engine="sharded", step=step,
+            leaves=len(digestable),
+            bytes=_nbytes(leaf for _, leaf in digestable),
+            metric="ckpt_sharded_digest_s",
+        ):
+            meta["leaf_digests"] = {
+                key: leaf_digest(leaf) for key, leaf in digestable
+            }
         if extra_meta:
             meta.update(extra_meta)
+        if self.use_async:
+            # Orbax's save waits for the previous save's background write
+            # before it copies anything; waiting here first, where its own
+            # wait would come, gives that wait a span of its own and
+            # leaves Orbax's nothing to wait for
+            with telemetry.span(
+                "ckpt_wait_previous", engine="sharded", step=step,
+                waited=self._write_in_flight(),
+                metric="ckpt_sharded_wait_previous_s",
+            ):
+                self._ckptr.wait_until_finished()
         # async saves: this span covers serialize + the device→host copy
         # (the part the training loop pays for); the write-to-durable tail
-        # shows up as the ckpt_wait_durable span when someone waits
+        # is ckpt_write_background on the commit thread, and shows up as
+        # the ckpt_wait_durable span when someone waits
         with telemetry.span(
-            "ckpt_serialize", engine="sharded", path=str(path),
-            async_=self.use_async, metric="ckpt_sharded_serialize_s",
+            "ckpt_serialize", engine="sharded", path=str(path), step=step,
+            async_=self.use_async,
+            bytes=_nbytes(jax.tree_util.tree_leaves(state)),
+            metric="ckpt_sharded_serialize_s",
         ):
             self._ckptr.save(
                 path,
@@ -132,13 +196,30 @@ class ShardedCheckpointer:
             # prune only already-finalized checkpoints; the in-flight save's
             # tmp dir is invisible to the registry until orbax renames it.
             if jax.process_index() == 0:
-                prune_checkpoints(path.parent, max_keep, sharded=True)
+                with telemetry.span(
+                    "ckpt_prune", engine="sharded", step=step,
+                    metric="ckpt_sharded_prune_s",
+                ) as prune_span:
+                    removed = prune_checkpoints(
+                        path.parent, max_keep, sharded=True
+                    )
+                    prune_span.note(removed=len(removed))
         blocking_s = time.monotonic() - t0
         telemetry.emit(
             "ckpt_save_blocking", engine="sharded", path=str(path),
             blocking_s=round(blocking_s, 4), async_=self.use_async,
         )
         return blocking_s
+
+    def _write_in_flight(self):
+        """Is the previous async save's commit thread still at work? Read
+        off Orbax's own handle on it (no public accessor); None where this
+        Orbax keeps it elsewhere."""
+        manager = getattr(self._ckptr, "_async_manager", None)
+        if not hasattr(manager, "_thread"):
+            return None
+        thread = manager._thread
+        return thread is not None and thread.is_alive()
 
     def wait(self):
         """Block until any in-flight async save is durable."""

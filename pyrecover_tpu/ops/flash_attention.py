@@ -247,6 +247,7 @@ def _fwd(q, k, v, seg, *, causal, scale, block_q, block_kv):
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(*inputs)
     return out.transpose(0, 2, 1, 3), lse
 
@@ -441,6 +442,7 @@ def _bwd(causal, scale, block_q, block_kv, res, g):
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_dq",
     )(*dq_inputs)
 
     # dk/dv: grid dim 3 sweeps (q_block × GQA group member) so the whole
@@ -490,6 +492,7 @@ def _bwd(causal, scale, block_q, block_kv, res, g):
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_dkv",
     )(*dkv_inputs)
 
     return (

@@ -229,6 +229,19 @@ finished request, a ``serving_restore`` span around the weight restore,
 and the ``ttft_s`` / ``tpot_s`` / ``e2e_s`` request-latency histograms
 (p50/p95/p99 rendered by ``tools/summarize_telemetry.py``).
 
+Checkpoint spans (``checkpoint/sharded.py``; README "Tracing & trace
+analysis"): inside the trainer's ``ckpt_save`` the sharded engine opens,
+one after the other, ``ckpt_digest`` (BLAKE2b of each ``.params`` leaf,
+pulled to the host for it; fields engine, step, leaves, bytes),
+``ckpt_wait_previous`` (async only: the previous save's background write;
+engine, step, waited — whether one was in flight), ``ckpt_serialize``
+(Orbax's save call: device→host copy of the state and dispatch; engine,
+path, step, async_, bytes) and ``ckpt_prune`` (retention on this thread;
+engine, step, removed). They cover every blocking second of the save but
+the manifest and the fault seams. Orbax's commit thread records a
+retroactive ``ckpt_write_background`` (engine) when it ends: its start to
+the commit. Each feeds a ``ckpt_sharded_<phase>_s`` histogram.
+
 Tracing + metrics events (``spans.py`` / ``metrics.py``; see README
 "Tracing & trace analysis" for the span catalog):
     span_begin        name, span, parent, tid, thread, mono, ...

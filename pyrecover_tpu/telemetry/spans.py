@@ -27,6 +27,12 @@ context manager — two attribute loads and a truth test, no allocation, no
 clock read — so instrumentation points are free on un-instrumented runs.
 With sinks active a span costs two ``emit`` calls.
 
+While a ``jax.profiler`` trace is being taken, a live span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so the profile shows
+``ckpt_save`` and its phases on the host's thread line above the idle
+device with no clock arithmetic. With the profiler off that is one
+``is_enabled()`` test a span; retroactive spans carry none.
+
 ``record_span`` writes a RETROACTIVE span (one ``span`` event carrying
 ``mono``+``dur_s``): the train hot loop buffers per-step timestamps and
 emits its step/data-wait/dispatch spans at the next sync point, so tracing
@@ -48,6 +54,8 @@ spans under the router's root span instead of orphaning them.
 
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from pyrecover_tpu.telemetry import bus, tracing
 
@@ -82,7 +90,7 @@ class Span:
     (the jax.profiler window)."""
 
     __slots__ = ("name", "fields", "span_id", "parent_id", "t0", "metric",
-                 "_open")
+                 "_open", "_annotation")
 
     def __init__(self, name, fields, metric=None):  # jaxlint: host-only
         self.name = name
@@ -99,6 +107,10 @@ class Span:
             fields.setdefault("attempt", ctx.attempt)
         stack.append(self.span_id)
         self._open = True
+        self._annotation = None
+        if TraceAnnotation.is_enabled():
+            self._annotation = TraceAnnotation(name)
+            self._annotation.__enter__()
         self.t0 = time.monotonic()
         bus.emit(
             "span_begin", name=name, span=self.span_id,
@@ -107,12 +119,19 @@ class Span:
             mono=round(self.t0, 6), **fields,
         )
 
+    def note(self, **fields):  # jaxlint: host-only
+        """Fields known only once the work is done (a count of what was
+        removed): carried by ``span_end``."""
+        self.fields.update(fields)
+
     def end(self, ok=True, error=None):  # jaxlint: host-only
         """Close the span (idempotent)."""
         if not self._open:
             return
         self._open = False
         t1 = time.monotonic()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         stack = _stack()
         # tolerate out-of-order closes (a begin/end pair crossing a
         # callback boundary): pop down to and including this span
@@ -148,6 +167,9 @@ class _NullSpan:
     __slots__ = ()
     span_id = None
     parent_id = None
+
+    def note(self, **fields):  # jaxlint: host-only
+        pass
 
     def end(self, ok=True, error=None):  # jaxlint: host-only
         pass

@@ -89,6 +89,48 @@ def test_span_end_idempotent_and_out_of_order():
     assert spans.current_span_id() is None
 
 
+def test_note_adds_fields_known_at_the_end():
+    sink = telemetry.add_sink(telemetry.MemorySink())
+    with spans.span("ckpt_prune", engine="sharded") as sp:
+        sp.note(removed=2)
+    (b,) = by_event(sink, "span_begin")
+    (e,) = by_event(sink, "span_end")
+    assert "removed" not in b and e["removed"] == 2 and e["engine"] == "sharded"
+    telemetry.close()
+    spans.span("ckpt_prune").note(removed=2)  # the shared no-op takes it too
+
+
+def test_live_spans_show_in_a_profiler_trace_by_name(tmp_path):
+    """While a jax.profiler trace is taken, a live span also opens a
+    TraceAnnotation of its name (retroactive spans cannot: their time is
+    past); with the profiler off a span holds none."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    telemetry.add_sink(telemetry.MemorySink())
+    with spans.span("outside_the_trace") as sp:
+        assert sp._annotation is None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with spans.span("ckpt_save", step=1) as sp:
+            assert sp._annotation is not None
+            with spans.span("ckpt_digest"):
+                pass
+        spans.record_span("ckpt_write_background", 1.0, 2.0)
+    finally:
+        jax.profiler.stop_trace()
+    (trace,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {
+        e.name for p in ProfileData.from_file(trace).planes
+        for ln in p.lines for e in ln.events
+    }
+    assert {"ckpt_save", "ckpt_digest"} <= names
+    assert "ckpt_write_background" not in names
+    assert "outside_the_trace" not in names
+
+
 def test_spans_are_thread_isolated():
     """Each thread nests on its own stack: concurrent spans never parent
     across threads, and ids never collide."""
