@@ -13,14 +13,27 @@ feasible (BASELINE.md).
 A checkpoint directory holds two items: ``state`` (the sharded pytree) and
 ``meta`` (JSON: sampler data-order state + counters) — the analogue of the
 reference's `metadata={epoch,step}` planner state (checkpoint.py:254-258).
+
+``meta`` also carries ``leaf_digests``: the BLAKE2b-128 digest of every
+fully-addressable ``.params`` leaf, the serving restore's tamper gate. The
+save call only copies those leaves to the host (all transfers in flight at
+once, under the ``ckpt_digest`` span): that much must happen before the
+next step donates the state's buffers. An async save hashes the copies in
+a commit future of the same Orbax save (``_MetaHandler``), off the loop's
+thread (the retroactive ``ckpt_digest_background`` span), and Orbax's
+atomic rename waits for it: no checkpoint commits without its digests, and
+a failed hash fails the save like a failed write. A sync save has no later
+commit and hashes inline, inside ``ckpt_digest``.
 """
 
+import dataclasses
 import json
 import threading
 import time
 from pathlib import Path
 
 import jax
+import numpy as np
 import orbax.checkpoint as ocp
 
 from pyrecover_tpu import telemetry
@@ -89,13 +102,81 @@ def _nbytes(leaves):  # jaxlint: host-only
     return int(sum(getattr(leaf, "nbytes", 0) for leaf in leaves))
 
 
+def _host_copies(digestable):  # jaxlint: host-only
+    """``[(key, host array)]``: every transfer is started before the first
+    is waited for. Nothing reads the device after this returns, so the
+    caller may donate or delete the leaves."""
+    for _, leaf in digestable:
+        if isinstance(leaf, jax.Array):
+            leaf.copy_to_host_async()
+    return [(key, np.asarray(leaf)) for key, leaf in digestable]
+
+
+def _leaf_digests(host_leaves):  # jaxlint: host-only
+    from pyrecover_tpu.checkpoint.zerostall.chunkstore import leaf_digest
+
+    return {key: leaf_digest(arr) for key, arr in host_leaves}
+
+
+class _MetaHandler(ocp.JsonCheckpointHandler):
+    """The ``meta`` item: Orbax's JSON item (``meta/metadata``), whose
+    commit future first hashes the host copies handed to it and files the
+    result under ``leaf_digests``. The future runs on a thread of its own
+    and Orbax's commit waits for it like for any write."""
+
+    @classmethod
+    def typestr(cls):
+        # what the commit marker records for the item: it is Orbax's JSON
+        # item on disk, and any Orbax reader may restore it as such
+        return ocp.JsonCheckpointHandler.typestr()
+
+    async def async_save(self, directory, item=None, args=None):
+        meta, deferred, step = dict(args.item), args.deferred, args.step
+
+        async def fill_and_write():
+            if deferred:
+                t0 = time.monotonic()
+                meta["leaf_digests"] = _leaf_digests(deferred)
+                telemetry.record_span(
+                    "ckpt_digest_background", t0, time.monotonic(),
+                    engine="sharded", step=step, leaves=len(deferred),
+                    bytes=_nbytes(arr for _, arr in deferred),
+                    metric="ckpt_sharded_digest_background_s",
+                )
+            await self._save_fn(meta, directory)  # Orbax's own JSON write
+
+        return [ocp.future.CommitFutureAwaitingContractedSignals(
+            fill_and_write(), name="meta_digest_save"
+        )]
+
+
+@dataclasses.dataclass
+class _MetaSave(ocp.args.CheckpointArgs):
+    """``item``: the JSON mapping; ``deferred``: ``[(key, host array)]``
+    still to be hashed into ``item["leaf_digests"]`` by the commit. Known
+    to ``_composite_handler``'s registry only, not to Orbax's global one."""
+
+    item: dict
+    deferred: list
+    step: int | None = None
+
+
+def _composite_handler():
+    """``meta`` is written by ``_MetaHandler`` and read as plain JSON."""
+    registry = ocp.handlers.DefaultCheckpointHandlerRegistry()
+    meta = _MetaHandler()
+    registry.add("meta", _MetaSave, meta)
+    registry.add("meta", ocp.args.JsonRestore, meta)
+    return ocp.CompositeCheckpointHandler(handler_registry=registry)
+
+
 class ShardedCheckpointer:
     """Long-lived checkpointer; owns the async machinery. Use as a context
     manager or call close()."""
 
     def __init__(self, use_async=True):
         self.use_async = use_async
-        handler = ocp.CompositeCheckpointHandler()
+        handler = _composite_handler()
         if use_async:
             self._ckptr = ocp.AsyncCheckpointer(handler)
             _register_background_write_listener()
@@ -110,9 +191,14 @@ class ShardedCheckpointer:
         The blocking seconds lie under four spans, in this order:
         ``ckpt_digest``, ``ckpt_wait_previous`` (async only),
         ``ckpt_serialize``, ``ckpt_prune``; the manifest, the topology and
-        the fault seams read metadata only and stay outside them. The
-        write that goes on after the return is ``ckpt_write_background``,
-        recorded by Orbax's commit thread when it ends."""
+        the fault seams read metadata only and stay outside them.
+        ``ckpt_digest`` is the host copy of the digestable ``.params``
+        leaves (``leaves``, ``bytes``) and, on a sync save, their hash; an
+        async save hands the hash of all of them (``deferred``) to its
+        commit, where it is the retroactive ``ckpt_digest_background``.
+        The state is read here and never after the return. The write that
+        goes on after the return is ``ckpt_write_background``, recorded by
+        Orbax's commit thread when it ends, the hash included."""
         t0 = time.monotonic()
         path = Path(path).absolute()
         step = (extra_meta or {}).get("step")
@@ -124,7 +210,6 @@ class ShardedCheckpointer:
         # same schema manifest the vanilla engine embeds (one schema,
         # two producers): preflight/resume diff it without tensor reads
         from pyrecover_tpu.analysis.shardcheck.manifest import state_manifest
-        from pyrecover_tpu.checkpoint.zerostall.chunkstore import leaf_digest
         from pyrecover_tpu.parallel.mesh import state_topology
 
         meta = {
@@ -133,6 +218,8 @@ class ShardedCheckpointer:
             # saved topology: the elastic-resume gate (checkpoint/elastic.py)
             # diffs this against the live mesh before any tensor read
             "topology": state_topology(state),
+            # filled in below (sync) or by the commit (async)
+            "leaf_digests": {},
         }
         # per-params-leaf content digests: Orbax's raw (target-free)
         # read verifies nothing, so the serving restore needs its own
@@ -145,11 +232,15 @@ class ShardedCheckpointer:
             "ckpt_digest", engine="sharded", step=step,
             leaves=len(digestable),
             bytes=_nbytes(leaf for _, leaf in digestable),
+            deferred=len(digestable) if self.use_async else 0,
             metric="ckpt_sharded_digest_s",
         ):
-            meta["leaf_digests"] = {
-                key: leaf_digest(leaf) for key, leaf in digestable
-            }
+            # the copy cannot wait: the next step donates these buffers.
+            # The hash can, as long as the commit waits for it
+            deferred = _host_copies(digestable)
+            if not self.use_async:
+                meta["leaf_digests"] = _leaf_digests(deferred)
+                deferred = []
         if extra_meta:
             meta.update(extra_meta)
         if self.use_async:
@@ -184,7 +275,7 @@ class ShardedCheckpointer:
                         ),
                         ocdbt_target_data_file_size=DATA_FILE_BYTES,
                     ),
-                    meta=ocp.args.JsonSave(meta),
+                    meta=_MetaSave(meta, deferred, step),
                 ),
                 force=True,
             )

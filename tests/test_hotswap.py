@@ -538,12 +538,53 @@ def test_restore_rejects_tampered_vanilla_before_placement(tmp_path):
         load_serving_params(path, CFG)
 
 
-def test_restore_rejects_tampered_sharded_before_placement(tmp_path):
+def _save_sharded_sync(path, state):
     from pyrecover_tpu.checkpoint.sharded import save_ckpt_sharded
 
+    save_ckpt_sharded(path, state, {})
+
+
+def _save_sharded_async(path, state):
+    """The trainer's engine: the digests are hashed by the save's commit."""
+    from pyrecover_tpu.checkpoint.sharded import ShardedCheckpointer
+
+    with ShardedCheckpointer(use_async=True) as ckptr:
+        ckptr.save(path, state, {})
+        ckptr.wait()
+
+
+def _save_sharded_parent_layout(path, state):
+    """A directory as the engine wrote it before the hash moved to the
+    commit: Orbax's own JSON item, the digests hashed inline beforehand."""
+    import numpy as np
+    import orbax.checkpoint as ocp
+
+    from pyrecover_tpu.analysis.shardcheck.manifest import state_manifest
+    from pyrecover_tpu.checkpoint.zerostall.chunkstore import leaf_digest
+    from pyrecover_tpu.parallel.mesh import state_topology
+
+    digests = {
+        ".params" + jax.tree_util.keystr(p): leaf_digest(np.asarray(leaf))
+        for p, leaf in jax.tree_util.tree_flatten_with_path(state.params)[0]
+    }
+    with ocp.Checkpointer(ocp.CompositeCheckpointHandler()) as ckptr:
+        ckptr.save(path.absolute(), args=ocp.args.Composite(
+            state=ocp.args.PyTreeSave(state),
+            meta=ocp.args.JsonSave({
+                "sampler": {}, "manifest": state_manifest(state),
+                "topology": state_topology(state), "leaf_digests": digests,
+            }),
+        ))
+
+
+@pytest.mark.parametrize(
+    "save", [_save_sharded_sync, _save_sharded_async,
+             _save_sharded_parent_layout],
+    ids=["sync", "async", "parent_layout"])
+def test_restore_rejects_tampered_sharded_before_placement(tmp_path, save):
     state = _train_state()
     path = tmp_path / "ckpt_1"
-    save_ckpt_sharded(path, state, {})
+    save(path, state)
     load_serving_params(path, CFG)  # intact: loads
     # flip a byte in the largest tensorstore data file (Orbax's raw read
     # verifies nothing — the recorded leaf digests must catch it)
