@@ -240,7 +240,9 @@ def memory_budget(leaves, specs, mesh_shape, model_config, *, batch_size,
     batch_shards = mesh.get("data", 1) * mesh.get("fsdp", 1)
     b_loc = max(batch_size // batch_shards, 1)
     s_loc = max(seq_len // mesh.get("sequence", 1), 1)
-    layers_loc = max(cfg.n_layers // mesh.get("pipeline", 1), 1)
+    # saved activations count layer PASSES: a looped stack saves a carry
+    # for each of its loop_steps sweeps over the held layers
+    layers_loc = max(cfg.layer_passes // mesh.get("pipeline", 1), 1)
     # per-layer saved set ~ attention ins/outs + FFN hidden, in units of
     # (b, s, dim): qkv+attn_out+residuals ~6 dim-widths + 3 ffn widths
     ffn = cfg.expert_hidden_dim if cfg.n_experts > 0 else cfg.ffn_hidden_dim
@@ -252,6 +254,11 @@ def memory_budget(leaves, specs, mesh_shape, model_config, *, batch_size,
             2 if cfg.remat_policy == "save-attn" else 1
         )
     rows["activations_bytes"] = per_layer * layers_loc
+    if cfg.loop_steps > 1:
+        # every pass's normed state is kept for the exit loss
+        rows["activations_bytes"] += (
+            cfg.loop_steps * b_loc * s_loc * cfg.dim * itemsize
+        )
     chunk = loss_chunk_size if 0 < loss_chunk_size < s_loc else s_loc
     vocab_loc = cfg.vocab_size // max(mesh.get("tensor", 1), 1)
     # logits + logprobs, f32 (train_state.chunked_ce)

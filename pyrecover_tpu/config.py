@@ -186,6 +186,12 @@ class TrainConfig:
                 f"unknown --grad-allreduce {self.grad_allreduce!r} "
                 "(expected fp32, bf16 or int8)"
             )
+        if self.model.loop_steps > 1 and self.mesh.pipeline > 1:
+            from pyrecover_tpu.models.llama import refuse_looped
+
+            # both schedules: a stage would have to run loop_steps times a
+            # step, which neither gpipe nor 1f1b (parallel/pipeline.py) does
+            refuse_looped(self.model, "pipeline parallelism (--pp > 1)")
         if self.grad_quant_block <= 0:
             raise ValueError(
                 f"--grad-quant-block must be positive, got "
@@ -406,6 +412,19 @@ def build_parser():
     p.add_argument("--model-layers", type=int, default=d.model.n_layers)
     p.add_argument("--model-heads", type=int, default=d.model.n_heads)
     p.add_argument("--model-kv-heads", type=int, default=d.model.n_kv_heads)
+    p.add_argument("--model-loop-steps", type=int, default=d.model.loop_steps,
+                   help="Run the layer stack this many times over the SAME "
+                        "weights, the final norm closing every pass (a "
+                        "looped model; 1 = the plain decoder).")
+    p.add_argument("--model-post-norms", action="store_true",
+                   help="Sandwich norms: a second RMSNorm after each "
+                        "sublayer, inside the residual branch.")
+    p.add_argument("--model-exit-gate", action="store_true",
+                   help="With --model-loop-steps >= 2: an exit gate read "
+                        "from every pass; the loss is the expected "
+                        "cross-entropy over the exits less "
+                        "--model-exit-beta x the exit entropy.")
+    p.add_argument("--model-exit-beta", type=float, default=d.model.exit_beta)
     p.add_argument("--vocab-size", type=int, default=d.model.vocab_size,
                    help="Used with synthetic data; with a tokenizer, its vocab size wins.")
     p.add_argument("--use_flash_attention", "--use-flash-attention",
@@ -591,6 +610,10 @@ def get_args(argv=None):
         moe_capacity_factor=ns.moe_capacity_factor,
         moe_aux_weight=ns.moe_aux_weight,
         remat_policy=ns.remat_policy,
+        loop_steps=ns.model_loop_steps,
+        post_norms=ns.model_post_norms,
+        exit_gate=ns.model_exit_gate,
+        exit_beta=ns.model_exit_beta,
     )
     return TrainConfig(
         dataset=ns.dataset,

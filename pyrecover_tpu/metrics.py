@@ -94,9 +94,12 @@ class ThroughputMeter:
 
         # MoE: only the top-k active experts' FLOPs count toward MFU
         num_params -= inactive_expert_param_count(model_config)
+        # a looped model multiplies every non-embedding weight (layers,
+        # final norm, head, gate) loop_steps times a token: T * L layer
+        # passes and T head evaluations
         self.flop_per_token = get_num_flop_per_token(
-            num_params,
-            model_config.n_layers,
+            num_params * model_config.loop_steps,
+            model_config.layer_passes,
             model_config.n_heads,
             model_config.head_dim,
             seq_len,

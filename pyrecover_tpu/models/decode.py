@@ -8,7 +8,7 @@ decode step is O(1) in model FLOPs beyond attention against the cache.
 
 Design (TPU-first):
   * The cache is a pytree of layer-stacked buffers ``(L, B, max_len, Hkv,
-    hd)`` — the same leading-layer-axis convention as the parameters, so
+    hd)`` (``(loop_steps * L, ...)`` for a looped model, swept pass by pass) — the same leading-layer-axis convention as the parameters, so
     the per-layer scan zips params and cache slices together and the whole
     decode step is ONE jitted program with static shapes (``chunk`` is a
     static width; ``pos`` is a traced offset into the cache).
@@ -30,7 +30,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from pyrecover_tpu.models.llama import ffn_sublayer, qkv_proj, rms_norm
+from pyrecover_tpu.models.llama import (
+    attn_residual,
+    ffn_sublayer,
+    qkv_proj,
+    rms_norm,
+)
 from pyrecover_tpu.ops.rope import precompute_rope
 from pyrecover_tpu.utils.dtypes import resolve_dtype
 
@@ -42,7 +47,9 @@ _DECODE_BLOCK = 256
 
 
 def init_kv_cache(config, batch_size, max_len, dtype=None):
-    """Zeroed KV cache: {"k","v"} each (L, B, max_len, Hkv, head_dim).
+    """Zeroed KV cache: {"k","v"} each (L, B, max_len, Hkv, head_dim); a
+    looped model has keys and values of its own for every (pass, layer)
+    pair, so its leading axis is ``loop_steps * L``, pass-major.
 
     The physical buffer length is rounded up to a multiple of
     ``_DECODE_BLOCK`` when longer than one block, so the blockwise cache
@@ -53,7 +60,7 @@ def init_kv_cache(config, batch_size, max_len, dtype=None):
     max_len = int(max_len)
     if max_len > _DECODE_BLOCK and max_len % _DECODE_BLOCK:
         max_len = (max_len // _DECODE_BLOCK + 1) * _DECODE_BLOCK
-    shape = (cfg.n_layers, batch_size, max_len, cfg.n_kv_heads,
+    shape = (cfg.layer_passes, batch_size, max_len, cfg.n_kv_heads,
              cfg.head_dim)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
@@ -171,7 +178,7 @@ def decode_forward(params, cache, tokens, pos, config):
         kc = jax.lax.dynamic_update_slice_in_dim(kc, k.astype(kc.dtype), pos, 1)
         vc = jax.lax.dynamic_update_slice_in_dim(vc, v.astype(vc.dtype), pos, 1)
         attn = _cached_attention(q, kc, vc, pos, c, scale)
-        x = x + attn @ layer["wo"].astype(cdt)
+        x = attn_residual(x, attn, layer, cfg)
         x, _ = ffn_sublayer(x, layer, cfg)
         return x, (kc, vc)
 
@@ -180,10 +187,21 @@ def decode_forward(params, cache, tokens, pos, config):
         new_x, (kc, vc) = block(x, (layer, kc, vc))
         return new_x, (kc, vc)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"])
+    # the second sweep: every pass over the same layers, against its own
+    # (L, ...) slice of the cache, the final norm closing it; the last
+    # pass's state is the one the head reads (early_exit_threshold 1: no
+    # exit before it). One pass compiles to the plain decoder's program:
+    # XLA inlines a forward loop of one trip (PERF.md, Findings, PR 27)
+    def one_pass(x, kv):
+        x, kv = jax.lax.scan(body, x, (params["layers"], *kv))
+        return rms_norm(x, params["final_norm"], cfg.norm_eps), kv
+
+    per_pass = lambda a: a.reshape(cfg.loop_steps, cfg.n_layers, *a.shape[1:])
+    hidden, (new_k, new_v) = jax.lax.scan(
+        one_pass, x, (per_pass(cache["k"]), per_pass(cache["v"]))
     )
-    hidden = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    new_k = new_k.reshape(cache["k"].shape)
+    new_v = new_v.reshape(cache["v"].shape)
     logits = jnp.einsum(
         "bcd,dv->bcv", hidden, params["output"].astype(cdt),
         preferred_element_type=jnp.float32,

@@ -88,8 +88,40 @@ class ModelConfig:
     moe_aux_weight: float = 0.01  # load-balance loss scale
     moe_ffn_hidden: int = 0  # per-expert hidden size; 0 → ffn_hidden_dim
     moe_dispatch: str = "auto"  # "auto" | "grouped" | "einsum" | "scatter" (moe.py)
+    # -- looped stack (1 pass, no post-norms, no gate = the plain decoder) --
+    # the SAME n_layers run loop_steps times a forward, the final norm
+    # closing every pass (its output feeds the head AND the next pass)
+    loop_steps: int = 1
+    # "sandwich" norms: a second RMSNorm after each sublayer, inside the
+    # residual branch (x + norm(sublayer(norm(x))))
+    post_norms: bool = False
+    # exit gate Linear(dim -> 1) read from every pass's normed state; the
+    # training loss becomes the expectation of the per-pass cross-entropy
+    # under the exit distribution, less exit_beta * its entropy
+    # (train_state.chunked_exit_loss)
+    exit_gate: bool = False
+    exit_beta: float = 0.1
 
     def __post_init__(self):
+        if self.loop_steps < 1:
+            raise ValueError(
+                f"loop_steps (--model-loop-steps) must be >= 1, got "
+                f"{self.loop_steps}"
+            )
+        if self.exit_gate and self.loop_steps < 2:
+            raise ValueError(
+                "exit_gate (--model-exit-gate) needs loop_steps >= 2: one "
+                "pass has no exit to choose between"
+            )
+        if self.n_experts > 0 and (
+            self.loop_steps > 1 or self.post_norms or self.exit_gate
+        ):
+            raise ValueError(
+                "a looped, sandwich-normed or gated mixture of experts is "
+                "not supported (untested: the load-balance loss has no "
+                "per-pass form); drop --moe-experts or the --model-loop-* "
+                "flags"
+            )
         if self.n_experts > 0 and self.moe_top_k > self.n_experts:
             raise ValueError(
                 f"moe_top_k={self.moe_top_k} must be <= "
@@ -120,6 +152,12 @@ class ModelConfig:
         return self.dim // self.n_heads
 
     @property
+    def layer_passes(self):
+        """Layer evaluations a forward makes: the work and the saved
+        activations scale with this, the parameters with ``n_layers``."""
+        return self.n_layers * self.loop_steps
+
+    @property
     def expert_hidden_dim(self):
         return self.moe_ffn_hidden or self.ffn_hidden_dim
 
@@ -141,6 +179,18 @@ class ModelConfig:
         )
         base.update(overrides)
         return dataclasses.replace(self, **base)
+
+
+def refuse_looped(config, path):
+    """One sentence for the paths that run the stack once and cannot run
+    it ``loop_steps`` times; called where the model or the engine is
+    built, never inside a trace."""
+    if config.loop_steps > 1:
+        raise ValueError(
+            f"{path} cannot run a looped model (loop_steps="
+            f"{config.loop_steps}): it sweeps the layers once a forward "
+            "(ROADMAP.md, Reach: looped stack)"
+        )
 
 
 def _normal_init(key, shape, std, dtype):
@@ -178,6 +228,9 @@ def init_params(rng, config):
         "wo": stacked(keys[4], (cfg.n_heads * hd, cfg.dim), resid_std),
         "ffn_norm": jnp.ones((L, cfg.dim), dtype=pdt),
     }
+    if cfg.post_norms:
+        layers["attn_post_norm"] = jnp.ones((L, cfg.dim), dtype=pdt)
+        layers["ffn_post_norm"] = jnp.ones((L, cfg.dim), dtype=pdt)
     if cfg.n_experts > 0:
         E, F = cfg.n_experts, cfg.expert_hidden_dim
         layers.update({
@@ -200,6 +253,14 @@ def init_params(rng, config):
         "final_norm": jnp.ones((cfg.dim,), dtype=pdt),
         "output": _normal_init(keys[8], (cfg.dim, cfg.vocab_size), std, pdt),
     }
+    if cfg.exit_gate:
+        # the gate's key is folded in BESIDE the ten: split(rng, 11)[:10]
+        # is not split(rng, 10), and every other leaf must draw as before
+        # jaxlint: disable-next=prng-key-reuse -- deliberate: fold_in
+        # derives an eleventh stream without moving the ten split above
+        gate_key = jax.random.fold_in(rng, 10)
+        params["exit_gate_w"] = _normal_init(gate_key, (cfg.dim, 1), std, pdt)
+        params["exit_gate_b"] = jnp.zeros((1,), dtype=pdt)
     return params
 
 
@@ -247,6 +308,16 @@ def qkv_proj(h, layer, config, cos, sin):
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
+def attn_residual(x, attn, layer, config):
+    """Output projection of the attention heads (B, S, heads*hd) added to
+    the residual, through the post-sublayer norm where the model has one —
+    shared by the training forward and the KV-cached decoder."""
+    y = attn @ layer["wo"].astype(resolve_dtype(config.compute_dtype))
+    if config.post_norms:
+        y = rms_norm(y, layer["attn_post_norm"], config.norm_eps)
+    return x + y
+
+
 def ffn_sublayer(x, layer, config):
     """Post-attention FFN sublayer (pre-norm residual): dense SwiGLU
     (reference model.py:268-269) or MoE. Returns ``(x, aux)`` — shared by
@@ -264,8 +335,10 @@ def ffn_sublayer(x, layer, config):
         return x + y, aux
     gate = jax.nn.silu(h @ layer["w1"].astype(cdt))
     up = h @ layer["w3"].astype(cdt)
-    x = x + (gate * up) @ layer["w2"].astype(cdt)
-    return x, jnp.zeros((x.shape[0],), dtype=jnp.float32)
+    y = (gate * up) @ layer["w2"].astype(cdt)
+    if cfg.post_norms:
+        y = rms_norm(y, layer["ffn_post_norm"], cfg.norm_eps)
+    return x + y, jnp.zeros((x.shape[0],), dtype=jnp.float32)
 
 
 def _block(x, layer, cos, sin, config, attn_fn, segment_ids=None):
@@ -276,7 +349,6 @@ def _block(x, layer, cos, sin, config, attn_fn, segment_ids=None):
     packed-sequence boundaries into the attention mask.
     """
     cfg = config
-    cdt = resolve_dtype(cfg.compute_dtype)
     b, s, d = x.shape
     hd = cfg.head_dim
 
@@ -292,7 +364,7 @@ def _block(x, layer, cos, sin, config, attn_fn, segment_ids=None):
         attn = attn_fn(q, k, v, causal=True, segment_ids=segment_ids)
     attn = checkpoint_name(attn, "attn_out")
     attn = attn.reshape(b, s, cfg.n_heads * hd)
-    x = x + attn @ layer["wo"].astype(cdt)
+    x = attn_residual(x, attn, layer, cfg)
     x = constrain(x, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, None)
 
     # --- FFN sublayer ---
@@ -301,16 +373,9 @@ def _block(x, layer, cos, sin, config, attn_fn, segment_ids=None):
     return x, aux
 
 
-def forward_hidden_with_aux(params, tokens, config, segment_ids=None):
-    """Embed → n_layers pre-norm blocks → final RMSNorm; returns
-    ``(hidden, aux)``: the hidden states (batch, seq, dim) BEFORE the vocab
-    projection (split out so the loss can fuse projection + cross-entropy
-    per sequence chunk without ever materializing (batch, seq, vocab)
-    logits — an HBM optimization the reference, which always materializes
-    full logits at train.py:262-266, has no analogue of), and the scalar
-    MoE load-balance aux loss summed over layers, averaged over rows
-    (0 for dense models). ``segment_ids`` (batch, seq) enables packed-
-    sequence attention masking (``--pack-sequences``)."""
+def _stack(params, tokens, config, segment_ids):
+    """The embedded carry and the function that runs it through the
+    ``n_layers`` blocks once: ``(carry, run_stack)``."""
     cfg = config
     cdt = resolve_dtype(cfg.compute_dtype)
     seq_len = tokens.shape[1]
@@ -362,11 +427,74 @@ def forward_hidden_with_aux(params, tokens, config, segment_ids=None):
     }
     if segment_ids is not None:
         carry["seg"] = segment_ids.astype(jnp.int32)
-    carry = pipeline_blocks(
-        params["layers"], carry, block_carry,
-        n_microbatches=cfg.pp_microbatches,
-    )
 
+    def run_stack(carry):
+        return pipeline_blocks(
+            params["layers"], carry, block_carry,
+            n_microbatches=cfg.pp_microbatches,
+        )
+
+    return carry, run_stack
+
+
+def exit_gate_logits(params, hidden, config):
+    """The exit gate's logit of every token, f32 (batch, seq): one
+    ``Linear(dim -> 1)`` with a bias, read from a pass's normed state."""
+    w = params["exit_gate_w"][:, 0].astype(hidden.dtype)
+    logit = jnp.einsum(
+        "bsd,d->bs", hidden, w, preferred_element_type=jnp.float32
+    )
+    return logit + params["exit_gate_b"].astype(jnp.float32)
+
+
+def forward_passes_with_aux(params, tokens, config, segment_ids=None):
+    """The stack run ``loop_steps`` times over the same layers, the final
+    norm closing every pass: returns ``(hiddens, gate_logits, aux)`` with
+    the normed state of every pass stacked (T, batch, seq, dim), the exit
+    gate's logits (T, batch, seq) f32 (``None`` without a gate) and the
+    aux loss of :func:`forward_hidden_with_aux`. One compiled layer body
+    whatever T and L are: a scan over passes round the scan over layers,
+    each layer pass rematerialised as the config says (T·L saved carries).
+    """
+    cfg = config
+    from pyrecover_tpu.parallel.pipeline import pipeline_axis_size
+
+    if cfg.loop_steps > 1 and pipeline_axis_size() > 1:
+        refuse_looped(cfg, "the pipeline schedule (--pp > 1)")
+    carry, run_stack = _stack(params, tokens, cfg, segment_ids)
+
+    def one_pass(carry, _):
+        with jax.named_scope("loop_pass"):
+            carry = run_stack(carry)
+            h = rms_norm(carry["x"], params["final_norm"], cfg.norm_eps)
+            gate = exit_gate_logits(params, h, cfg) if cfg.exit_gate else None
+        return dict(carry, x=h), (h, gate)
+
+    carry, (hiddens, gates) = jax.lax.scan(
+        one_pass, carry, None, length=cfg.loop_steps
+    )
+    return hiddens, gates, jnp.mean(carry["aux"])
+
+
+def forward_hidden_with_aux(params, tokens, config, segment_ids=None):
+    """Embed → n_layers pre-norm blocks → final RMSNorm; returns
+    ``(hidden, aux)``: the hidden states (batch, seq, dim) BEFORE the vocab
+    projection (split out so the loss can fuse projection + cross-entropy
+    per sequence chunk without ever materializing (batch, seq, vocab)
+    logits — an HBM optimization the reference, which always materializes
+    full logits at train.py:262-266, has no analogue of), and the scalar
+    MoE load-balance aux loss summed over layers, averaged over rows
+    (0 for dense models). ``segment_ids`` (batch, seq) enables packed-
+    sequence attention masking (``--pack-sequences``). A looped model
+    returns its LAST pass's state (:func:`forward_passes_with_aux`)."""
+    cfg = config
+    if cfg.loop_steps > 1:
+        hiddens, _, aux = forward_passes_with_aux(
+            params, tokens, cfg, segment_ids
+        )
+        return hiddens[-1], aux
+    carry, run_stack = _stack(params, tokens, cfg, segment_ids)
+    carry = run_stack(carry)
     hidden = rms_norm(carry["x"], params["final_norm"], cfg.norm_eps)
     return hidden, jnp.mean(carry["aux"])
 

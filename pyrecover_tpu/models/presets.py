@@ -105,12 +105,15 @@ def analytic_param_count(cfg, exclude_embedding=False):
         per_layer += cfg.n_experts * 3 * cfg.dim * cfg.expert_hidden_dim
     else:
         per_layer += 3 * cfg.dim * cfg.ffn_hidden_dim
+    if cfg.post_norms:
+        per_layer += 2 * cfg.dim
     embed = 0 if exclude_embedding else cfg.vocab_size * cfg.dim
     return (
         embed
         + cfg.n_layers * per_layer
         + cfg.dim
         + cfg.dim * cfg.vocab_size
+        + (cfg.dim + 1 if cfg.exit_gate else 0)
     )
 
 

@@ -53,7 +53,12 @@ _RULES = {
     # norms: replicated within a stage (tiny), layer axis on pipeline
     "attn_norm": P(AXIS_PIPE, None),
     "ffn_norm": P(AXIS_PIPE, None),
+    "attn_post_norm": P(AXIS_PIPE, None),
+    "ffn_post_norm": P(AXIS_PIPE, None),
     "final_norm": P(None),
+    # exit gate Linear(D -> 1) of a looped model: tiny, replicated
+    "exit_gate_w": P(None, None),
+    "exit_gate_b": P(None),
     # untied output projection (D, V) (reference model.py:367)
     "output": P(AXIS_FSDP, AXIS_TENSOR),
 }

@@ -33,7 +33,12 @@ import jax
 import jax.numpy as jnp
 
 from pyrecover_tpu.models.decode import NEG_INF
-from pyrecover_tpu.models.llama import ffn_sublayer, qkv_proj, rms_norm
+from pyrecover_tpu.models.llama import (
+    attn_residual,
+    ffn_sublayer,
+    qkv_proj,
+    rms_norm,
+)
 from pyrecover_tpu.ops.rope import precompute_rope
 from pyrecover_tpu.parallel.collectives import (
     block_dequantize_int8,
@@ -182,7 +187,7 @@ def paged_forward(params, pool_arrays, tokens, pos, tables, config, *,
         attn = paged_attention(
             q, layer_pool, tables, qpos, scale, block_size, kv_mode
         )
-        x = x + attn @ layer["wo"].astype(cdt)
+        x = attn_residual(x, attn, layer, cfg)
         x, _ = ffn_sublayer(x, layer, cfg)
         return x, layer_pool
 

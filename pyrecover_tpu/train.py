@@ -44,6 +44,7 @@ from pyrecover_tpu.preempt import (
 from pyrecover_tpu.resilience import faults, quarantine_checkpoint
 from pyrecover_tpu.train_state import (
     create_train_state,
+    exit_stats_fields,
     make_eval_step,
     make_train_step,
 )
@@ -838,6 +839,10 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
         processes=jax.process_count(),
         mesh={k: int(v) for k, v in dict(mesh.shape).items()},
         params_m=round(n_params / 1e6, 3),
+        # a looped model sweeps its layers loop_steps times a forward:
+        # the work and the saved carries scale with layer_passes
+        loop_steps=model_config.loop_steps,
+        layer_passes=model_config.layer_passes,
         batch_size=config.batch_size,
         sequence_length=config.sequence_length,
         grad_accum_steps=config.grad_accumulation_steps,
@@ -1297,10 +1302,19 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
                 want_log = step % config.logging_frequency == 0
                 if want_log or check_preempt:
                     t_sync0 = time.monotonic()
-                    # jaxlint: disable-next=host-sync-in-hot-loop -- THE
-                    # deliberate once-per-interval sync: everything else
-                    # batches to this point (ISSUE 2 allowlisted site)
-                    loss = float(metrics["loss"])  # device sync
+                    exit_stats = metrics.get("exit_stats")
+                    if exit_stats is None:
+                        # jaxlint: disable-next=host-sync-in-hot-loop -- THE
+                        # deliberate once-per-interval sync: everything else
+                        # batches to this point (ISSUE 2 allowlisted site)
+                        loss = float(metrics["loss"])  # device sync
+                    else:
+                        # an exit-gated model: the loss comes up inside its
+                        # vector of exit statistics, in the same one transfer
+                        # jaxlint: disable-next=host-sync-in-hot-loop -- the
+                        # same deliberate sync, in the loss's place
+                        exit_stats = np.asarray(exit_stats).tolist()
+                        loss = exit_stats[0]
                     sync_s = time.monotonic() - t_sync0
                     for t in pending_tokens:
                         # jaxlint: disable-next=host-sync-in-hot-loop -- the
@@ -1340,6 +1354,7 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
                         steps=n, interval_s=round(dt, 6),
                         iter_s=round(dt / n, 6), sync_s=round(sync_s, 6),
                         grad_accum_steps=config.grad_accumulation_steps,
+                        **exit_stats_fields(exit_stats),
                     )
                     # live plane: the same derived numbers the throughput
                     # event carries, as gauges the exporter can serve

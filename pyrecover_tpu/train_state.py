@@ -171,6 +171,126 @@ def chunked_loss(params, tokens, labels, model_config, chunk_size):
     return chunked_ce(params, hidden, labels, model_config, chunk_size)
 
 
+def exit_distribution(gate_logits):
+    """Exit probabilities of a looped model from its gate's logits
+    (T, ...): ``p_1 = l_1``, ``p_t = l_t * prod_{j<t}(1 - l_j)``, the last
+    pass taking the remainder ``prod_{j<T}(1 - l_j)`` so that the T sum to
+    one (``l = sigmoid(logit)``). Returns ``(p, log p)``, both f32, taken
+    through log-sigmoids so no product underflows."""
+    g = gate_logits.astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-g), axis=0)  # log prod_{j<=t}(1-l_j)
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]], axis=0)
+    log_p = jnp.concatenate(
+        [before[:-1] + jax.nn.log_sigmoid(g[:-1]), before[-1:]], axis=0
+    )
+    return jnp.exp(log_p), log_p
+
+
+def chunked_exit_loss(params, hiddens, gate_logits, labels, model_config,
+                      chunk_size):
+    """Expected loss over the exits of a looped model, per valid token
+    ``sum_t p_t CE(logits_t, y) - beta H(p)``, the head's product and its
+    cross-entropy taken T times a sequence chunk with the per-token
+    weights ``p_t`` — no (batch, seq, vocab) logits ever exist, and the
+    gate gets its gradient through ``p_t`` and ``H``.
+
+    ``hiddens`` (T, B, S, D), ``gate_logits`` (T, B, S). Returns
+    ``(objective, n_valid, stats)``, means over the valid tokens; ``stats``
+    is ONE f32 vector ``[expected_ce, loop_ce (T), exit_mass (T),
+    exit_entropy]`` (:func:`exit_stats_fields` names its parts): the expected
+    cross-entropy, the plain cross-entropy of every pass, the mean of
+    ``p_t`` and the mean entropy. One vector because the trainer fetches it
+    in the loss's place, in one transfer.
+    """
+    from pyrecover_tpu.models.llama import project_vocab
+
+    T, b, s, d = hiddens.shape
+    with jax.named_scope("exit_head_loss"):
+        valid = labels != IGNORE_INDEX
+        n_valid = jnp.sum(valid)
+        p, log_p = exit_distribution(gate_logits)
+        p = jnp.where(valid[None], p, 0.0)
+        entropy = -jnp.sum(p * log_p)
+
+        chunk = chunk_size if 0 < chunk_size < s and s % chunk_size == 0 else s
+        n = s // chunk
+        # (T, B, S, ...) -> (T * n, B, chunk, ...): one compiled head body
+        # mapped over every (pass, chunk) pair
+        split = lambda a: jnp.moveaxis(
+            a.reshape(T, b, n, chunk, *a.shape[3:]), 2, 1
+        ).reshape(T * n, b, chunk, *a.shape[3:])
+        l_chunks = jnp.tile(
+            jnp.moveaxis(labels.reshape(b, n, chunk), 1, 0), (T, 1, 1)
+        )
+
+        # remat per chunk, as chunked_ce_sum: the backward recomputes one
+        # chunk's logits instead of saving T x (b, s, vocab) of them
+        @jax.checkpoint
+        def per_chunk(args):
+            h, lab, w = args
+            logits = project_vocab(params, h, model_config)
+            ok = lab != IGNORE_INDEX
+            logprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            ce = jnp.where(
+                ok, -_token_logprob(logprobs, jnp.where(ok, lab, 0)), 0.0
+            )
+            return jnp.sum(w * ce), jnp.sum(ce)
+
+        weighted, plain = jax.lax.map(
+            per_chunk, (split(hiddens), l_chunks, split(p))
+        )
+        stats = jnp.concatenate([
+            jnp.sum(weighted)[None],
+            jnp.sum(plain.reshape(T, n), axis=1),
+            jnp.sum(p, axis=(1, 2)),
+            entropy[None],
+        ]) / jnp.maximum(n_valid, 1).astype(jnp.float32)
+        objective = stats[0] - model_config.exit_beta * stats[-1]
+    return objective, n_valid, stats
+
+
+def exit_stats_fields(stats):
+    """The parts of :func:`chunked_exit_loss`'s vector by name, as the
+    ``train_sync`` event carries them (the expected cross-entropy, its first
+    entry, is the event's ``loss``). ``stats``: a host list, or None for a
+    model without an exit gate, which gives no fields."""
+    if stats is None:
+        return {}
+    T = (len(stats) - 2) // 2
+    return {
+        "loop_ce": [round(x, 6) for x in stats[1:1 + T]],
+        "exit_mass": [round(x, 6) for x in stats[1 + T:1 + 2 * T]],
+        "exit_entropy": round(stats[-1], 6),
+    }
+
+
+def model_loss(params, inputs, labels, segments, model_config, chunk_size):
+    """Forward + the model's own token loss: ``(objective, ce, n_valid,
+    moe_aux, stats)``, ``objective`` and ``ce`` means over the valid
+    tokens. A plain model's objective IS its cross-entropy and ``stats``
+    is None; an exit-gated looped model's is :func:`chunked_exit_loss`,
+    ``ce`` the expected cross-entropy (what the step reports as ``loss``,
+    and ``stats[0]``). The MoE aux term is the caller's to add."""
+    from pyrecover_tpu.models.llama import (
+        forward_hidden_with_aux,
+        forward_passes_with_aux,
+    )
+
+    if not model_config.exit_gate:
+        hidden, moe_aux = forward_hidden_with_aux(
+            params, inputs, model_config, segment_ids=segments
+        )
+        ce, n = chunked_ce(params, hidden, labels, model_config, chunk_size)
+        return ce, ce, n, moe_aux, None
+    hiddens, gates, moe_aux = forward_passes_with_aux(
+        params, inputs, model_config, segment_ids=segments
+    )
+    objective, n, stats = chunked_exit_loss(
+        params, hiddens, gates, labels, model_config, chunk_size
+    )
+    return objective, stats[0], n, moe_aux, stats
+
+
 def _pipelined_1f1b_value_and_grad(params, batch, model_config,
                                    loss_chunk_size):
     """Manual value-and-grad through the explicit 1F1B pipeline schedule
@@ -390,6 +510,12 @@ def make_train_step(model_config, optimizer, donate=True, loss_chunk_size=0,
         raise ValueError(
             f"grad_bucket_mb must be >= 0, got {grad_bucket_mb}"
         )
+    if (use_quant or bucket_mb > 0) and model_config.exit_gate:
+        raise ValueError(
+            "--grad-allreduce bf16/int8 and --grad-bucket-mb cannot train "
+            "an exit-gated model: the explicit gradient sync sums one "
+            "cross-entropy per replica, not the expected loss over the exits"
+        )
     if (use_quant or bucket_mb > 0) and model_config.pp_schedule == "1f1b":
         raise ValueError(
             "--grad-allreduce bf16/int8 and --grad-bucket-mb compose with "
@@ -411,21 +537,22 @@ def make_train_step(model_config, optimizer, donate=True, loss_chunk_size=0,
 
     def micro_loss(params, inputs, labels, segments, n_total, rows_total):
         """Micro-batch objective: ``Σ_chunk CE / N_total`` (+ row-weighted
-        aux). Its grads SUM over micro-steps to the full-batch grads."""
-        from pyrecover_tpu.models.llama import forward_hidden_with_aux
-
-        hidden, moe_aux = forward_hidden_with_aux(
-            params, inputs, model_config, segment_ids=segments
+        aux). Its grads SUM over micro-steps to the full-batch grads, as
+        do the exit statistics it returns beside the aux loss."""
+        obj, ce, n, moe_aux, stats = model_loss(
+            params, inputs, labels, segments, model_config, loss_chunk_size
         )
-        ce, n = chunked_ce(params, hidden, labels, model_config, loss_chunk_size)
-        total = ce * jnp.maximum(n, 1).astype(jnp.float32) / n_total
+        n_here = jnp.maximum(n, 1).astype(jnp.float32)
+        total = obj * n_here / n_total
         if model_config.n_experts > 0:
             # moe_aux is this micro-batch's per-row mean; reweight so the
             # sum over micro-steps is the full-batch row mean
             total = total + model_config.moe_aux_weight * moe_aux * (
                 inputs.shape[0] / rows_total
             )
-        return total, moe_aux
+        if stats is not None:
+            stats = stats * n_here / n_total
+        return total, (moe_aux, stats)
 
     def _local_value_and_grad(params, inputs, labels, segs, n_total, B):
         """Per-replica value-and-grad of the LOCAL batch shard, objective
@@ -664,6 +791,7 @@ def make_train_step(model_config, optimizer, donate=True, loss_chunk_size=0,
         # explicit collective, mesh or not)
         use_explicit = use_quant or (layout is not None and data_n > 1)
         new_residual = state.grad_residual
+        exit_stats = None  # the explicit-sync and 1f1b paths refuse a gate
         if use_explicit:
             grads, loss, n_valid, moe_aux, new_residual = _quantized_grads(
                 state, batch, segments, layout, order
@@ -674,24 +802,18 @@ def make_train_step(model_config, optimizer, donate=True, loss_chunk_size=0,
             )
         elif A == 1:
             def loss_fn(params):
-                from pyrecover_tpu.models.llama import forward_hidden_with_aux
-
-                hidden, moe_aux = forward_hidden_with_aux(
-                    params, batch["inputs"], model_config,
-                    segment_ids=segments,
+                obj, ce, n_valid, moe_aux, stats = model_loss(
+                    params, batch["inputs"], batch["labels"], segments,
+                    model_config, loss_chunk_size,
                 )
-                ce, n_valid = chunked_ce(
-                    params, hidden, batch["labels"], model_config,
-                    loss_chunk_size,
-                )
-                total = ce
+                total = obj
                 if model_config.n_experts > 0:
-                    total = ce + model_config.moe_aux_weight * moe_aux
-                return total, (ce, n_valid, moe_aux)
+                    total = obj + model_config.moe_aux_weight * moe_aux
+                return total, (ce, n_valid, moe_aux, stats)
 
-            (_, (loss, n_valid, moe_aux)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(state.params)
+            (_, (loss, n_valid, moe_aux, exit_stats)), grads = (
+                jax.value_and_grad(loss_fn, has_aux=True)(state.params)
+            )
         else:
             B = batch["inputs"].shape[0]
             if B % A:
@@ -710,15 +832,18 @@ def make_train_step(model_config, optimizer, donate=True, loss_chunk_size=0,
 
             def micro(acc, xs):
                 inp, lab, sg = xs if segs is not None else (*xs, None)
-                (obj, moe_aux), g = jax.value_and_grad(
+                (obj, (moe_aux, stats)), g = jax.value_and_grad(
                     micro_loss, has_aux=True
                 )(state.params, inp, lab, sg, n_total, float(B))
-                acc_g, acc_obj, acc_aux = acc
+                acc_g, acc_obj, acc_aux, acc_stats = acc
                 acc_g = jax.tree_util.tree_map(
                     lambda a, b: a + b.astype(jnp.float32), acc_g, g
                 )
+                if stats is not None:
+                    acc_stats = acc_stats + stats
                 return (acc_g, acc_obj + obj,
-                        acc_aux + moe_aux * (inp.shape[0] / B)), None
+                        acc_aux + moe_aux * (inp.shape[0] / B),
+                        acc_stats), None
 
             zero_g = jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params
@@ -726,8 +851,12 @@ def make_train_step(model_config, optimizer, donate=True, loss_chunk_size=0,
             xs = (
                 (inputs, labels) if segs is None else (inputs, labels, segs)
             )
-            (grads, obj, moe_aux), _ = jax.lax.scan(
-                micro, (zero_g, jnp.float32(0), jnp.float32(0)), xs,
+            zero_stats = None if not model_config.exit_gate else jnp.zeros(
+                (2 * model_config.loop_steps + 2,), jnp.float32
+            )
+            (grads, obj, moe_aux, exit_stats), _ = jax.lax.scan(
+                micro,
+                (zero_g, jnp.float32(0), jnp.float32(0), zero_stats), xs,
             )
             grads = jax.tree_util.tree_map(
                 lambda g, p: g.astype(p.dtype), grads, state.params
@@ -736,6 +865,10 @@ def make_train_step(model_config, optimizer, donate=True, loss_chunk_size=0,
             loss = obj
             if model_config.n_experts > 0:
                 loss = obj - model_config.moe_aux_weight * moe_aux
+            if model_config.exit_gate:
+                # the objective holds the entropy bonus; `loss` stays a
+                # cross-entropy (the expected one), as without accumulation
+                loss = exit_stats[0]
 
         # zero1's decomposed update lives INSIDE the optimizer chain
         # (optim.zero1_wrap, placed after global-norm clipping so the norm
@@ -763,6 +896,11 @@ def make_train_step(model_config, optimizer, donate=True, loss_chunk_size=0,
             "grad_norm": grad_norm,
             "moe_aux": moe_aux,
         }
+        if model_config.exit_gate:
+            # [loss, loop_ce (T), exit_mass (T), entropy]: the trainer's
+            # loss sync fetches THIS in the loss's place, so the exit
+            # statistics cost no transfer of their own
+            metrics["exit_stats"] = exit_stats
         return new_state, metrics
 
     donate_argnums = (0,) if donate else ()

@@ -108,5 +108,8 @@ def get_num_flop_per_token(num_params, n_layers, n_heads, head_dim, seq_len):
 
     6N covers fwd+bwd matmul FLOPs on non-embedding params; the second term
     is the attention score/value FLOPs which scale with sequence length.
+    For a looped model the caller passes the weights a token is multiplied
+    with (``loop_steps`` x the held parameters) and ``layer_passes`` for
+    ``n_layers`` (metrics.ThroughputMeter).
     """
     return 6 * num_params + 12 * n_layers * n_heads * head_dim * seq_len

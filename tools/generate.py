@@ -116,6 +116,11 @@ def main(argv=None):
     ap.add_argument("--model-kv-heads", type=int, default=0)
     ap.add_argument("--max-seq-len", type=int, default=0)
     ap.add_argument("--multiple-of", type=int, default=0)
+    ap.add_argument("--model-loop-steps", type=int, default=1,
+                    help="a looped checkpoint: the trainer's flags of the "
+                         "same names (custom shape only)")
+    ap.add_argument("--model-post-norms", action="store_true")
+    ap.add_argument("--model-exit-gate", action="store_true")
     ap.add_argument("--prompt-ids", default="1",
                     help="comma-separated token ids; ';' separates a BATCH "
                          "of equal-length prompts decoded in lockstep "
@@ -144,6 +149,9 @@ def main(argv=None):
                 vocab_size=args.vocab_size or 32768,
                 max_seq_len=args.max_seq_len or 2048,
                 multiple_of=args.multiple_of or 1024,
+                loop_steps=args.model_loop_steps,
+                post_norms=args.model_post_norms,
+                exit_gate=args.model_exit_gate,
             )
         else:
             if any(shape_flags) or args.multiple_of:
