@@ -15,17 +15,31 @@ A checkpoint directory holds two items: ``state`` (the sharded pytree) and
 reference's `metadata={epoch,step}` planner state (checkpoint.py:254-258).
 
 ``meta`` also carries ``leaf_digests``: the BLAKE2b-128 digest of every
-fully-addressable ``.params`` leaf, the serving restore's tamper gate. The
-save call only copies those leaves to the host (all transfers in flight at
-once, under the ``ckpt_digest`` span): that much must happen before the
-next step donates the state's buffers. An async save hashes the copies in
-a commit future of the same Orbax save (``_MetaHandler``), off the loop's
-thread (the retroactive ``ckpt_digest_background`` span), and Orbax's
-atomic rename waits for it: no checkpoint commits without its digests, and
-a failed hash fails the save like a failed write. A sync save has no later
-commit and hashes inline, inside ``ckpt_digest``.
+fully-addressable ``.params`` leaf, the serving restore's tamper gate.
+
+The state crosses the host link ONCE a save. The save call takes one
+snapshot of it into host memory (``_snapshot``, under ``ckpt_serialize``):
+``copy_to_host_async()`` on the array of every shard this process writes,
+a bounded number of bytes in flight, then waits for them. The runtime keeps
+each host copy on the shard's own array object, and both later readers ask
+those objects: the digests (``_host_leaf``) and Orbax's serialization
+(``addressable_shards[i].data`` of the replica it writes), whose save call
+therefore finds every transfer done and only dispatches the write. After
+the snapshot nothing reads the device, so the caller may donate or delete
+the state the moment ``save`` returns. A leaf that is no ``jax.Array`` is on
+the host already; a leaf with several replicas is cut up on its devices by
+Orbax (replica-parallel: every replica writes a part) and goes that way as
+before, one replica of it snapshotted only where it needs a digest.
+
+An async save hashes the snapshot's ``.params`` leaves in a commit future
+of the same Orbax save (``_MetaHandler``), off the loop's thread (the
+retroactive ``ckpt_digest_background`` span), and Orbax's atomic rename
+waits for it: no checkpoint commits without its digests, and a failed hash
+fails the save like a failed write. A sync save has no later commit and
+hashes inline, inside ``ckpt_digest``.
 """
 
+import collections
 import dataclasses
 import json
 import threading
@@ -102,14 +116,80 @@ def _nbytes(leaves):  # jaxlint: host-only
     return int(sum(getattr(leaf, "nbytes", 0) for leaf in leaves))
 
 
-def _host_copies(digestable):  # jaxlint: host-only
-    """``[(key, host array)]``: every transfer is started before the first
-    is waited for. Nothing reads the device after this returns, so the
-    caller may donate or delete the leaves."""
-    for _, leaf in digestable:
-        if isinstance(leaf, jax.Array):
-            leaf.copy_to_host_async()
-    return [(key, np.asarray(leaf)) for key, leaf in digestable]
+# What the copy waits for is the host's first touch of the fresh buffers it
+# lands in, and the runtime touches the more of them at once the more
+# transfers are under way: on a TPU v5e host the 6.8 GB Mistral state takes
+# 7.2-9.4 s with 0.5-1 GiB in flight, 4.3-5.2 s with 2-4 GiB, and 6.4-10.3 s
+# with all of it (PERF.md §6, PR 28). So the snapshot starts a transfer only
+# while fewer bytes than this are under way
+IN_FLIGHT_BYTES = 3 * 1024**3
+
+
+def _start_host_copy(arr):  # jaxlint: host-only
+    """The one place a save starts a device→host transfer (the tests count
+    the calls); the runtime keeps the copy on ``arr``."""
+    arr.copy_to_host_async()
+
+
+def _await_host_copy(arr):  # jaxlint: host-only
+    """Wait for ``arr``'s transfer; its host copy, which ``arr`` keeps."""
+    return np.asarray(arr)
+
+
+def _written_shards(leaf):  # jaxlint: host-only
+    """The shard arrays of ``leaf`` that this process's save reads: those
+    of replica 0, the very objects Orbax's serialization asks for."""
+    return [s for s in leaf.addressable_shards if s.replica_id == 0]
+
+
+def _has_replicas(leaf):  # jaxlint: host-only
+    """More devices than distinct shards: Orbax cuts such a leaf up on its
+    devices and every replica sends a part (its replica-parallel rule)."""
+    index_of = leaf.sharding.devices_indices_map(leaf.shape)
+    return len(index_of) > len(set(map(str, index_of.values())))
+
+
+def _snapshot(leaves, digestable):  # jaxlint: host-only
+    """Copy to the host, once, every shard array the save's readers will
+    ask for (Orbax's serialization, and the hash of the ``digestable``
+    leaves), with at most IN_FLIGHT_BYTES under way, and wait for them.
+    Returns ``(bytes copied, leaves left as they are)``. Nothing reads the
+    device after this returns."""
+    hashed = {id(leaf) for _, leaf in digestable}
+    flying, in_flight, copied, left = collections.deque(), 0, 0, 0
+    for leaf in leaves:
+        if not isinstance(leaf, jax.Array) or (
+            id(leaf) not in hashed and _has_replicas(leaf)
+        ):
+            left += 1
+            continue
+        for shard in _written_shards(leaf):
+            arr = shard.data
+            while flying and in_flight + arr.nbytes > IN_FLIGHT_BYTES:
+                head = flying.popleft()
+                _await_host_copy(head)
+                in_flight -= head.nbytes
+            _start_host_copy(arr)
+            flying.append(arr)
+            in_flight += arr.nbytes
+            copied += arr.nbytes
+    for arr in flying:
+        _await_host_copy(arr)
+    return copied, left
+
+
+def _host_leaf(leaf):  # jaxlint: host-only
+    """The whole of a fully-addressable leaf as one host array, put
+    together from the snapshot's copies of its shards."""
+    if not isinstance(leaf, jax.Array):
+        return np.asarray(leaf)
+    shards = _written_shards(leaf)
+    if len(shards) == 1:
+        return _await_host_copy(shards[0].data)
+    out = np.empty(leaf.shape, leaf.dtype)
+    for shard in shards:
+        out[shard.index] = _await_host_copy(shard.data)
+    return out
 
 
 def _leaf_digests(host_leaves):  # jaxlint: host-only
@@ -188,17 +268,23 @@ class ShardedCheckpointer:
         """Start (async) or perform (sync) a sharded save. Returns wall
         seconds spent blocking the training loop.
 
-        The blocking seconds lie under four spans, in this order:
-        ``ckpt_digest``, ``ckpt_wait_previous`` (async only),
-        ``ckpt_serialize``, ``ckpt_prune``; the manifest, the topology and
-        the fault seams read metadata only and stay outside them.
-        ``ckpt_digest`` is the host copy of the digestable ``.params``
-        leaves (``leaves``, ``bytes``) and, on a sync save, their hash; an
-        async save hands the hash of all of them (``deferred``) to its
-        commit, where it is the retroactive ``ckpt_digest_background``.
-        The state is read here and never after the return. The write that
-        goes on after the return is ``ckpt_write_background``, recorded by
-        Orbax's commit thread when it ends, the hash included."""
+        The blocking seconds lie under three spans in a row:
+        ``ckpt_wait_previous`` (async only), ``ckpt_serialize``,
+        ``ckpt_prune``; the manifest, the topology and the fault seams read
+        metadata only and stay outside them. ``ckpt_serialize`` is the
+        device→host copy of the whole state and the dispatch: first the
+        snapshot (noted on it: ``snapshot_s``; ``snapshot_bytes``, the
+        bytes the snapshot copied, against ``bytes`` the share of the state
+        it engaged on; ``fallback_leaves``, the leaves left as they are),
+        then ``ckpt_digest`` inside it, then Orbax's save call, which finds
+        the copies made. ``ckpt_digest`` is what the digests still cost the
+        loop: picking the snapshot's ``.params`` leaves (``leaves``,
+        ``bytes``) and, on a sync save, their hash; an async save hands the
+        hash of all of them (``deferred``) to its commit, where it is the
+        retroactive ``ckpt_digest_background``. The state is read by the
+        snapshot and never after it. The write that goes on after the
+        return is ``ckpt_write_background``, recorded by Orbax's commit
+        thread when it ends, the hash included."""
         t0 = time.monotonic()
         path = Path(path).absolute()
         step = (extra_meta or {}).get("step")
@@ -228,42 +314,48 @@ class ShardedCheckpointer:
         # exists to avoid; a leaf without a digest is simply not
         # verifiable on that path (single-process covers them all).
         digestable = _digestable_params(state)
-        with telemetry.span(
-            "ckpt_digest", engine="sharded", step=step,
-            leaves=len(digestable),
-            bytes=_nbytes(leaf for _, leaf in digestable),
-            deferred=len(digestable) if self.use_async else 0,
-            metric="ckpt_sharded_digest_s",
-        ):
-            # the copy cannot wait: the next step donates these buffers.
-            # The hash can, as long as the commit waits for it
-            deferred = _host_copies(digestable)
-            if not self.use_async:
-                meta["leaf_digests"] = _leaf_digests(deferred)
-                deferred = []
+        leaves = jax.tree_util.tree_leaves(state)
         if extra_meta:
             meta.update(extra_meta)
         if self.use_async:
             # Orbax's save waits for the previous save's background write
             # before it copies anything; waiting here first, where its own
-            # wait would come, gives that wait a span of its own and
-            # leaves Orbax's nothing to wait for
+            # wait would come, gives that wait a span of its own, leaves
+            # Orbax's nothing to wait for, and lets the previous write drop
+            # its snapshot before this one is taken
             with telemetry.span(
                 "ckpt_wait_previous", engine="sharded", step=step,
                 waited=self._write_in_flight(),
                 metric="ckpt_sharded_wait_previous_s",
             ):
                 self._ckptr.wait_until_finished()
-        # async saves: this span covers serialize + the device→host copy
-        # (the part the training loop pays for); the write-to-durable tail
-        # is ckpt_write_background on the commit thread, and shows up as
-        # the ckpt_wait_durable span when someone waits
+        # the part the training loop pays for: the copy cannot wait, the
+        # next step donates these buffers. The write-to-durable tail is
+        # ckpt_write_background on the commit thread, and shows up as the
+        # ckpt_wait_durable span when someone waits
         with telemetry.span(
             "ckpt_serialize", engine="sharded", path=str(path), step=step,
-            async_=self.use_async,
-            bytes=_nbytes(jax.tree_util.tree_leaves(state)),
+            async_=self.use_async, bytes=_nbytes(leaves),
             metric="ckpt_sharded_serialize_s",
-        ):
+        ) as serialize_span:
+            t_snap = time.monotonic()
+            copied, left = _snapshot(leaves, digestable)
+            serialize_span.note(
+                snapshot_s=round(time.monotonic() - t_snap, 6),
+                snapshot_bytes=copied, fallback_leaves=left,
+            )
+            with telemetry.span(
+                "ckpt_digest", engine="sharded", step=step,
+                leaves=len(digestable),
+                bytes=_nbytes(leaf for _, leaf in digestable),
+                deferred=len(digestable) if self.use_async else 0,
+                metric="ckpt_sharded_digest_s",
+            ):
+                # the hash can wait, as long as the commit waits for it
+                deferred = [(key, _host_leaf(leaf)) for key, leaf in digestable]
+                if not self.use_async:
+                    meta["leaf_digests"] = _leaf_digests(deferred)
+                    deferred = []
             self._ckptr.save(
                 path,
                 args=ocp.args.Composite(

@@ -231,19 +231,25 @@ and the ``ttft_s`` / ``tpot_s`` / ``e2e_s`` request-latency histograms
 
 Checkpoint spans (``checkpoint/sharded.py``; README "Tracing & trace
 analysis"): inside the trainer's ``ckpt_save`` the sharded engine opens,
-one after the other, ``ckpt_digest`` (each ``.params`` leaf copied to the
-host, all transfers in flight, for the BLAKE2b tamper gate; a sync save
-hashes here too, an async save hands the hash on; fields engine, step,
-leaves, bytes, deferred — the leaves whose hash was handed on),
-``ckpt_wait_previous`` (async only: the previous save's background write;
-engine, step, waited — whether one was in flight), ``ckpt_serialize``
-(Orbax's save call: device→host copy of the state and dispatch; engine,
-path, step, async_, bytes) and ``ckpt_prune`` (retention on this thread;
-engine, step, removed). They cover every blocking second of the save but
-the manifest and the fault seams. An async save's hash is a commit future
-of the same Orbax save and records a retroactive ``ckpt_digest_background``
-(engine, step, leaves, bytes) on its own thread; Orbax's commit thread,
-which waits for it and for the writes, records a retroactive
+one after the other, ``ckpt_wait_previous`` (async only: the previous
+save's background write; engine, step, waited — whether one was in
+flight), ``ckpt_serialize`` (the device→host copy of the whole state and
+the dispatch: first the one snapshot every later reader reads, then Orbax's
+save call, which finds the copies made; engine, path, step, async_, bytes,
+and, noted when the snapshot is in, snapshot_s, snapshot_bytes — the bytes
+it copied — and fallback_leaves — the leaves left as they are: on the host
+already, or replicated and cut up by Orbax on their devices) and
+``ckpt_prune`` (retention on this thread; engine, step, removed). Inside
+``ckpt_serialize``, between the snapshot and Orbax's call, lies
+``ckpt_digest``: what the BLAKE2b tamper gate still costs the loop, picking
+the snapshot's ``.params`` leaves and, on a sync save, hashing them; an
+async save hands the hash on (fields engine, step, leaves, bytes, deferred
+— the leaves whose hash was handed on). The three in a row cover every
+blocking second of the save but the manifest and the fault seams. An async
+save's hash is a commit future of the same Orbax save and records a
+retroactive ``ckpt_digest_background`` (engine, step, leaves, bytes) on its
+own thread; Orbax's commit thread, which waits for it and for the writes,
+records a retroactive
 ``ckpt_write_background`` (engine) when it ends: its start to the commit.
 Each feeds a ``ckpt_sharded_<phase>_s`` histogram.
 

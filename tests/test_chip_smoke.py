@@ -147,6 +147,7 @@ def test_widths_are_the_presets_and_the_cache_dir_is_the_packages():
     ["tools/bench_decode.py"],
     ["tools/bench_decode.py", "--serving"],
     ["tools/bench_flash_blocks.py"],
+    ["tools/ckpt_d2h_probe.py"],
 ])
 def test_timed_scripts_refuse_a_cpu(script):
     """A timed script measures the chip or nothing: on a CPU-only host it

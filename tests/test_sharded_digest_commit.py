@@ -1,8 +1,9 @@
 """The tamper gate's digests under the async sharded save: the save call
-copies the ``.params`` leaves to the host, the BLAKE2b hash runs in a commit
-future of the same Orbax save (off the caller's thread), and the checkpoint
-commits only when the digests are in ``meta/metadata``. Same algorithm, same
-leaves, the state as it was at the call."""
+snapshots the state into host memory once, the BLAKE2b hash of the
+snapshot's ``.params`` leaves runs in a commit future of the same Orbax save
+(off the caller's thread), and the checkpoint commits only when the digests
+are in ``meta/metadata``. Same algorithm, same leaves, the state as it was
+at the call."""
 
 import dataclasses
 import json
