@@ -22,7 +22,7 @@
 # Launch on every worker:
 #   gcloud compute tpus tpu-vm ssh "$TPU_NAME" --zone "$ZONE" --worker=all \
 #     --command "cd ~/pyrecover_tpu && bash launch/launch_tpu_pod.sh \
-#                --checkpoint-dir gs://my-bucket/ckpts --sharded-checkpoint \
+#                --checkpoint-dir gs://my-bucket/ckpts --checkpoint-engine sharded \
 #                --experiment_name myrun"
 
 set -euo pipefail
@@ -34,5 +34,5 @@ SCRIPT_DIR="$(cd "$(dirname "${BASH_SOURCE[0]}")" && pwd)"
 
 exec bash "${SCRIPT_DIR}/run_resilient.sh" \
   --timeaware-checkpointing \
-  --sharded-checkpoint \
+  --checkpoint-engine sharded \
   "$@"

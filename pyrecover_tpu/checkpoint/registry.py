@@ -32,7 +32,10 @@ _CKPT_RE = re.compile(r"^ckpt_(\d+)(_final)?(\.ckpt|\.zs\.json)?$")
 VANILLA_SUFFIX = ".ckpt"
 ZEROSTALL_SUFFIX = ".zs.json"
 
-ENGINES = ("vanilla", "sharded", "zerostall")
+# what each engine's checkpoint names end in (sharded: a bare directory)
+SUFFIXES = {"vanilla": VANILLA_SUFFIX, "sharded": "",
+            "zerostall": ZEROSTALL_SUFFIX}
+ENGINES = tuple(SUFFIXES)
 
 
 def engine_of(path):
@@ -47,28 +50,18 @@ def engine_of(path):
     return "vanilla"
 
 
-def _resolve_engine(sharded, engine):
-    """One engine name from the legacy ``sharded`` tristate and the
-    explicit ``engine`` parameter (which wins). None = all engines."""
-    if engine is not None:
-        if engine not in ENGINES:
-            raise ValueError(f"unknown checkpoint engine {engine!r}")
-        return engine
-    if sharded is None:
-        return None
-    return "sharded" if sharded else "vanilla"
+def _known(engine):
+    if engine is not None and engine not in SUFFIXES:
+        raise ValueError(f"unknown checkpoint engine {engine!r}")
+    return engine
 
 
 def checkpoint_path(checkpoint_dir, experiment_name, step, *, final=False,
-                    sharded=False, engine=None):
-    engine = _resolve_engine(sharded, engine) or "vanilla"
+                    engine="vanilla"):
     name = f"ckpt_{int(step)}"
     if final:
         name += "_final"
-    if engine == "vanilla":
-        name += VANILLA_SUFFIX
-    elif engine == "zerostall":
-        name += ZEROSTALL_SUFFIX
+    name += SUFFIXES[_known(engine)]
     return Path(checkpoint_dir) / experiment_name / name
 
 
@@ -78,16 +71,15 @@ def parse_step(path):
     return int(m.group(1)) if m else None
 
 
-def list_checkpoints(exp_dir, *, sharded=None, engine=None):
+def list_checkpoints(exp_dir, *, engine=None):
     """All checkpoints in ``exp_dir``, ordered oldest→newest by step.
 
     ``engine`` ("vanilla" | "sharded" | "zerostall") restricts to one
-    engine's checkpoints; the legacy ``sharded`` tristate maps True→
-    "sharded", False→"vanilla". With neither, every engine's checkpoints
-    are returned.
+    engine's checkpoints; without it, every engine's checkpoints are
+    returned.
     """
     exp_dir = Path(exp_dir)
-    want = _resolve_engine(sharded, engine)
+    want = _known(engine)
     if not exp_dir.is_dir():
         return []
     out = []
@@ -108,29 +100,28 @@ def list_checkpoints(exp_dir, *, sharded=None, engine=None):
     return [p for _, _, p in out]
 
 
-def get_latest_checkpoint(exp_dir, *, sharded=None, engine=None):
+def get_latest_checkpoint(exp_dir, *, engine=None):
     """Newest checkpoint by step number (reference checkpoint.py:371-404,
     which used mtime — step numbers are the actual intent)."""
-    ckpts = list_checkpoints(exp_dir, sharded=sharded, engine=engine)
+    ckpts = list_checkpoints(exp_dir, engine=engine)
     return ckpts[-1] if ckpts else None
 
 
-def prune_checkpoints(exp_dir, max_keep, *, sharded=None, engine=None):
+def prune_checkpoints(exp_dir, max_keep, *, engine=None):
     """Delete oldest checkpoints beyond ``max_keep`` (plus checksum
     sidecars). Returns the deleted paths.
 
-    Engine-scoped: with ``engine`` (or the legacy ``sharded`` flag) only
-    that engine's checkpoints count against ``max_keep`` — retention on
+    Engine-scoped: with ``engine`` only that engine's checkpoints
+    count against ``max_keep`` — retention on
     one engine never deletes another's. For zerostall, removing a
     manifest only drops references; the chunk bytes are reclaimed by
     ``zerostall.chunkstore.collect_garbage`` (refcounted — a chunk any
     live manifest still names is never collected)."""
     if max_keep is None or max_keep <= 0:
         return []
-    want = _resolve_engine(sharded, engine)
-    ckpts = list_checkpoints(exp_dir, engine=want)
+    ckpts = list_checkpoints(exp_dir, engine=engine)
     doomed = ckpts[:-max_keep] if len(ckpts) > max_keep else []
-    engine_label = want or "any"
+    engine_label = engine or "any"
     from pyrecover_tpu.resilience import faults
 
     for p in doomed:

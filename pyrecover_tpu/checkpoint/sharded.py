@@ -51,8 +51,14 @@ import numpy as np
 import orbax.checkpoint as ocp
 
 from pyrecover_tpu import telemetry
+from pyrecover_tpu.checkpoint.engine import (
+    PARAMS_PREFIX,
+    CheckpointEngine,
+    CheckpointIntegrityError,
+    CheckpointStructureError,
+    nest_params,
+)
 from pyrecover_tpu.checkpoint.registry import prune_checkpoints
-from pyrecover_tpu.checkpoint.vanilla import CheckpointStructureError
 from pyrecover_tpu.resilience import faults
 from pyrecover_tpu.utils.logging import log_host0
 
@@ -250,12 +256,18 @@ def _composite_handler():
     return ocp.CompositeCheckpointHandler(handler_registry=registry)
 
 
-class ShardedCheckpointer:
-    """Long-lived checkpointer; owns the async machinery. Use as a context
-    manager or call close()."""
+class ShardedCheckpointer(CheckpointEngine):
+    """The sharded engine. Long-lived; owns the async machinery. Use as a
+    context manager or call close(). ``verify`` is taken for the engines'
+    common constructor: the commit marker and the digests are not
+    optional."""
 
-    def __init__(self, use_async=True):
+    name = "sharded"
+
+    def __init__(self, use_async=True, verify=False, max_keep=None):
         self.use_async = use_async
+        self.max_keep = max_keep
+        self._in_flight = False  # a save was dispatched and nobody waited
         handler = _composite_handler()
         if use_async:
             self._ckptr = ocp.AsyncCheckpointer(handler)
@@ -264,9 +276,10 @@ class ShardedCheckpointer:
             self._ckptr = ocp.Checkpointer(handler)
 
     def save(self, path, state, sampler_state=None, *, max_keep=None,
-             extra_meta=None):
+             extra_meta=None, final=False):
         """Start (async) or perform (sync) a sharded save. Returns wall
-        seconds spent blocking the training loop.
+        seconds spent blocking the training loop; a ``final`` save then
+        waits until it is durable. ``max_keep`` overrides the engine's.
 
         The blocking seconds lie under three spans in a row:
         ``ckpt_wait_previous`` (async only), ``ckpt_serialize``,
@@ -288,6 +301,8 @@ class ShardedCheckpointer:
         t0 = time.monotonic()
         path = Path(path).absolute()
         step = (extra_meta or {}).get("step")
+        if max_keep is None:
+            max_keep = self.max_keep
         telemetry.emit(
             "ckpt_save_start", engine="sharded", path=str(path),
             async_=self.use_async,
@@ -371,6 +386,7 @@ class ShardedCheckpointer:
                 ),
                 force=True,
             )
+            self._in_flight = self.use_async
         # async saves: dispatch accepted (durability is wait()'s business);
         # sync saves: the directory is committed at this point
         telemetry.watchdog.beat("ckpt_writer")
@@ -384,7 +400,7 @@ class ShardedCheckpointer:
                     metric="ckpt_sharded_prune_s",
                 ) as prune_span:
                     removed = prune_checkpoints(
-                        path.parent, max_keep, sharded=True
+                        path.parent, max_keep, engine="sharded"
                     )
                     prune_span.note(removed=len(removed))
         blocking_s = time.monotonic() - t0
@@ -392,6 +408,8 @@ class ShardedCheckpointer:
             "ckpt_save_blocking", engine="sharded", path=str(path),
             blocking_s=round(blocking_s, 4), async_=self.use_async,
         )
+        if final:
+            self.wait()
         return blocking_s
 
     def _write_in_flight(self):
@@ -406,6 +424,7 @@ class ShardedCheckpointer:
 
     def wait(self):
         """Block until any in-flight async save is durable."""
+        self._in_flight = False
         if hasattr(self._ckptr, "wait_until_finished"):
             t0 = time.monotonic()
             with telemetry.span(
@@ -422,8 +441,22 @@ class ShardedCheckpointer:
                 wait_s=round(time.monotonic() - t0, 4),
             )
 
+    def join(self, timeout_s=None):
+        """``wait`` under the interface's name. Orbax's wait takes no
+        bound, and nothing of its write is counted as shadow seconds."""
+        self.wait()
+        return 0.0
+
+    def precheck(self, path, target_state):
+        return precheck_ckpt_sharded(path, target_state)
+
+    def load(self, path, target_state, *, prechecked=False):
+        return self.restore(path, target_state)
+
     def restore(self, path, target_state):
-        """Restore onto the shardings carried by ``target_state``'s leaves."""
+        """Restore onto the shardings carried by ``target_state``'s leaves
+        (the TARGET shardings, not the saved ones: Orbax range-reads each
+        leaf straight into its target shards — this engine's reshard)."""
         path = Path(path).absolute()
         t0 = time.monotonic()
         telemetry.emit("ckpt_restore_start", engine="sharded", path=str(path))
@@ -449,15 +482,46 @@ class ShardedCheckpointer:
         )
         return result.state, meta.get("sampler", {}), meta
 
+    def read_params(self, path):
+        """Raw (target-free) Orbax read of the ``state`` item; returns the
+        ``params`` subtree as host arrays. Verifies each leaf against the
+        content digests the save recorded in the ``meta`` item (Orbax's
+        raw read detects NO tensor corruption of its own — measured: a
+        flipped tensorstore byte loads silently) — a mismatch raises
+        before any placement."""
+        from pyrecover_tpu.checkpoint.zerostall.chunkstore import leaf_digest
+
+        path = Path(path)
+        with ocp.Checkpointer(ocp.PyTreeCheckpointHandler()) as ckptr:
+            tree = ckptr.restore(path / "state")
+        params = tree["params"] if isinstance(tree, dict) else tree.params
+        meta_file = path / "meta" / "metadata"
+        digests = {}
+        if meta_file.exists():
+            try:
+                digests = json.loads(meta_file.read_text()).get(
+                    "leaf_digests"
+                ) or {}
+            except ValueError:
+                digests = {}
+        flat = []
+        for p, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+            key = PARAMS_PREFIX + jax.tree_util.keystr(p)
+            arr = np.asarray(leaf)
+            expected = digests.get(key)
+            if expected is not None and leaf_digest(arr) != expected:
+                raise CheckpointIntegrityError(
+                    f"checkpoint {path.name}: leaf {key} fails its "
+                    "recorded content digest — tensorstore file tampered "
+                    "or bit-flipped after save"
+                )
+            flat.append((key, arr))
+        return nest_params(flat)
+
     def close(self):
-        self.wait()
+        if self._in_flight:
+            self.wait()
         self._ckptr.close()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        self.close()
 
 
 def precheck_ckpt_sharded(path, target_state=None):

@@ -41,6 +41,12 @@ import numpy as np
 from flax.serialization import msgpack_restore
 
 from pyrecover_tpu import telemetry
+from pyrecover_tpu.checkpoint.engine import (
+    CheckpointIntegrityError,
+    CheckpointStructureError,  # noqa: F401  (re-exported)
+    HandleEngine,
+    nest_params,
+)
 from pyrecover_tpu.checkpoint.registry import prune_checkpoints
 from pyrecover_tpu.parallel.mesh import state_topology, sync_global_devices
 from pyrecover_tpu.resilience import faults
@@ -50,14 +56,6 @@ from pyrecover_tpu.utils.logging import log_host0
 FORMAT_VERSION = 2
 SUPPORTED_FORMATS = (1, 2)  # v1 (msgpack) stays readable
 MAGIC = b"PYRCKPT2"
-
-
-class CheckpointStructureError(ValueError):
-    """The checkpoint decoded fine but does not FIT the target state
-    (leaf count / shape mismatch) — a configuration error, not file
-    corruption. The latest-resume fallback must NOT skip past these:
-    every candidate would fail identically and the run would silently
-    restart from step 0 with the wrong model."""
 
 
 def _leaf_to_numpy(leaf):
@@ -434,7 +432,7 @@ def _write_stream(path, leaves_iter, meta, verify, max_keep):
         write_s=round(time.monotonic() - t0, 4), checksum=bool(verify),
     )
     if max_keep:
-        prune_checkpoints(path.parent, max_keep, sharded=False)
+        prune_checkpoints(path.parent, max_keep, engine="vanilla")
 
 
 def read_ckpt_raw(path, *, check_version=True):
@@ -758,3 +756,40 @@ def load_ckpt_vanilla(path, target_state, *, verify=False):
         step=int(meta.get("step", 0)),
     )
     return state, meta.get("sampler", {}), meta
+
+
+class VanillaEngine(HandleEngine):
+    """This module's functions behind the engines' interface."""
+
+    name = "vanilla"
+    _save = staticmethod(save_ckpt_vanilla)
+    _precheck = staticmethod(precheck_ckpt_vanilla)
+
+    def load(self, path, target_state, *, prechecked=False):
+        # single-process: the pre-check just checksummed the same bytes —
+        # don't pay a second verification pass (multi-host keeps the
+        # in-load verify: hosts != 0 read the file themselves). Elastic
+        # execution for this engine: full global leaves on every host,
+        # device_put onto the target shardings (reslice + scatter).
+        verify = self.verify and not (
+            prechecked and jax.process_count() == 1
+        )
+        return load_ckpt_vanilla(path, target_state, verify=verify)
+
+    def read_params(self, path):
+        # tamper gate: the framed container catches truncation and length
+        # drift structurally, but a flipped byte INSIDE a tensor frame
+        # decodes silently — when the save left a checksum sidecar, verify
+        # it before any leaf is decoded (and long before placement)
+        sidecar = _sidecar(path)
+        if sidecar.exists():
+            expected = sidecar.read_text().strip()
+            if expected and not verify_checksum(path, expected):
+                raise CheckpointIntegrityError(
+                    f"checkpoint {Path(path).name} fails its checksum "
+                    "sidecar — file tampered or bit-flipped after save"
+                )
+        _, paths, leaves = read_ckpt_raw(path)
+        return nest_params(
+            (p, np.asarray(leaf)) for p, leaf in zip(paths, leaves)
+        )

@@ -283,6 +283,27 @@ def restore(exp_dir, target_state):  # jaxlint: host-only
     return state, doc.get("sampler", {}), doc
 
 
+class RamTier:
+    """One experiment's record, as the resume walk asks for it
+    (``CheckpointEngine.ram_tier``). The functions are looked up when
+    called, so a test that patches one on this module is obeyed."""
+
+    def __init__(self, exp_dir):
+        self.exp_dir = exp_dir
+
+    def peek(self):
+        return peek(self.exp_dir)
+
+    def usable(self, target_topology, *, min_step=0):
+        return usable(self.exp_dir, target_topology, min_step=min_step)
+
+    def verify(self, record):
+        return verify(record)
+
+    def restore(self, target_state):
+        return restore(self.exp_dir, target_state)
+
+
 def drop(exp_dir=None):
     """Forget records (all of them with no argument) — test hygiene and
     the explicit opt-out for memory-tight callers."""

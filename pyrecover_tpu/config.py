@@ -6,8 +6,6 @@ re-design, plus TPU-native extensions (mesh shape, fsdp/tensor/sequence
 axes, remat, synthetic data). Torch-specific flags are kept as accepted
 aliases so reference launch lines keep working:
 
-  * ``--use-torch-distributed-ckpt`` → alias of ``--sharded-checkpoint``
-    (Orbax-style sharded save, the `torch.distributed.checkpoint` analogue).
   * ``--fused-optimizer`` / ``--compile`` → accepted no-ops (XLA always
     compiles and fuses the optimizer into the step).
   * ``--use_flash_attention`` → selects the Pallas flash-attention kernel.
@@ -20,6 +18,7 @@ import argparse
 import dataclasses
 from typing import Optional
 
+from pyrecover_tpu.checkpoint.engine import ENGINES
 from pyrecover_tpu.models.llama import ModelConfig
 from pyrecover_tpu.parallel.mesh import MeshConfig
 
@@ -121,13 +120,11 @@ class TrainConfig:
     experiment_name: str = "default-exp"
     verify_checkpoints: bool = False
     max_kept_checkpoints: int = 3
-    sharded_checkpoint: bool = False  # --use-torch-distributed-ckpt equivalent
     # which engine writes checkpoints: "vanilla" (single-file streaming),
     # "sharded" (Orbax/tensorstore), or "zerostall" (async snapshot
     # pipeline + content-addressed chunk store + in-RAM emergency tier,
-    # checkpoint/zerostall/). "" derives from --sharded-checkpoint; an
-    # explicit value wins over the legacy boolean.
-    checkpoint_engine: str = ""  # "" | vanilla | sharded | zerostall
+    # checkpoint/zerostall/); checkpoint/engine.py maps the name
+    checkpoint_engine: str = "vanilla"  # vanilla | sharded | zerostall
     async_checkpoint: bool = True  # overlap saves with training
     # topology-elastic resume (checkpoint/elastic.py): "auto" reshards a
     # checkpoint saved on a different topology onto the live mesh (after a
@@ -269,18 +266,10 @@ class TrainConfig:
             raise ValueError(
                 f"--ckpt-auto-window must be >= 1, got {self.ckpt_auto_window}"
             )
-        # engine resolution: the explicit --checkpoint-engine wins; the
-        # legacy --sharded-checkpoint boolean is kept in sync because the
-        # sharded-specific machinery (Orbax checkpointer) keys off it
-        if not self.checkpoint_engine:
-            self.checkpoint_engine = (
-                "sharded" if self.sharded_checkpoint else "vanilla"
-            )
-        elif self.checkpoint_engine not in ("vanilla", "sharded", "zerostall"):
+        if self.checkpoint_engine not in ENGINES:
             raise ValueError(
                 f"unknown checkpoint engine {self.checkpoint_engine!r}"
             )
-        self.sharded_checkpoint = self.checkpoint_engine == "sharded"
         if self.attention_impl == "auto":
             if self.mesh.sequence > 1:
                 attn = "ring"
@@ -517,20 +506,13 @@ def build_parser():
                    type=str, default=d.experiment_name)
     p.add_argument("--verify-checkpoints", action="store_true")
     p.add_argument("--max-kept-checkpoints", type=int, default=d.max_kept_checkpoints)
-    p.add_argument("--use-torch-distributed-ckpt", "--sharded-checkpoint",
-                   dest="sharded_checkpoint", action="store_true",
-                   help="Sharded multi-host checkpoint (Orbax/tensorstore).")
-    # default None (not d.checkpoint_engine: post_init already resolved
-    # that to a concrete engine, which would silently outvote the legacy
-    # --sharded-checkpoint flag); unset defers to the boolean
-    p.add_argument("--checkpoint-engine", type=str, default=None,
-                   choices=["vanilla", "sharded", "zerostall"],
+    p.add_argument("--checkpoint-engine", type=str,
+                   default=d.checkpoint_engine, choices=ENGINES,
                    help="Checkpoint engine: vanilla single-file, sharded "
-                        "(Orbax), or zerostall (async snapshot pipeline + "
-                        "content-addressed chunk dedup + in-RAM emergency "
-                        "restore tier; the save window is invisible to the "
-                        "train loop). Default: sharded when "
-                        "--sharded-checkpoint is set, else vanilla.")
+                        "(Orbax, multi-host), or zerostall (async snapshot "
+                        "pipeline + content-addressed chunk dedup + in-RAM "
+                        "emergency restore tier; the save window is "
+                        "invisible to the train loop).")
     p.add_argument("--no-async-checkpoint", action="store_true")
     p.add_argument("--elastic-resume", type=str, default=d.elastic_resume,
                    choices=["auto", "on", "off"],
@@ -667,8 +649,7 @@ def get_args(argv=None):
         experiment_name=ns.experiment_name,
         verify_checkpoints=ns.verify_checkpoints,
         max_kept_checkpoints=ns.max_kept_checkpoints,
-        sharded_checkpoint=ns.sharded_checkpoint,
-        checkpoint_engine=ns.checkpoint_engine or "",
+        checkpoint_engine=ns.checkpoint_engine,
         async_checkpoint=not ns.no_async_checkpoint,
         elastic_resume=ns.elastic_resume,
         timeaware_checkpointing=ns.timeaware_checkpointing,

@@ -49,13 +49,12 @@ from pyrecover_tpu.checkpoint.registry import (
     get_latest_checkpoint,
     parse_step,
 )
-from pyrecover_tpu.serving.restore import (
+from pyrecover_tpu.checkpoint.engine import (
     PARAMS_PREFIX,
-    _keystr_parts,
-    _nest,
-    _place_params,
-    load_serving_params,
+    keystr_parts,
+    nest_params,
 )
+from pyrecover_tpu.serving.restore import _place_params, load_serving_params
 from pyrecover_tpu.utils.logging import log_host0
 
 
@@ -268,7 +267,7 @@ class HotSwapper:
             )},
         )
         host_cache = {p: arr for p, arr in flat}
-        nested = _nest([(_keystr_parts(p)[1:], arr) for p, arr in flat])
+        nested = nest_params(flat)
         placed = _place_params(nested, self.mesh)
         return placed, doc, host_cache, stats
 
@@ -308,7 +307,7 @@ class HotSwapper:
             path = entry["path"]
             if not path.startswith(PARAMS_PREFIX):
                 continue
-            leaf = self._params_leaf(_keystr_parts(path)[1:])
+            leaf = self._params_leaf(keystr_parts(path)[1:])
             if leaf is None:
                 continue
             cache[path] = np.asarray(leaf)

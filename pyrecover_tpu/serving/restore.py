@@ -19,7 +19,6 @@ accounting; an infeasible plan raises :class:`ServingRestoreError`
 naming every finding instead of dying mid-restore.
 """
 
-import re
 import time
 from pathlib import Path
 
@@ -27,25 +26,17 @@ import numpy as np
 
 from pyrecover_tpu import telemetry
 from pyrecover_tpu.checkpoint.elastic import preflight_elastic, read_saved_meta
-from pyrecover_tpu.checkpoint.registry import engine_of
-
-PARAMS_PREFIX = ".params"
-_KEY_RE = re.compile(r"\['([^']*)'\]|\.([A-Za-z_][A-Za-z0-9_]*)|\[(\d+)\]")
+from pyrecover_tpu.checkpoint.engine import (
+    PARAMS_PREFIX,
+    CheckpointIntegrityError,
+    engine_for_path,
+    keystr_parts,
+)
 
 
 class ServingRestoreError(RuntimeError):
     """The checkpoint cannot serve on this topology (preflight findings
     or a params subtree the manifest does not carry)."""
-
-
-def _keystr_parts(path_str):
-    """``".params['layers']['wq']"`` -> ``["params", "layers", "wq"]``."""
-    parts = []
-    for m in _KEY_RE.finditer(path_str):
-        parts.append(m.group(1) if m.group(1) is not None
-                     else m.group(2) if m.group(2) is not None
-                     else int(m.group(3)))
-    return parts
 
 
 def _params_entries(manifest):
@@ -54,7 +45,7 @@ def _params_entries(manifest):
     for entry in manifest.get("leaves", []):
         if not entry["path"].startswith(PARAMS_PREFIX):
             continue
-        parts = _keystr_parts(entry["path"])
+        parts = keystr_parts(entry["path"])
         if not parts or parts[0] != "params":
             continue
         out.append((parts[1:], entry))
@@ -66,113 +57,10 @@ def _params_entries(manifest):
     return out
 
 
-def _nest(flat):
-    """``[(key path, value)]`` -> nested dict tree (the params layout)."""
-    root = {}
-    for parts, value in flat:
-        node = root
-        for key in parts[:-1]:
-            node = node.setdefault(key, {})
-        node[parts[-1]] = value
-    return root
-
-
-def _read_params_vanilla(path):
-    from pyrecover_tpu.checkpoint.vanilla import (
-        _sidecar,
-        read_ckpt_raw,
-        verify_checksum,
-    )
-
-    # tamper gate: the framed container catches truncation and length
-    # drift structurally, but a flipped byte INSIDE a tensor frame
-    # decodes silently — when the save left a checksum sidecar, verify
-    # it before any leaf is decoded (and long before placement)
-    sidecar = _sidecar(Path(path))
-    if sidecar.exists():
-        expected = sidecar.read_text().strip()
-        if expected and not verify_checksum(path, expected):
-            raise ServingRestoreError(
-                f"checkpoint {Path(path).name} fails its checksum sidecar "
-                "— file tampered or bit-flipped after save; refusing to "
-                "serve from it"
-            )
-    _, paths, leaves = read_ckpt_raw(path)
-    flat = [
-        (_keystr_parts(p)[1:], np.asarray(leaf))
-        for p, leaf in zip(paths, leaves)
-        if p.startswith(PARAMS_PREFIX)
-    ]
-    return _nest(flat)
-
-
-def _read_params_zerostall(path):
-    from pyrecover_tpu.checkpoint.vanilla import _dtype_from_str
-    from pyrecover_tpu.checkpoint.zerostall.chunkstore import (
-        ChunkStore,
-        assemble_leaf,
-        read_manifest,
-    )
-
-    doc = read_manifest(path)
-    store = ChunkStore(Path(path).parent)
-    flat = []
-    for entry in doc["leaves"]:
-        p = entry["path"]
-        if not p.startswith(PARAMS_PREFIX):
-            continue
-        arr = assemble_leaf(store, entry, _dtype_from_str(entry["dtype"]))
-        flat.append((_keystr_parts(p)[1:], arr))
-    return _nest(flat)
-
-
 def _read_params_sharded(path):
-    """Raw (target-free) Orbax read of the ``state`` item; returns the
-    ``params`` subtree as host arrays. Verifies each leaf against the
-    content digests the save recorded in the ``meta`` item (Orbax's raw
-    read detects NO tensor corruption of its own — measured: a flipped
-    tensorstore byte loads silently) — a mismatch raises before any
-    placement."""
-    import json
-
-    import orbax.checkpoint as ocp
-
-    with ocp.Checkpointer(ocp.PyTreeCheckpointHandler()) as ckptr:
-        tree = ckptr.restore(Path(path) / "state")
-    params = tree["params"] if isinstance(tree, dict) else tree.params
-    import jax
-
-    meta_file = Path(path) / "meta" / "metadata"
-    digests = {}
-    if meta_file.exists():
-        try:
-            digests = json.loads(meta_file.read_text()).get(
-                "leaf_digests"
-            ) or {}
-        except ValueError:
-            digests = {}
-    from pyrecover_tpu.checkpoint.zerostall.chunkstore import leaf_digest
-
-    flat = []
-    for p, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
-        key = jax.tree_util.keystr(p)
-        arr = np.asarray(leaf)
-        expected = digests.get(f"{PARAMS_PREFIX}{key}")
-        if expected is not None and leaf_digest(arr) != expected:
-            raise ServingRestoreError(
-                f"checkpoint {Path(path).name}: leaf .params{key} fails "
-                "its recorded content digest — tensorstore file tampered "
-                "or bit-flipped after save; refusing to serve from it"
-            )
-        flat.append((_keystr_parts(key), arr))
-    return _nest(flat)
-
-
-_READERS = {
-    "vanilla": _read_params_vanilla,
-    "sharded": _read_params_sharded,
-    "zerostall": _read_params_zerostall,
-}
+    """The sharded engine's reader, under the name its tests import."""
+    with engine_for_path(path) as engine:
+        return engine.read_params(path)
 
 
 def serving_topology(mesh=None):
@@ -217,7 +105,6 @@ def load_serving_params(path, model_config, *, mesh=None,  # jaxlint: host-only
     """
     path = Path(path)
     t0 = time.monotonic()
-    engine = engine_of(path)
     meta = read_saved_meta(path)
     from pyrecover_tpu.analysis.shardcheck.manifest import (
         manifest_from_ckpt_meta,
@@ -243,14 +130,19 @@ def load_serving_params(path, model_config, *, mesh=None,  # jaxlint: host-only
             + "; ".join(f"{f.rule_id}: {f.message}" for f in findings[:4])
         )
 
-    with telemetry.span(
-        "serving_restore", engine=engine, path=str(path),
+    with engine_for_path(path) as engine, telemetry.span(
+        "serving_restore", engine=engine.name, path=str(path),
         metric="serving_restore_s",
     ):
-        host_params = _READERS[engine](path)
+        try:
+            host_params = engine.read_params(path)
+        except CheckpointIntegrityError as e:
+            raise ServingRestoreError(
+                f"{e}; refusing to serve from it"
+            ) from e
         placed = _place_params(host_params, mesh)
     info = {
-        "engine": engine, "step": int(meta.get("step", 0)),
+        "engine": engine.name, "step": int(meta.get("step", 0)),
         "leaves": len(entries),
         "bytes": int(plan.total_bytes),
         "resharded_leaves": int(plan.resharded_leaves),

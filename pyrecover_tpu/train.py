@@ -21,15 +21,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from pyrecover_tpu import telemetry
 from pyrecover_tpu.telemetry import detectors
-from pyrecover_tpu.checkpoint import (
-    ShardedCheckpointer,
-    checkpoint_path,
-    list_checkpoints,
-    load_ckpt_vanilla,
-    load_ckpt_zerostall,
-    save_ckpt_vanilla,
-    save_ckpt_zerostall,
-)
+from pyrecover_tpu.checkpoint import checkpoint_path, list_checkpoints
+from pyrecover_tpu.checkpoint.engine import open_engine
 from pyrecover_tpu.config import TrainConfig, get_args
 from pyrecover_tpu.data import DataLoader, StatefulSampler, SyntheticTextDataset
 from pyrecover_tpu.metrics import LossCSVLogger, ThroughputMeter, WallTimeTotals
@@ -263,15 +256,16 @@ def build_eval_runner(config, model_config, pad_token_id, mesh):
     return run_eval
 
 
-def _resume(config, exp_dir, state, sampler, sharded_ckptr, totals):  # jaxlint: sync-point
+def _resume(config, exp_dir, state, sampler, engine, totals):  # jaxlint: sync-point
     """Resume from ``config.resume_from_checkpoint`` (reference
     train.py:195-212). Returns ``(start_step, state)``.
 
     "latest" walks candidates newest→oldest and FALLS BACK past a
     corrupt/truncated/torn checkpoint — exactly what a crash during or
-    after the newest save leaves behind, on EITHER engine (vanilla
-    single-file or sharded/Orbax); the integrity pre-check catches it and
-    the fallback turns it into a recovery instead of a dead job.
+    after the newest save leaves behind, on EVERY engine; the integrity
+    pre-check catches it and the fallback turns it into a recovery
+    instead of a dead job. ``engine`` is the run's checkpoint engine
+    (``checkpoint/engine.py``); None opens one from ``config``.
     Multi-host safety: corruption is judged by a host-LOCAL pre-check on
     host 0 and the verdict broadcast, so every host enters the collective
     load for the SAME candidate (a per-host exception inside the load
@@ -292,9 +286,9 @@ def _resume(config, exp_dir, state, sampler, sharded_ckptr, totals):  # jaxlint:
     mesh. With ``--elastic-resume off`` a topology drift raises a typed
     ``TopologyMismatchError`` naming both topologies.
 
-    Zerostall engine only: the in-RAM emergency tier
-    (``checkpoint/zerostall/emergency.py``) is consulted FIRST on a
-    "latest" resume. When host 0 holds a committed snapshot that is at
+    An engine with a RAM tier (``engine.ram_tier``; zerostall's
+    ``checkpoint/zerostall/emergency.py``): the tier is consulted FIRST on
+    a "latest" resume. When host 0 holds a committed snapshot that is at
     least as fresh as the newest disk manifest, on the SAME topology,
     and its recomputed chunk digests match the committed manifest, the
     restore happens from RAM in milliseconds — the disk tier (possibly
@@ -306,27 +300,24 @@ def _resume(config, exp_dir, state, sampler, sharded_ckptr, totals):  # jaxlint:
     path, so one host privately rejoining the disk walk would leave its
     verdict collectives one participant short (deadlock).
     """
-    from pyrecover_tpu.checkpoint import elastic, precheck_ckpt_sharded
+    from pyrecover_tpu.checkpoint import elastic
     from pyrecover_tpu.checkpoint.elastic import TopologyMismatchError
+    from pyrecover_tpu.checkpoint.engine import CheckpointStructureError
     from pyrecover_tpu.checkpoint.registry import parse_step
-    from pyrecover_tpu.checkpoint.vanilla import (
-        CheckpointStructureError,
-        precheck_ckpt_vanilla,
-    )
-    from pyrecover_tpu.checkpoint.zerostall import (
-        emergency,
-        precheck_ckpt_zerostall,
-    )
     from pyrecover_tpu.parallel.mesh import (
         broadcast_host0_obj,
         broadcast_host0_scalar,
         state_topology,
     )
 
+    if engine is None:
+        with open_engine(config) as engine:
+            return _resume(config, exp_dir, state, sampler, engine, totals)
     t0 = time.monotonic()
-    engine = config.checkpoint_engine
     target = config.resume_from_checkpoint
     explicit = target != "latest"
+    # None or not on EVERY host alike, whatever record each holds
+    tier = None if explicit else engine.ram_tier(exp_dir)
     if explicit:
         candidates = [target]
     else:
@@ -336,7 +327,8 @@ def _resume(config, exp_dir, state, sampler, sharded_ckptr, totals):  # jaxlint:
         # shared-FS stragglers) would have hosts exchanging verdicts
         # about DIFFERENT checkpoints. Host 0's listing is authoritative.
         candidates = broadcast_host0_obj(
-            [str(p) for p in list_checkpoints(exp_dir, engine=engine)[::-1]]
+            [str(p) for p in
+             list_checkpoints(exp_dir, engine=engine.name)[::-1]]
         )
         if not candidates:
             # the "anything at all to restore?" decision must also be
@@ -344,9 +336,9 @@ def _resume(config, exp_dir, state, sampler, sharded_ckptr, totals):  # jaxlint:
             # per-host peek here would send host 0 into the use_ram
             # broadcast below while every peer had already returned fresh
             have_ram = 0
-            if engine == "zerostall":
+            if tier is not None:
                 if jax.process_index() == 0:
-                    have_ram = int(emergency.peek(exp_dir) is not None)
+                    have_ram = int(tier.peek() is not None)
                 have_ram = int(broadcast_host0_scalar(have_ram))
             if not have_ram:
                 log_host0(
@@ -354,18 +346,18 @@ def _resume(config, exp_dir, state, sampler, sharded_ckptr, totals):  # jaxlint:
                 )
                 return 0, state
 
-    # ---- in-RAM emergency tier (zerostall, "latest" only) ------------------
+    # ---- in-RAM emergency tier ---------------------------------------------
     # host-0 gate: fresh enough (>= newest disk manifest), same topology,
     # digests intact; verdict broadcast so every host takes the same path
-    if engine == "zerostall" and not explicit:
+    if tier is not None:
         use_ram = 0
         if jax.process_index() == 0:
             best_disk = parse_step(candidates[0]) if candidates else -1
-            record = emergency.usable(
-                exp_dir, state_topology(state), min_step=max(best_disk, 0)
+            record = tier.usable(
+                state_topology(state), min_step=max(best_disk, 0)
             )
             if record is not None:
-                ok, reason = emergency.verify(record)
+                ok, reason = tier.verify(record)
                 if ok:
                     use_ram = 1
                 else:
@@ -379,7 +371,7 @@ def _resume(config, exp_dir, state, sampler, sharded_ckptr, totals):  # jaxlint:
                     )
         if int(broadcast_host0_scalar(use_ram)) == 1:
             try:
-                state, sampler_meta, doc = emergency.restore(exp_dir, state)
+                state, sampler_meta, doc = tier.restore(state)
             except Exception as e:
                 # verified on host 0 a moment ago — reaching here means a
                 # race/rot between gate and restore; disk is the truth
@@ -440,24 +432,7 @@ def _resume(config, exp_dir, state, sampler, sharded_ckptr, totals):  # jaxlint:
                     elastic.GATE_MISMATCH: 4,
                 }[gate]
                 if verdict in (1, 5) and not explicit:
-                    if engine == "sharded":
-                        ok, why = precheck_ckpt_sharded(cand, state)
-                    elif engine == "zerostall":
-                        # manifest + per-chunk existence/size (digest
-                        # rehash with --verify-checkpoints); the schema
-                        # diff dies on a wrong-model resume here
-                        ok, why = precheck_ckpt_zerostall(
-                            cand, verify=config.verify_checkpoints,
-                            target_state=state,
-                        )
-                    else:
-                        # target_state activates the manifest schema diff:
-                        # a wrong-model resume dies on a header read here,
-                        # not minutes later mid-restore
-                        ok, why = precheck_ckpt_vanilla(
-                            cand, verify=config.verify_checkpoints,
-                            target_state=state,
-                        )
+                    ok, why = engine.precheck(cand, state)
                     if not ok:
                         verdict, reason = 0, why
             # faultcheck: disable-next=recovery-swallow -- not a swallow:
@@ -527,35 +502,9 @@ def _resume(config, exp_dir, state, sampler, sharded_ckptr, totals):  # jaxlint:
         )
         try:
             with reshard_span:
-                if engine == "sharded":
-                    # per-leaf reads with the TARGET shardings (not the
-                    # saved ones): Orbax range-reads each leaf straight
-                    # into its target shards — the sharded engine's
-                    # reshard execution
-                    state, sampler_meta, meta = sharded_ckptr.restore(
-                        cand, state
-                    )
-                elif engine == "zerostall":
-                    # chunk reads re-verify their content digests; leaves
-                    # assemble host-side and device_put onto the TARGET
-                    # shardings (elastic execution identical to vanilla)
-                    state, sampler_meta, meta = load_ckpt_zerostall(
-                        cand, state
-                    )
-                else:
-                    # single-process: the pre-check just checksummed the
-                    # same bytes — don't pay a second verification pass
-                    # (multi-host keeps the in-load verify: hosts != 0
-                    # read the file themselves). Elastic execution for
-                    # this engine: full global leaves on every host,
-                    # device_put onto the target shardings (reslice +
-                    # scatter).
-                    verify = config.verify_checkpoints and not (
-                        prechecked and jax.process_count() == 1
-                    )
-                    state, sampler_meta, meta = load_ckpt_vanilla(
-                        cand, state, verify=verify
-                    )
+                state, sampler_meta, meta = engine.load(
+                    cand, state, prechecked=prechecked
+                )
         except Exception as e:
             if (
                 explicit
@@ -853,46 +802,14 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
     # to cpu is an error: platform_fallback event, then raise
     detectors.check_expected_accelerator()
 
-    sharded_ckptr = (
-        ShardedCheckpointer(use_async=config.async_checkpoint)
-        if config.sharded_checkpoint
-        else None
-    )
-
-    # ---- checkpoint strategy dispatch (reference train.py:153-161) ---------
-    engine = config.checkpoint_engine
-    pending_saves = []  # at most one in-flight background save handle
-
-    def join_pending_saves(timeout_s=None):
-        """Join every in-flight background save handle. Mid-run callers
-        pass no timeout (the next save must serialize behind the previous
-        commit); the train() unwind passes a bounded one so a wedged disk
-        cannot turn teardown into a hang. Every join emits a
-        ``ckpt_bg_join`` event — the regression trail proving no
-        non-daemon checkpoint work is abandoned at exit."""
-        while pending_saves:
-            handle = pending_saves.pop()
-            t0 = time.monotonic()
-            try:
-                handle.wait(timeout=timeout_s)
-            finally:
-                telemetry.emit(
-                    "ckpt_bg_join", engine=engine,
-                    waited_s=round(time.monotonic() - t0, 4),
-                    completed=bool(handle.done),
-                    ok=handle.error is None,
-                    bounded=timeout_s is not None,
-                )
-                # background seconds the train loop did NOT pay for: the
-                # goodput ledger's recovered-overlap bucket
-                totals.ckpt_shadow_s += (
-                    getattr(handle, "shadow_s", 0.0) or 0.0
-                )
+    # ---- checkpoint engine (reference train.py:153-161) --------------------
+    # at most one save is in flight, and the engine serialises behind it
+    engine = open_engine(config)
 
     def save_ckpt(step, final=False):
         path = checkpoint_path(
             config.checkpoint_dir, config.experiment_name, step,
-            final=final, engine=engine,
+            final=final, engine=engine.name,
         )
         # mesh-replicated GLOBAL scalar, like every other state leaf: a
         # bare jnp.asarray would be host-local, which the multi-host
@@ -921,52 +838,14 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
         if watcher is not None:
             watcher.arm_escalation(exp_dir, step)
         save_span = telemetry.spans.begin(
-            "ckpt_save", step=int(step), final=bool(final), engine=engine,
+            "ckpt_save", step=int(step), final=bool(final),
+            engine=engine.name,
         )
         try:
-            if engine == "sharded":
-                secs = sharded_ckptr.save(
-                    path, state_to_save, sampler_meta,
-                    max_keep=config.max_kept_checkpoints, extra_meta=extra,
-                )
-                if final:
-                    sharded_ckptr.wait()
-            elif engine == "zerostall":
-                # the engine's own depth-1 queue back-pressures too, but
-                # joining here keeps handle shadow accounting in order
-                join_pending_saves()
-                if config.async_checkpoint and not final:
-                    secs, handle = save_ckpt_zerostall(
-                        path, state_to_save, sampler_meta,
-                        verify=config.verify_checkpoints,
-                        max_keep=config.max_kept_checkpoints,
-                        extra_meta=extra, background=True,
-                    )
-                    pending_saves.append(handle)
-                else:
-                    secs = save_ckpt_zerostall(
-                        path, state_to_save, sampler_meta,
-                        verify=config.verify_checkpoints,
-                        max_keep=config.max_kept_checkpoints,
-                        extra_meta=extra, background=False,
-                    )
-            else:
-                join_pending_saves()  # serialize with any in-flight write
-                if config.async_checkpoint and not final:
-                    secs, handle = save_ckpt_vanilla(
-                        path, state_to_save, sampler_meta,
-                        verify=config.verify_checkpoints,
-                        max_keep=config.max_kept_checkpoints,
-                        extra_meta=extra, background=True,
-                    )
-                    pending_saves.append(handle)
-                else:
-                    secs = save_ckpt_vanilla(
-                        path, state_to_save, sampler_meta,
-                        verify=config.verify_checkpoints,
-                        max_keep=config.max_kept_checkpoints,
-                        extra_meta=extra,
-                    )
+            secs = engine.save(
+                path, state_to_save, sampler_meta, extra_meta=extra,
+                final=final,
+            )
         except BaseException as e:
             save_span.end(ok=False, error=f"{type(e).__name__}: {e}")
             raise
@@ -984,7 +863,7 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
         # step: every save_ckpt call is interval-gated by its caller
         telemetry.emit(
             "ckpt_saved", step=int(step), path=path.name, final=bool(final),
-            engine=engine, blocking_s=round(secs, 4),
+            engine=engine.name, blocking_s=round(secs, 4),
         )
         return secs
 
@@ -998,15 +877,14 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
         try:
             with telemetry.span("resume", metric="resume_s"):
                 start_step, state = _resume(
-                    config, exp_dir, state, sampler, sharded_ckptr, totals
+                    config, exp_dir, state, sampler, engine, totals
                 )
         except BaseException:
             # the teardown try/finally only starts after loader.start();
             # a failed resume (wrong model config, every-candidate-corrupt)
             # must not leak the async checkpointer's thread machinery in
             # long-lived callers
-            if sharded_ckptr is not None:
-                sharded_ckptr.close()
+            engine.close()
             raise
     if start_step > 0 and prior_step is not None and prior_step > start_step:
         telemetry.emit(
@@ -1029,7 +907,7 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
         from pyrecover_tpu.resilience.autopilot import CheckpointAutopilot
 
         autopilot = CheckpointAutopilot(
-            exp_dir, engine=engine,
+            exp_dir, engine=engine.name,
             static_interval=config.checkpoint_frequency,
             floor=config.ckpt_auto_floor,
             ceiling=config.ckpt_auto_ceiling,
@@ -1473,7 +1351,7 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
             # timeout keeps a wedged writer from hanging the unwind (the
             # daemon flag would then be what it was always meant to be:
             # the very last resort, after a loud TimeoutError)
-            join_pending_saves(timeout_s=_BG_JOIN_TIMEOUT_S)
+            engine.join(timeout_s=_BG_JOIN_TIMEOUT_S)
         except Exception:
             if not unwinding:
                 raise
@@ -1481,8 +1359,10 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
                 "in-flight background checkpoint save also failed during "
                 "error unwind", level=30,  # WARNING; the original error wins
             )
-        if sharded_ckptr is not None:
-            sharded_ckptr.close()
+        finally:
+            # every join's background seconds: the loop did not pay for them
+            totals.ckpt_shadow_s += engine.shadow_s
+        engine.close()
     write_requeue_marker(exp_dir, done=not stopped_early, step=step)
     status["status"] = "stopped_early" if stopped_early else "finished"
     status["step"] = step

@@ -96,7 +96,9 @@ def mode_resume(proc_id, workdir, sharded):
 
     from pyrecover_tpu.parallel.mesh import sync_global_devices
 
-    _run_train(workdir, sharded_checkpoint=sharded)
+    _run_train(
+        workdir, checkpoint_engine="sharded" if sharded else "vanilla"
+    )
     sync_global_devices("pre_corrupt")
     exp = Path(workdir) / "mp"
     if proc_id == 0:
@@ -109,7 +111,8 @@ def mode_resume(proc_id, workdir, sharded):
     sync_global_devices("post_corrupt")
     msgs = _capture_host0_log()
     _, end_step, stopped = _run_train(
-        workdir, sharded_checkpoint=sharded, resume_from_checkpoint="latest"
+        workdir, checkpoint_engine="sharded" if sharded else "vanilla",
+        resume_from_checkpoint="latest",
     )
     return {
         "end_step": end_step,
@@ -139,7 +142,7 @@ def mode_moe_ep(proc_id, workdir):
             n_experts=4, moe_top_k=2, moe_dispatch="grouped"
         ),
         mesh=MeshConfig(data=2, tensor=2, expert=2),
-        sharded_checkpoint=True,  # Orbax multihost writes of EP-sharded leaves
+        checkpoint_engine="sharded",  # Orbax multihost writes of EP-sharded leaves
     )
     # one number per HOST, computed from purely local data: params are
     # sharded over (expert, tensor) — both axes inside one host on this
@@ -348,7 +351,7 @@ def main():
     assert sampler_meta["consumed"] == 3
 
     # sharded checkpoint: every process writes its own shards
-    spath = checkpoint_path(workdir, "dist", 4, sharded=True)
+    spath = checkpoint_path(workdir, "dist", 4, engine="sharded")
     save_ckpt_sharded(spath, state, {"consumed": 4}, extra_meta={"step": 4})
     state_s, _, meta = load_ckpt_sharded(spath, state)
     assert meta["step"] == 4

@@ -215,7 +215,7 @@ def test_prune_never_counts_or_deletes_quarantined(tmp_path, mem_sink):
     quarantine_checkpoint(tmp_path / "ckpt_1.ckpt")
     # 3 live entries + 1 quarantined: max_keep=2 must delete exactly the
     # oldest LIVE one and leave the quarantine dir untouched
-    doomed = prune_checkpoints(tmp_path, 2, sharded=False)
+    doomed = prune_checkpoints(tmp_path, 2, engine="vanilla")
     assert [p.name for p in doomed] == ["ckpt_2.ckpt"]
     assert len(list_quarantined(tmp_path)) == 1
     pruned = events(mem_sink, "ckpt_pruned")

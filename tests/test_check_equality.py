@@ -46,7 +46,7 @@ def test_cross_format_equality(tmp_ckpt_dir):
     """A vanilla file and a sharded dir holding the same state compare equal."""
     s = make_state(3)
     v = checkpoint_path(tmp_ckpt_dir, "x", 1)
-    d = checkpoint_path(tmp_ckpt_dir, "x", 1, sharded=True)
+    d = checkpoint_path(tmp_ckpt_dir, "x", 1, engine="sharded")
     save_ckpt_vanilla(v, s)
     save_ckpt_sharded(d, s)
     assert main([str(v), str(d)]) == 0

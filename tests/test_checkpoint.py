@@ -52,7 +52,7 @@ def test_checkpoint_path_naming(tmp_ckpt_dir):
     assert p.name == f"ckpt_42{VANILLA_SUFFIX}"
     p = checkpoint_path(tmp_ckpt_dir, "exp", 42, final=True)
     assert p.name == f"ckpt_42_final{VANILLA_SUFFIX}"
-    p = checkpoint_path(tmp_ckpt_dir, "exp", 7, sharded=True)
+    p = checkpoint_path(tmp_ckpt_dir, "exp", 7, engine="sharded")
     assert p.name == "ckpt_7"
     assert parse_step(p) == 7
 
@@ -115,10 +115,10 @@ def test_vanilla_retention_prunes_with_sidecars(tmp_ckpt_dir):
 
 def test_sharded_roundtrip_bitexact(tmp_ckpt_dir):
     state = make_state(seed=3)
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 5, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 5, engine="sharded")
     save_ckpt_sharded(path, state, {"epoch": 0, "cursor": 4}, extra_meta={"step": 5})
     assert path.is_dir()
-    assert get_latest_checkpoint(path.parent, sharded=True) == path
+    assert get_latest_checkpoint(path.parent, engine="sharded") == path
 
     target = make_state(seed=77)
     restored, sampler_state, meta = load_ckpt_sharded(path, target)
@@ -187,7 +187,7 @@ def test_sharded_restore_onto_mesh(tmp_ckpt_dir, devices8):
     from pyrecover_tpu.parallel.sharding import shard_params
 
     state = make_state(seed=4)
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 9, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 9, engine="sharded")
     save_ckpt_sharded(path, state)
 
     mesh = create_mesh(MeshConfig(data=2, fsdp=2, tensor=2))

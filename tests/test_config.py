@@ -4,7 +4,7 @@ the right TrainConfig."""
 
 import pytest
 
-from pyrecover_tpu.config import get_args
+from pyrecover_tpu.config import TrainConfig, get_args
 
 
 def test_reference_style_command_line():
@@ -22,7 +22,7 @@ def test_reference_style_command_line():
         "--experiment_name", "my-exp",
         "--verify-checkpoints",
         "--max-kept-checkpoints", "3",
-        "--use-torch-distributed-ckpt",
+        "--checkpoint-engine", "sharded",
         "--timeaware-checkpointing",
         "--default-iter-time", "1.0",
         "--default-ckpt-time", "10.0",
@@ -43,7 +43,7 @@ def test_reference_style_command_line():
     assert cfg.training_steps == 3000
     assert cfg.experiment_name == "my-exp"
     assert cfg.verify_checkpoints
-    assert cfg.sharded_checkpoint  # --use-torch-distributed-ckpt alias
+    assert cfg.checkpoint_engine == "sharded"
     assert cfg.timeaware_checkpointing
     assert cfg.model.attention_impl == "flash"  # --use_flash_attention
     assert cfg.log_loss_to_csv
@@ -51,6 +51,15 @@ def test_reference_style_command_line():
     assert cfg.model.compute_dtype == "bfloat16"
     assert cfg.grad_max_norm == 1.0
     assert cfg.profile and cfg.profile_step_start == 10
+
+
+@pytest.mark.parametrize(
+    "flag", ["--use-torch-distributed-ckpt", "--sharded-checkpoint"])
+def test_legacy_engine_flags_are_refused(flag):
+    """One spelling of the engine choice: ``--checkpoint-engine``."""
+    with pytest.raises(SystemExit):
+        get_args([flag])
+    assert not hasattr(TrainConfig(), "sharded_checkpoint")
 
 
 def test_mesh_flags():

@@ -266,7 +266,7 @@ def test_vanilla_cross_mesh_roundtrip(tmp_ckpt_dir, grids, src, dst):
 def test_sharded_cross_mesh_roundtrip(tmp_ckpt_dir, grids, src, dst):
     _, state_src, _ = grids[src]
     _, _, target = grids[dst]
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 5, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 5, engine="sharded")
     save_ckpt_sharded(path, state_src, {"consumed": 5},
                       extra_meta={"step": 5})
     assert elastic.read_saved_meta(path)["topology"]["devices"] == src

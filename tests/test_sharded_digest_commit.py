@@ -101,7 +101,7 @@ def test_async_save_records_a_digest_per_params_leaf(tmp_ckpt_dir, state,
                    not x.sharding.is_fully_replicated
                    for x in jax.tree_util.tree_leaves(state.params))
     want = want_digests(state)
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, engine="sharded")
     with ShardedCheckpointer(use_async=True) as ckptr:
         ckptr.save(path, state, extra_meta={"step": 1})
         ckptr.wait()
@@ -118,7 +118,7 @@ def test_digests_are_of_the_state_at_the_call(tmp_ckpt_dir, state, held_hash):
     the device would fail here."""
     entered, release, _ = held_hash
     want = want_digests(state)
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, engine="sharded")
     with ShardedCheckpointer(use_async=True) as ckptr:
         ckptr.save(path, state, extra_meta={"step": 1})
         for leaf in jax.tree_util.tree_leaves(state):
@@ -135,7 +135,7 @@ def test_digests_are_of_the_state_at_the_call(tmp_ckpt_dir, state, held_hash):
 def test_no_commit_while_the_hash_is_open(tmp_ckpt_dir, state, held_hash):
     entered, release, callers = held_hash
     want = want_digests(state)
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, engine="sharded")
     with ShardedCheckpointer(use_async=True) as ckptr:
         ckptr.save(path, state, extra_meta={"step": 1})
         assert entered.wait(30)
@@ -160,7 +160,7 @@ def broken_hash(arr):
 def test_a_failed_hash_fails_the_save_and_commits_nothing(tmp_ckpt_dir, state,
                                                           monkeypatch):
     monkeypatch.setattr(chunkstore, "leaf_digest", broken_hash)
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, engine="sharded")
     ckptr = ShardedCheckpointer(use_async=True)
     ckptr.save(path, state, extra_meta={"step": 1})  # the dispatch succeeds
     with pytest.raises(OSError, match="hash failed"):
@@ -168,7 +168,7 @@ def test_a_failed_hash_fails_the_save_and_commits_nothing(tmp_ckpt_dir, state,
     assert not path.exists()
     # the engine goes on: the next save, with a sound hash, commits
     monkeypatch.setattr(chunkstore, "leaf_digest", leaf_digest)
-    path2 = checkpoint_path(tmp_ckpt_dir, "exp", 2, sharded=True)
+    path2 = checkpoint_path(tmp_ckpt_dir, "exp", 2, engine="sharded")
     ckptr.save(path2, state, extra_meta={"step": 2})
     ckptr.close()
     assert not path.exists()
@@ -178,7 +178,7 @@ def test_a_failed_hash_fails_the_save_and_commits_nothing(tmp_ckpt_dir, state,
 def test_a_failed_hash_fails_a_sync_save_at_the_call(tmp_ckpt_dir, state,
                                                      monkeypatch):
     monkeypatch.setattr(chunkstore, "leaf_digest", broken_hash)
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, engine="sharded")
     with ShardedCheckpointer(use_async=False) as ckptr:
         with pytest.raises(OSError, match="hash failed"):
             ckptr.save(path, state, extra_meta={"step": 1})
@@ -189,7 +189,8 @@ def test_sync_and_async_write_the_same_meta(tmp_ckpt_dir, state):
     paths = {}
     for use_async in (True, False):
         paths[use_async] = checkpoint_path(
-            tmp_ckpt_dir, "async" if use_async else "sync", 1, sharded=True)
+            tmp_ckpt_dir, "async" if use_async else "sync", 1,
+            engine="sharded")
         with ShardedCheckpointer(use_async=use_async) as ckptr:
             ckptr.save(paths[use_async], state, {"epoch": 3},
                        extra_meta={"step": 1})
@@ -211,7 +212,7 @@ def test_spans_say_where_the_hash_ran(tmp_ckpt_dir, state, use_async):
     params = jax.tree_util.tree_leaves(state.params)
     with ShardedCheckpointer(use_async=use_async) as ckptr:
         for step in (1, 2, 3):
-            ckptr.save(checkpoint_path(tmp_ckpt_dir, "exp", step, sharded=True),
+            ckptr.save(checkpoint_path(tmp_ckpt_dir, "exp", step, engine="sharded"),
                        state, max_keep=2, extra_meta={"step": step})
     digest = [e for e in sink.events
               if e["event"] == "span_end" and e["name"] == "ckpt_digest"]

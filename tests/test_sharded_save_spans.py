@@ -68,7 +68,7 @@ def run_saves(ckpt_dir, state, use_async, n, *, max_keep=1, outer=True):
     blocking, outers = [], []
     with ShardedCheckpointer(use_async=use_async) as ckptr:
         for step in range(1, n + 1):
-            path = checkpoint_path(ckpt_dir, "exp", step, sharded=True)
+            path = checkpoint_path(ckpt_dir, "exp", step, engine="sharded")
             sp = spans.begin("ckpt_save", step=step) if outer else spans._NULL
             blocking.append(ckptr.save(
                 path, state, max_keep=max_keep, extra_meta={"step": step}))
@@ -215,12 +215,12 @@ def test_without_a_sink_the_save_opens_no_span(tmp_ckpt_dir, state, use_async,
     assert not telemetry.enabled()
     with ShardedCheckpointer(use_async=use_async) as ckptr:
         for step in (1, 2):
-            ckptr.save(checkpoint_path(tmp_ckpt_dir, "exp", step, sharded=True),
+            ckptr.save(checkpoint_path(tmp_ckpt_dir, "exp", step, engine="sharded"),
                        state, max_keep=1, extra_meta={"step": step})
     assert made == [] and spans.current_span_id() is None
     # and with one, the same calls do (the probe itself works)
     telemetry.add_sink(telemetry.MemorySink())
     with ShardedCheckpointer(use_async=use_async) as ckptr:
-        ckptr.save(checkpoint_path(tmp_ckpt_dir, "exp", 3, sharded=True),
+        ckptr.save(checkpoint_path(tmp_ckpt_dir, "exp", 3, engine="sharded"),
                    state, max_keep=1, extra_meta={"step": 3})
     assert "ckpt_digest" in made and "ckpt_serialize" in made

@@ -128,7 +128,7 @@ def test_every_leaf_crosses_the_host_link_once(tmp_ckpt_dir, state, transfers,
     leaves = jax.tree_util.tree_leaves(state)
     shards = [s.data for x in leaves for s in x.addressable_shards]
     assert len(shards) == len(leaves)  # one device: one shard a leaf
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, engine="sharded")
     with ShardedCheckpointer(use_async=use_async) as ckptr:
         ckptr.save(path, state, extra_meta={"step": 1})
     # one transfer a leaf, scalars and rank-1 leaves like the rest
@@ -147,7 +147,7 @@ def test_the_count_is_per_save(tmp_ckpt_dir, state, transfers, use_async):
     second finds the copy it kept)."""
     with ShardedCheckpointer(use_async=use_async) as ckptr:
         for step in (1, 2):
-            ckptr.save(checkpoint_path(tmp_ckpt_dir, "exp", step, sharded=True),
+            ckptr.save(checkpoint_path(tmp_ckpt_dir, "exp", step, engine="sharded"),
                        state, extra_meta={"step": step})
     n = len(jax.tree_util.tree_leaves(state))
     assert len(transfers["started"]) == 2 * n
@@ -157,7 +157,7 @@ def test_the_count_is_per_save(tmp_ckpt_dir, state, transfers, use_async):
 def test_the_state_may_go_the_moment_save_returns(tmp_ckpt_dir, state):
     values = host_values(state)
     target = tiny_state()
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, engine="sharded")
     with ShardedCheckpointer(use_async=True) as ckptr:
         ckptr.save(path, state, extra_meta={"step": 1})
         for leaf in jax.tree_util.tree_leaves(state):
@@ -184,7 +184,7 @@ def test_a_state_over_8_devices_keeps_its_layout_and_restores_under_another(
     params = {id(x) for x in jax.tree_util.tree_leaves(spread.params)}
     split = [x for x in leaves if not x.sharding.is_fully_replicated]
     assert split and all(len(x.addressable_shards) == 8 for x in split)
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, engine="sharded")
     sink = telemetry.add_sink(telemetry.MemorySink())
     with ShardedCheckpointer(use_async=use_async) as ckptr:
         ckptr.save(path, spread, extra_meta={"step": 1})
@@ -237,7 +237,7 @@ def test_a_leaf_already_on_the_host_is_left_as_it_is(tmp_ckpt_dir, state,
     mixed = dataclasses.replace(state, rng=rng_on_host)
     values = host_values(mixed)
     sink = telemetry.add_sink(telemetry.MemorySink())
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, engine="sharded")
     with ShardedCheckpointer(use_async=use_async) as ckptr:
         ckptr.save(path, mixed, extra_meta={"step": 1})
         restored, _, _ = ckptr.restore(path, tiny_state())
@@ -255,7 +255,7 @@ def test_the_span_says_what_the_snapshot_moved(tmp_ckpt_dir, state, use_async):
     sink = telemetry.add_sink(telemetry.MemorySink())
     with ShardedCheckpointer(use_async=use_async) as ckptr:
         for step in (1, 2):
-            ckptr.save(checkpoint_path(tmp_ckpt_dir, "exp", step, sharded=True),
+            ckptr.save(checkpoint_path(tmp_ckpt_dir, "exp", step, engine="sharded"),
                        state, extra_meta={"step": step})
     total = sum(x.nbytes for x in jax.tree_util.tree_leaves(state))
     spans = serialize_spans(sink)
@@ -273,7 +273,7 @@ def test_no_more_in_flight_than_the_bound(tmp_ckpt_dir, state, transfers,
     monkeypatch.setattr(sharded, "IN_FLIGHT_BYTES", cap)
     leaves = jax.tree_util.tree_leaves(state)
     largest = max(x.nbytes for x in leaves)
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, engine="sharded")
     with ShardedCheckpointer(use_async=False) as ckptr:
         ckptr.save(path, state, extra_meta={"step": 1})
     assert len(transfers["started"]) == len(leaves)
@@ -290,7 +290,7 @@ def test_the_form_on_disk_is_what_it_was(tmp_ckpt_dir, state):
     """Every leaf is filed as a ``jax.Array`` with its sharding, chunks and
     data files stay bounded, and the commit marker names the same items: a
     checkpoint of the parent commit reads the same, and the reverse."""
-    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, sharded=True)
+    path = checkpoint_path(tmp_ckpt_dir, "exp", 1, engine="sharded")
     with ShardedCheckpointer(use_async=True) as ckptr:
         ckptr.save(path, state, {"epoch": 3}, extra_meta={"step": 1})
     assert sorted(p.name for p in path.iterdir()) == [

@@ -35,7 +35,7 @@ def test_inspect_both_formats(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "vanilla" in out and "step: 1" in out and "tok_embed" in out
 
-    d = checkpoint_path(tmp_path, "x", 2, sharded=True)
+    d = checkpoint_path(tmp_path, "x", 2, engine="sharded")
     save_ckpt_sharded(d, state, extra_meta={"step": 2})
     assert inspect_main([str(d)]) == 0
     out = capsys.readouterr().out

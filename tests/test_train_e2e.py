@@ -46,25 +46,26 @@ def leaves(state):
 
 
 @pytest.mark.parametrize(
-    "sharded,async_ckpt",
-    [(False, False), (True, False), (False, True), (True, True)],
+    "engine,async_ckpt",
+    [("vanilla", False), ("sharded", False), ("vanilla", True),
+     ("sharded", True)],
     ids=["vanilla", "sharded", "vanilla-async", "sharded-async"],
 )
-def test_driver_resume_bitexact(tmp_path, sharded, async_ckpt):
+def test_driver_resume_bitexact(tmp_path, engine, async_ckpt):
     straight_dir = tmp_path / "straight"
     resumed_dir = tmp_path / "resumed"
 
-    cfg = tiny_config(straight_dir, sharded_checkpoint=sharded,
+    cfg = tiny_config(straight_dir, checkpoint_engine=engine,
                       async_checkpoint=async_ckpt)
     straight_state, _, _ = train(cfg)
 
     # interrupted: run only 4 steps
-    cfg1 = tiny_config(resumed_dir, training_steps=4, sharded_checkpoint=sharded,
+    cfg1 = tiny_config(resumed_dir, training_steps=4, checkpoint_engine=engine,
                        async_checkpoint=async_ckpt)
     train(cfg1)
     # resumed: same total steps, restore from latest
     cfg2 = tiny_config(
-        resumed_dir, sharded_checkpoint=sharded, async_checkpoint=async_ckpt,
+        resumed_dir, checkpoint_engine=engine, async_checkpoint=async_ckpt,
         resume_from_checkpoint="latest",
     )
     resumed_state, end_step, stopped = train(cfg2)
@@ -212,7 +213,7 @@ def test_sharded_resume_falls_back_past_corrupt_checkpoint(tmp_path, caplog):
     import shutil
 
     cfg = tiny_config(tmp_path, training_steps=8, checkpoint_frequency=4,
-                      sharded_checkpoint=True)
+                      checkpoint_engine="sharded")
     train(cfg)
     exp = tmp_path / "e2e"
     newest = exp / "ckpt_8_final"
@@ -228,7 +229,7 @@ def test_sharded_resume_falls_back_past_corrupt_checkpoint(tmp_path, caplog):
     try:
         with caplog.at_level(logging.INFO, logger="pyrecover_tpu"):
             cfg2 = tiny_config(tmp_path, resume_from_checkpoint="latest",
-                               sharded_checkpoint=True)
+                               checkpoint_engine="sharded")
             _, end_step, _ = train(cfg2)
     finally:
         logger.propagate = False
@@ -247,7 +248,7 @@ def test_sharded_resume_falls_back_past_corrupt_checkpoint(tmp_path, caplog):
     try:
         with caplog.at_level(logging.INFO, logger="pyrecover_tpu"):
             cfg3 = tiny_config(tmp_path, resume_from_checkpoint="latest",
-                               sharded_checkpoint=True)
+                               checkpoint_engine="sharded")
             _, end_step, _ = train(cfg3)
     finally:
         logger.propagate = False
@@ -263,7 +264,7 @@ def test_sharded_resume_falls_back_past_corrupt_checkpoint(tmp_path, caplog):
         if f.is_file():
             f.write_bytes(f.read_bytes()[: max(f.stat().st_size // 2, 1)])
     cfg4 = tiny_config(tmp_path, resume_from_checkpoint="latest",
-                       sharded_checkpoint=True)
+                       checkpoint_engine="sharded")
     _, end_step, _ = train(cfg4)
     assert end_step == 8
 
@@ -271,14 +272,14 @@ def test_sharded_resume_falls_back_past_corrupt_checkpoint(tmp_path, caplog):
     shutil.rmtree(newest / "state")
     with pytest.raises(Exception):
         train(tiny_config(tmp_path, resume_from_checkpoint=str(newest),
-                          sharded_checkpoint=True))
+                          checkpoint_engine="sharded"))
 
     # wrong model config → CheckpointStructureError fails HARD under
     # 'latest' (host-0 verdict code 2, raised on every host)
     from pyrecover_tpu.checkpoint.vanilla import CheckpointStructureError
 
     cfg5 = tiny_config(tmp_path, resume_from_checkpoint="latest",
-                       sharded_checkpoint=True)
+                       checkpoint_engine="sharded")
     cfg5.model = ModelConfig().tiny(max_seq_len=32, vocab_size=128,
                                     n_layers=4)  # trained with 2 layers
     cfg5.__post_init__()
@@ -291,7 +292,7 @@ def test_sharded_resume_falls_back_past_corrupt_checkpoint(tmp_path, caplog):
             (p / "_CHECKPOINT_METADATA").unlink()
     with pytest.raises(RuntimeError, match="refusing"):
         train(tiny_config(tmp_path, resume_from_checkpoint="latest",
-                          sharded_checkpoint=True))
+                          checkpoint_engine="sharded"))
 
 
 def test_done_marker_on_completion(tmp_path):
@@ -332,7 +333,7 @@ def test_ring_accum_eval_compose_bitexact_resume(tmp_path):
     eval loop + sharded checkpointing compose, and resume is still
     bit-exact."""
     common = dict(
-        sharded_checkpoint=True, grad_accumulation_steps=2,
+        checkpoint_engine="sharded", grad_accumulation_steps=2,
         eval_frequency=4, eval_samples=8,
     )
 

@@ -475,7 +475,7 @@ def test_mixed_engine_discovery_and_latest(tmp_path):
             for p in list_checkpoints(exp, engine="zerostall")] == [15, 25]
     # legacy tristate keeps its meaning — and zerostall manifests are
     # FILES, yet must never leak into the vanilla engine's view
-    assert [parse_step(p) for p in list_checkpoints(exp, sharded=False)] \
+    assert [parse_step(p) for p in list_checkpoints(exp, engine="vanilla")] \
         == [10, 30]
     assert parse_step(get_latest_checkpoint(exp, engine="vanilla")) == 30
     assert parse_step(get_latest_checkpoint(exp, engine="zerostall")) == 25
