@@ -88,13 +88,12 @@ def main():
                     help="disable block rematerialization (more HBM, fewer FLOPs)")
     ap.add_argument("--remat-policy", default="full",
                     choices=["full", "save-attn", "auto"],
-                    help="remat policy: full recompute, keep attention "
-                         "outputs (skips recomputing the attention "
-                         "sublayer), or auto — size the policy "
-                         "(none/save-attn/full + a per-chip batch "
-                         "suggestion) against the shardcheck HBM model "
-                         "for the live device kind (utils/remat.py; "
-                         "overrides --no-remat)")
+                    help="what the layer scan keeps under remat: nothing "
+                         "(full), the flash call's residuals (save-attn: "
+                         "the forward kernel runs once), or auto — the "
+                         "richest save-set of utils/remat.py's ladder "
+                         "that fits the live device kind, with a per-chip "
+                         "batch suggestion (--no-remat stays no remat)")
     ap.add_argument("--flash-block-q", type=int, default=0,
                     help="flash-attention q tile; 0 = the per-device-kind "
                          "default (ops/flash_attention.py DEFAULT_BLOCKS, "
@@ -154,11 +153,11 @@ def main():
         flash_block_kv=args.flash_block_kv, remat_policy=args.remat_policy,
         moe_dispatch=args.moe_dispatch,
     )
-    # --remat-policy auto: size the policy (and a per-chip batch
+    # --remat-policy auto: pick the save-set (and a per-chip batch
     # suggestion) against the SC05 HBM model BEFORE anything builds the
     # model — the ROADMAP "spend the zero1 headroom" lever, measured
     remat_decision = None
-    if args.remat_policy == "auto":
+    if args.remat_policy == "auto" and not args.no_remat:
         from pyrecover_tpu.utils.remat import resolve_remat_policy
 
         remat_decision = resolve_remat_policy(
@@ -169,10 +168,7 @@ def main():
             grad_allreduce=args.grad_allreduce,
             device_kind=device.device_kind,
         )
-        model_cfg = dataclasses.replace(
-            model_cfg, remat=remat_decision.remat,
-            remat_policy=remat_decision.remat_policy,
-        )
+        model_cfg = remat_decision.apply(model_cfg)
     train_cfg = TrainConfig(
         sequence_length=args.seq_len,
         batch_size=args.batch_size,
@@ -426,11 +422,11 @@ def main():
         ov_part = "buckets off (single tail collective)"
     if remat_decision is not None:
         rm_part = (
-            f"remat auto -> {remat_decision.policy} "
-            f"(modelled {remat_decision.table[remat_decision.policy]/2**30:.2f}"
-            f" GiB/chip vs budget "
-            + (f"{remat_decision.budget_bytes/2**30:.1f} GiB"
-               if remat_decision.budget_bytes else "unknown")
+            f"remat auto -> {remat_decision.rung} "
+            f"(modelled {remat_decision.table[remat_decision.rung]/2**30:.2f}"
+            f" GiB/chip vs the compiler's limit "
+            + (f"{remat_decision.limit_bytes/2**30:.2f} GiB"
+               if remat_decision.limit_bytes else "unknown")
             + f", suggested per-chip batch "
               f"{remat_decision.suggested_batch_per_chip})"
         )

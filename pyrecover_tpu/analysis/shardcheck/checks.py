@@ -208,25 +208,45 @@ def _bucket_of(path):
 
 def memory_budget(leaves, specs, mesh_shape, model_config, *, batch_size,
                   seq_len, loss_chunk_size=0, config=None, locus="config"):
-    """Check 2 — coarse per-device HBM budget.
+    """Check 2 — per-device HBM budget of the train step.
 
     Exact terms: params and optimizer state are summed leaf-by-leaf at
-    their sharded sizes (metadata math, no estimation). Coarse terms,
-    labelled as such: gradients (one param-sized f32-ish transient),
-    saved activations for the backward (per-layer residency ~ the block's
-    intermediate widths, halved-ish by remat), and the loss/logit buffer
-    (full logits, or one chunk when the chunked CE is on). Returns
-    ``(rows, findings)`` where ``rows`` is the budget table the reporter
-    renders.
+    their sharded sizes (metadata math, no estimation). Modelled terms,
+    held by tests/test_remat_ladder.py to the v5e compiler's own peaks
+    (``compiled.memory_analysis()``; tools/remat_ladder.py) at the two
+    benchmark cells' shapes, every remat rung, to within -1.5 % / +4 %:
+
+    * gradients: one param-sized set, at param dtype;
+    * ``activations_bytes``: what the forward sweep SAVES for the
+      backward — without remat a layer pass's whole set of
+      intermediates, under remat its carry plus the named values the
+      policy keeps (utils/remat.py ``named_bytes``), times the layer
+      passes; a looped stack also keeps every pass's normed state;
+    * ``working_bytes``: what the backward sweep holds BESIDE that while
+      it is in one layer pass (the pass recomputed from its carry and its
+      cotangents, ~6.5 model widths + 4 FFN widths a token), or the loss
+      head's float32 logits where those are larger (the two never live
+      together). A looped stack adds the inner scan's saved set copied
+      into the outer scan's once a pass, each pass's boundary values and
+      cotangents, and the pass's own layer gradients beside the
+      accumulator.
+
+    Returns ``(rows, findings)`` where ``rows`` is the budget table the
+    reporter renders.
     """
+    from pyrecover_tpu.utils.dtypes import resolve_dtype
+    from pyrecover_tpu.utils.remat import named_bytes, saved_names
+
     config = config or DEFAULT_CONFIG
     cfg = model_config
     mesh = mesh_shape
     buckets = {"params": 0, "optimizer": 0, "counters": 0}
+    layer_params = 0
     for (path, shape, dtype), spec in zip(leaves, specs):
-        buckets[_bucket_of(path)] += (
-            leaf_nbytes(shape, dtype) // spec_shard_factor(spec, mesh)
-        )
+        nbytes = leaf_nbytes(shape, dtype) // spec_shard_factor(spec, mesh)
+        buckets[_bucket_of(path)] += nbytes
+        if path.startswith(".params") and "layers" in path:
+            layer_params += nbytes
     rows = {
         "params_bytes": buckets["params"],
         "optimizer_bytes": buckets["optimizer"] + buckets["counters"],
@@ -234,37 +254,55 @@ def memory_budget(leaves, specs, mesh_shape, model_config, *, batch_size,
         "gradients_bytes": buckets["params"],
     }
 
-    from pyrecover_tpu.utils.dtypes import resolve_dtype
-
     itemsize = np.dtype(resolve_dtype(cfg.compute_dtype)).itemsize
     batch_shards = mesh.get("data", 1) * mesh.get("fsdp", 1)
+    tensor = max(mesh.get("tensor", 1), 1)
     b_loc = max(batch_size // batch_shards, 1)
     s_loc = max(seq_len // mesh.get("sequence", 1), 1)
+    tokens = b_loc * s_loc
     # saved activations count layer PASSES: a looped stack saves a carry
     # for each of its loop_steps sweeps over the held layers
-    layers_loc = max(cfg.layer_passes // mesh.get("pipeline", 1), 1)
-    # per-layer saved set ~ attention ins/outs + FFN hidden, in units of
-    # (b, s, dim): qkv+attn_out+residuals ~6 dim-widths + 3 ffn widths
+    layers_held = max(cfg.n_layers // mesh.get("pipeline", 1), 1)
+    passes = layers_held * cfg.loop_steps
     ffn = cfg.expert_hidden_dim if cfg.n_experts > 0 else cfg.ffn_hidden_dim
-    widths = 6 * cfg.dim + 3 * ffn // max(mesh.get("tensor", 1), 1)
-    per_layer = b_loc * s_loc * widths * itemsize
+    carry = tokens * cfg.dim * itemsize
+    # one layer pass in the backward sweep: its recomputed forward and
+    # the cotangents, in model and FFN widths a token (fitted to the
+    # compiler's peak at batch 2 and 4 of both cells)
+    work = int(tokens * itemsize * (6.5 * cfg.dim + 4 * ffn // tensor))
     if cfg.remat:
-        # full remat keeps only the layer carry (+ attn_out for save-attn)
-        per_layer = b_loc * s_loc * cfg.dim * itemsize * (
-            2 if cfg.remat_policy == "save-attn" else 1
+        per_pass = carry + named_bytes(
+            cfg, saved_names(cfg), tokens=tokens, itemsize=itemsize,
+            tensor=tensor,
         )
-    rows["activations_bytes"] = per_layer * layers_loc
+    else:
+        # no remat: every pass keeps its whole set of intermediates, and
+        # the backward's working set is one pass's cotangents
+        per_pass = int(tokens * itemsize * (7.5 * cfg.dim + 4 * ffn // tensor))
+        # the flash kernel's row statistics as it writes them: one float a
+        # (head, position), padded to the 128-lane tile
+        per_pass += 128 * named_bytes(
+            cfg, ("flash_lse",), tokens=tokens, itemsize=itemsize,
+            tensor=tensor,
+        )
+    rows["activations_bytes"] = per_pass * passes
     if cfg.loop_steps > 1:
         # every pass's normed state is kept for the exit loss
-        rows["activations_bytes"] += (
-            cfg.loop_steps * b_loc * s_loc * cfg.dim * itemsize
-        )
+        rows["activations_bytes"] += cfg.loop_steps * carry
+        # the inner scan's saved set is copied into the outer scan's, the
+        # pass boundaries hold ~6 carries more (state in and out, the normed
+        # state, their cotangents), and a pass's layer gradients stand
+        # beside the accumulator
+        work += per_pass * layers_held + 6 * cfg.loop_steps * carry
+        work += layer_params
     chunk = loss_chunk_size if 0 < loss_chunk_size < s_loc else s_loc
-    vocab_loc = cfg.vocab_size // max(mesh.get("tensor", 1), 1)
+    vocab_loc = cfg.vocab_size // tensor
     # logits + logprobs, f32 (train_state.chunked_ce)
     rows["logits_bytes"] = 2 * b_loc * chunk * vocab_loc * 4
+    rows["working_bytes"] = max(work, rows["logits_bytes"])
     rows["total_bytes"] = sum(
-        v for k, v in rows.items() if k.endswith("_bytes")
+        v for k, v in rows.items()
+        if k.endswith("_bytes") and k != "logits_bytes"
     )
 
     findings = []

@@ -171,8 +171,9 @@ def render_text(reports):
                 f" × seq {mem['seq_len']}): params {_human(mem['params_bytes'])}"
                 f" | optimizer {_human(mem['optimizer_bytes'])}"
                 f" | grads {_human(mem['gradients_bytes'])}"
-                f" | activations ~{_human(mem['activations_bytes'])}"
-                f" | logits ~{_human(mem['logits_bytes'])}"
+                f" | saved activations ~{_human(mem['activations_bytes'])}"
+                f" | working set ~{_human(mem['working_bytes'])}"
+                f" (logits ~{_human(mem['logits_bytes'])} inside it)"
                 f" | total ~{_human(mem['total_bytes'])}{cap}"
             )
         cen = r.get("census")

@@ -432,17 +432,21 @@ def build_parser():
                    default=d.model.moe_aux_weight,
                    help="load-balance aux loss scale")
     p.add_argument("--remat", action="store_true",
-                   help="Rematerialize transformer blocks (trade FLOPs for HBM).")
-    p.add_argument("--remat-policy", type=str, default="full",
-                   choices=["full", "save-attn", "auto"],
-                   help="With --remat: recompute everything, or keep each "
-                        "block's attention output (skips recomputing the "
-                        "attention sublayer in backward). 'auto' sizes the "
-                        "policy (none/save-attn/full) against the shardcheck "
-                        "HBM model for the live device kind at startup — "
-                        "ZeRO-1-freed headroom converts into the least "
-                        "recompute that fits (utils/remat.py; overrides "
-                        "--remat).")
+                   help="Rematerialize transformer blocks: the backward "
+                        "sweep recomputes each block from its carry, less "
+                        "what --remat-policy keeps. Without it nothing is "
+                        "rematerialized, whatever the policy.")
+    p.add_argument("--remat-policy", type=str, default="auto",
+                   choices=["auto", "save-attn", "full"],
+                   help="With --remat, what the layer scan keeps for the "
+                        "backward sweep. 'auto': the richest save-set of "
+                        "utils/remat.py's ladder (flash residuals, q/k/v, "
+                        "the post-attention residual, the SwiGLU products) "
+                        "whose modelled bytes fit what the compiler allows "
+                        "on the live device kind; on a kind with no known "
+                        "limit (the CPU) it keeps nothing. 'save-attn': the "
+                        "flash call's residuals (the forward kernel runs "
+                        "once). 'full': nothing, every block recomputed.")
     p.add_argument("--loss-chunk-size", type=int, default=0,
                    help=">0: compute the CE loss in sequence chunks of this size, "
                         "fusing the vocab projection (HBM saver for big vocabs).")

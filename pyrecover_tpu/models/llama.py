@@ -31,6 +31,7 @@ from pyrecover_tpu.ops.attention import sdpa_attention
 from pyrecover_tpu.ops.rope import apply_rope, precompute_rope
 from pyrecover_tpu.parallel.mesh import AXIS_DATA, AXIS_FSDP, AXIS_SEQ, AXIS_TENSOR, constrain
 from pyrecover_tpu.utils.dtypes import resolve_dtype
+from pyrecover_tpu.utils.remat import FLASH_LSE, checkpoint_policy, saved_names
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,14 +67,17 @@ class ModelConfig:
     # interleaving; parallel/pipeline.py::build_interleaved_tables)
     pp_virtual_stages: int = 1
     remat: bool = False
-    # remat policy when remat=True: "full" recomputes everything
-    # (nothing_saveable); "save-attn" keeps each block's attention output
-    # (one (B,S,D) tensor per layer) so the backward skips recomputing the
-    # whole attention sublayer — a little HBM for a chunk of the remat tax.
-    # "auto" is resolved BEFORE the model is built (utils/remat.py sizes
-    # none/save-attn/full against the shardcheck HBM model); forward never
-    # sees it.
-    remat_policy: str = "full"
+    # what the layer scan keeps for the backward sweep when remat=True:
+    # "full" nothing (every block recomputed from its carry), "save-attn"
+    # the flash call's two residuals (the forward kernel then runs once,
+    # not twice), "auto" the richest save-set of utils/remat.py's ladder
+    # that fits the device, resolved by the trainer BEFORE the model is
+    # built and handed over in ``remat_save``. An "auto" nobody resolved
+    # (a library caller, a device kind with no limit) keeps nothing: full.
+    remat_policy: str = "auto"
+    # the checkpoint names "auto" resolved to (utils/remat.py
+    # resolve_remat_policy); None = not resolved, ``remat_policy`` decides
+    remat_save: tuple = None
     # flash-attention (block_q, block_kv) tiling; 0 = auto-resolve from
     # the per-device-kind defaults table (ops/flash_attention.py
     # DEFAULT_BLOCKS, measured with tools/bench_flash_blocks.py — on v5e
@@ -129,8 +133,8 @@ class ModelConfig:
             )
         if self.remat_policy not in ("full", "save-attn", "auto"):
             raise ValueError(
-                f"remat_policy={self.remat_policy!r}: expected 'full', "
-                "'save-attn' or 'auto'"
+                f"remat_policy={self.remat_policy!r}: expected 'auto', "
+                "'save-attn' or 'full'"
             )
         if self.pp_schedule not in ("gpipe", "1f1b"):
             raise ValueError(
@@ -286,7 +290,12 @@ def _attention_fn(config):
             # value while the other resolves
             dq, dk = default_blocks()
             bq, bk = (bq if bq > 0 else dq), (bk if bk > 0 else dk)
-        return partial(flash_attention, block_q=bq, block_kv=bk)
+        # the kernel's row statistics are kept slim (lane 0) where a remat
+        # policy saves them; every other program is the one it was
+        slim = config.remat and FLASH_LSE in saved_names(config)
+        return partial(
+            flash_attention, block_q=bq, block_kv=bk, slim_lse=slim
+        )
     if config.attention_impl == "ring":
         from pyrecover_tpu.ops.ring_attention import ring_attention
 
@@ -333,8 +342,10 @@ def ffn_sublayer(x, layer, config):
             layer["moe_w2"], cfg,
         )
         return x + y, aux
-    gate = jax.nn.silu(h @ layer["w1"].astype(cdt))
-    up = h @ layer["w3"].astype(cdt)
+    # the two products before the activation carry names a remat policy
+    # can keep (utils/remat.py); the MoE path has none
+    gate = jax.nn.silu(checkpoint_name(h @ layer["w1"].astype(cdt), "ffn_w1"))
+    up = checkpoint_name(h @ layer["w3"].astype(cdt), "ffn_w3")
     y = (gate * up) @ layer["w2"].astype(cdt)
     if cfg.post_norms:
         y = rms_norm(y, layer["ffn_post_norm"], cfg.norm_eps)
@@ -355,17 +366,25 @@ def _block(x, layer, cos, sin, config, attn_fn, segment_ids=None):
     # --- attention sublayer ---
     h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     q, k, v = qkv_proj(h, layer, cfg, cos, sin)
-    q = constrain(q, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR, None)
-    k = constrain(k, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR, None)
-    v = constrain(v, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR, None)
+    # named AFTER rope and the constraint: the very arrays that enter the
+    # attention call, so the kernel's residual tuple holds the named values
+    q = checkpoint_name(
+        constrain(q, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR, None),
+        "attn_q")
+    k = checkpoint_name(
+        constrain(k, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR, None),
+        "attn_k")
+    v = checkpoint_name(
+        constrain(v, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR, None),
+        "attn_v")
     if segment_ids is None:
         attn = attn_fn(q, k, v, causal=True)
     else:
         attn = attn_fn(q, k, v, causal=True, segment_ids=segment_ids)
-    attn = checkpoint_name(attn, "attn_out")
     attn = attn.reshape(b, s, cfg.n_heads * hd)
     x = attn_residual(x, attn, layer, cfg)
-    x = constrain(x, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, None)
+    x = checkpoint_name(
+        constrain(x, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, None), "attn_resid")
 
     # --- FFN sublayer ---
     x, aux = ffn_sublayer(x, layer, cfg)
@@ -409,12 +428,7 @@ def _stack(params, tokens, config, segment_ids):
         return out
 
     if cfg.remat:
-        policy = (
-            jax.checkpoint_policies.save_only_these_names("attn_out")
-            if cfg.remat_policy == "save-attn"
-            else jax.checkpoint_policies.nothing_saveable
-        )
-        block_carry = jax.checkpoint(block_carry, policy=policy)
+        block_carry = jax.checkpoint(block_carry, policy=checkpoint_policy(cfg))
 
     # Under a mesh with a pipeline axis >1 this runs the microbatched
     # ppermute schedule (stages hold layer slices); otherwise it reduces to

@@ -41,6 +41,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -51,13 +52,25 @@ from pyrecover_tpu.parallel.mesh import (
     AXIS_TENSOR,
     nonmanual_axes,
 )
+from pyrecover_tpu.utils.remat import FLASH_LSE, FLASH_OUT
 
 NEG_INF = -1e30
 LANES = 128  # TPU lane width: scratch vectors are (bq, 128) replicated
-# logsumexp is per (batch, head, position) but stored with a small lane dim
-# (f32 sublane tile) — 8 instead of 128 keeps the HBM side 16x smaller; the
-# 1B bench point OOMs with full-lane replication.
+# logsumexp is one number per (batch, head, position); the kernels write
+# and read it with a minor dimension of 8 (the f32 sublane count) because
+# a (bq, 1) block is no legal store. That saves nothing in HBM: the chip
+# pads a minor dimension of 8 to the 128-lane tile all the same (256 MiB a
+# Mistral layer for 2 MiB of numbers), and every lane holds the same
+# value. So where a remat policy KEEPS the residual for the backward
+# (utils/remat.py saves `flash_lse`) it is lane 0 alone, (b, h, s)
+# (`_flash_fwd`, `slim_lse`), broadcast back to this shape for the backward
+# kernels; everywhere else it stays as the kernel wrote it.
 LSE_LANES = 8
+
+# (the two residuals the forward kernel writes carry `checkpoint_name`
+# tags, FLASH_OUT and FLASH_LSE of utils/remat.py: under `jax.checkpoint`
+# a policy that saves them keeps the backward sweep from running the
+# forward kernel a second time)
 
 
 def _interpret():
@@ -392,6 +405,8 @@ def _bwd(causal, scale, block_q, block_kv, res, g):
     q, k, v, seg, out, lse = res
     do, _ = g  # gradient wrt (out, lse); lse grad unused
     b, s, hq, d = q.shape
+    if lse.ndim == 3:  # kept as lane 0: back to the kernels' layout
+        lse = jnp.broadcast_to(lse[..., None], (*lse.shape, LSE_LANES))
     _, sk, hkv, _ = k.shape
     group = hq // hkv
     bq = min(block_q, s)
@@ -544,20 +559,24 @@ def default_blocks(device_kind=None):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, seg, causal, scale, block_q, block_kv):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q, k, v, seg, causal, scale, block_q, block_kv, slim_lse):
     out, _ = _fwd(q, k, v, seg, causal=causal, scale=scale,
                   block_q=block_q, block_kv=block_kv)
     return out
 
 
-def _flash_fwd(q, k, v, seg, causal, scale, block_q, block_kv):
+def _flash_fwd(q, k, v, seg, causal, scale, block_q, block_kv, slim_lse):
     out, lse = _fwd(q, k, v, seg, causal=causal, scale=scale,
                     block_q=block_q, block_kv=block_kv)
+    if slim_lse:  # every lane holds the same number: lane 0 is kept
+        lse = lse[..., 0]
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, seg, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_kv, res, g):
+def _flash_bwd(causal, scale, block_q, block_kv, slim_lse, res, g):
     dq, dk, dv = _bwd(causal, scale, block_q, block_kv, res, (g, None))
     seg = res[3]
     # segment ids are integral: their cotangent type is float0
@@ -571,7 +590,8 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal=True, scale=None,
-                    block_q=512, block_kv=512, segment_ids=None):
+                    block_q=512, block_kv=512, segment_ids=None,
+                    slim_lse=False):
     """Drop-in replacement for ``sdpa_attention`` (same signature/shapes),
     backed by the Pallas kernels. Total over sequence lengths and head
     dims (masked tail blocks / lane padding); ``segment_ids`` (batch,
@@ -579,6 +599,14 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
     There is NO silent fallback: every valid GQA config runs in the
     kernel, and a malformed one (q heads not a multiple of kv heads)
     raises exactly like ``sdpa_attention`` does.
+
+    ``slim_lse``: the row statistics kept for the backward are lane 0 of
+    what the kernel writes, (b, h, s) and not (b, h, s, 8) padded to 128
+    lanes: a 128th of the bytes while the residual lives through the
+    forward sweep, for one slice and one broadcast a call. For a caller
+    whose ``jax.checkpoint`` policy saves ``flash_lse``; where nothing
+    keeps the residual the pair only costs time (0.5 % of a looped step;
+    PERF.md section 6, PR 31).
 
     Under a mesh the kernel runs PER SHARD inside a ``shard_map`` over the
     batch axes (data, fsdp) and the head axis (tensor) — the layout the
@@ -605,7 +633,7 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
     bk = min(block_kv, sk)
 
     def local(q, k, v, seg):
-        return _flash(q, k, v, seg, causal, scale, bq, bk)
+        return _flash(q, k, v, seg, causal, scale, bq, bk, slim_lse)
 
     spec = _shard_spec(b, hq, hkv)
     if spec is None:

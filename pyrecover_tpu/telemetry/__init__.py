@@ -89,13 +89,17 @@ Core event names across the stack (fields beyond the envelope):
                       per bucket; degenerate=True means the cap admitted
                       everything into one bucket and the step kept the
                       unbucketed single-collective form)
-    remat_autosize    policy, fits, device_kind, budget_bytes,
-                      table_bytes, batch_size, batch_per_chip,
-                      suggested_batch_size, suggested_batch_per_chip,
-                      suggested_total_bytes (once per run under
-                      --remat-policy auto: the policy utils/remat.py
-                      sized against the SC05 HBM model, with the
-                      per-chip batch the freed headroom could carry)
+    remat_autosize    rung, saved_names, fits, device_kind,
+                      limit_bytes, margin_bytes, modelled_bytes (per
+                      rung), fell_back, compiled_peak_bytes,
+                      batch_size, batch_per_chip, suggested_batch_size,
+                      suggested_batch_per_chip, suggested_total_bytes
+                      (once per run with --remat, when the step has
+                      compiled: the rung of utils/remat.py's ladder the
+                      layer scan runs and the checkpoint names it keeps,
+                      the SC05 model's bytes per rung against the
+                      compiler's limit for the device kind, how many
+                      rungs the compiler refused, and its own peak)
     request_admitted  rid, prompt_tokens, max_new_tokens, blocks, slot,
                       queue_s (the serving scheduler admitted a request:
                       a decode slot plus its WHOLE KV-block footprint

@@ -682,13 +682,14 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
 
     dataset, pad_token_id, model_config = build_dataset(config)
 
-    # ---- remat-policy autoscaling (--remat-policy auto) --------------------
-    # sized BEFORE anything builds the model: the SC05 memory model picks
-    # the least recompute that fits this device kind's HBM (utils/remat),
-    # so the headroom zero1 freed becomes throughput. The decision event
-    # is emitted once sinks are live (remat_decision stashed until then).
+    # ---- what the layer scan keeps under remat (utils/remat.py) ------------
+    # resolved BEFORE anything builds the model: under `auto` the richest
+    # save-set of the ladder whose modelled bytes fit what the compiler
+    # enforces for this device kind; `remat: false` is no remat. The
+    # decision's event is emitted once the step is compiled (it carries
+    # the compiler's own peak and whether a rung was refused).
     remat_decision = None
-    if model_config.remat_policy == "auto":
+    if model_config.remat:
         from pyrecover_tpu.utils.remat import resolve_remat_policy
 
         remat_decision = resolve_remat_policy(
@@ -701,18 +702,16 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
             quant_block=config.grad_quant_block,
             device_kind=jax.devices()[0].device_kind,
         )
-        model_config = dataclasses.replace(
-            model_config, remat=remat_decision.remat,
-            remat_policy=remat_decision.remat_policy,
-        )
+        model_config = remat_decision.apply(model_config)
         log_host0(
-            "remat auto: policy %s on %s (modelled %.2f GiB/device vs "
-            "budget %s; per-chip batch suggestion %d)",
-            remat_decision.policy,
+            "remat (%s): rung %s on %s keeps %s (modelled %.2f GiB/device, "
+            "compiler's limit %s; per-chip batch suggestion %d)",
+            model_config.remat_policy, remat_decision.rung,
             remat_decision.device_kind or "<unknown device kind>",
-            remat_decision.table[remat_decision.policy] / 2**30,
-            (f"{remat_decision.budget_bytes / 2**30:.2f} GiB"
-             if remat_decision.budget_bytes else "unknown"),
+            ", ".join(remat_decision.saved_names) or "nothing",
+            remat_decision.table[remat_decision.rung] / 2**30,
+            (f"{remat_decision.limit_bytes / 2**30:.2f} GiB"
+             if remat_decision.limit_bytes else "unknown"),
             remat_decision.suggested_batch_per_chip,
         )
 
@@ -968,16 +967,29 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
         csv_logger.flush()
 
     try:
-        step_fn = make_train_step(
-            model_config, optimizer, loss_chunk_size=config.loss_chunk_size,
-            grad_accumulation_steps=config.grad_accumulation_steps,
-            optimizer_sharding=config.optimizer_sharding,
-            grad_allreduce=config.grad_allreduce,
-            grad_quant_block=config.grad_quant_block,
-            grad_bucket_mb=config.grad_bucket_mb,
-        )
-        if remat_decision is not None:
-            telemetry.emit("remat_autosize", **remat_decision.as_event())
+        def build_step(model_config):
+            return make_train_step(
+                model_config, optimizer,
+                loss_chunk_size=config.loss_chunk_size,
+                grad_accumulation_steps=config.grad_accumulation_steps,
+                optimizer_sharding=config.optimizer_sharding,
+                grad_allreduce=config.grad_allreduce,
+                grad_quant_block=config.grad_quant_block,
+                grad_bucket_mb=config.grad_bucket_mb,
+            )
+
+        if remat_decision is None:
+            step_fn = build_step(model_config)
+        else:
+            from pyrecover_tpu.utils.remat import CompiledOnce
+
+            # compiled before its first call, one rung leaner where the
+            # compiler refuses the chosen one for memory
+            step_fn = CompiledOnce(
+                build_step, model_config, remat_decision,
+                on_ready=lambda decision: telemetry.emit(
+                    "remat_autosize", **decision.as_event()),
+            )
         if config.grad_bucket_mb > 0:
             # one host-side record of the overlap configuration: the
             # bucket layout the step was built to issue (the same

@@ -313,6 +313,7 @@ def _pipelined_1f1b_value_and_grad(params, batch, model_config,
         pipeline_axis_size,
     )
     from pyrecover_tpu.utils.dtypes import resolve_dtype
+    from pyrecover_tpu.utils.remat import checkpoint_policy
 
     cfg = model_config
     cdt = resolve_dtype(cfg.compute_dtype)
@@ -359,12 +360,7 @@ def _pipelined_1f1b_value_and_grad(params, batch, model_config,
         return {"x": new_x, "aux": carry["aux"] + aux}
 
     if cfg.remat:
-        policy = (
-            jax.checkpoint_policies.save_only_these_names("attn_out")
-            if cfg.remat_policy == "save-attn"
-            else jax.checkpoint_policies.nothing_saveable
-        )
-        block_fn = jax.checkpoint(block_fn, policy=policy)
+        block_fn = jax.checkpoint(block_fn, policy=checkpoint_policy(cfg))
 
     def head_fn(hp, carry, d):
         hidden = rms_norm(carry["x"], hp["final_norm"], cfg.norm_eps)

@@ -162,15 +162,42 @@ def test_ffn_hidden_dim_formula():
     assert cfg.ffn_hidden_dim == 14336
 
 
-def test_remat_policies_match_no_remat():
-    """remat=True with both policies ("full" recompute, "save-attn") must
+def _remat_cases():
+    from pyrecover_tpu.utils.remat import LADDER
+
+    # the two explicit policies, then every rung of the ladder that
+    # rematerializes, each under both attention paths (the flash names
+    # exist only in the kernel's own forward rule)
+    cases = [("sdpa", dict(remat_policy="full")),
+             ("sdpa", dict(remat_policy="save-attn")),
+             ("flash", dict(remat_policy="full")),
+             ("flash", dict(remat_policy="save-attn"))]
+    for rung, names in LADDER.items():
+        if rung not in ("none", "full", "save-attn"):
+            cases += [("sdpa", dict(remat_save=names)),
+                      ("flash", dict(remat_save=names))]
+    return cases
+
+
+@pytest.mark.parametrize(
+    "attention,how", _remat_cases(),
+    ids=lambda v: v if isinstance(v, str) else "+".join(
+        v.get("remat_save") or (v["remat_policy"],)),
+)
+def test_remat_policies_match_no_remat(monkeypatch, attention, how):
+    """remat=True, whatever the layer scan keeps (the explicit policies
+    "full" and "save-attn", every rung of utils/remat.py's ladder), must
     produce the same loss AND gradients as remat=False — rematerialization
     is a memory strategy, never a numerics change."""
     import dataclasses
 
     from pyrecover_tpu.models.llama import forward_hidden_with_aux
 
-    base = ModelConfig().tiny(max_seq_len=32, vocab_size=128, n_layers=2)
+    monkeypatch.setenv("PYRECOVER_PALLAS_INTERPRET", "1")
+    base = dataclasses.replace(
+        ModelConfig().tiny(max_seq_len=32, vocab_size=128, n_layers=2),
+        attention_impl=attention,
+    )
     params = init_params(jax.random.key(0), base)
     tokens = jnp.asarray(
         np.random.default_rng(2).integers(0, 128, (2, 32)), dtype=jnp.int32
@@ -185,17 +212,16 @@ def test_remat_policies_match_no_remat():
         jax.value_and_grad(lambda p: loss(p, ref_cfg))
     )(params)
 
-    for policy in ("full", "save-attn"):
-        cfg = dataclasses.replace(base, remat=True, remat_policy=policy)
-        val, grads = jax.jit(
-            jax.value_and_grad(lambda p: loss(p, cfg))
-        )(params)
-        np.testing.assert_allclose(np.asarray(val), np.asarray(ref_val),
-                                   rtol=1e-6)
-        for a, b in zip(jax.tree_util.tree_leaves(ref_grads),
-                        jax.tree_util.tree_leaves(grads)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-5, atol=1e-6)
+    cfg = dataclasses.replace(base, remat=True, **how)
+    val, grads = jax.jit(
+        jax.value_and_grad(lambda p: loss(p, cfg))
+    )(params)
+    np.testing.assert_allclose(np.asarray(val), np.asarray(ref_val),
+                               rtol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(ref_grads),
+                    jax.tree_util.tree_leaves(grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
 
 
 def test_invalid_remat_policy_rejected():

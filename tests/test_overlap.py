@@ -20,9 +20,11 @@ autoscaling"):
     fires on the seeded misconfig (configured bucketed, traced fused);
     the traffic model prices per-bucket legs with the exposed-vs-hidden
     split.
-  * `--remat-policy auto` sizes none/save-attn/full against the SC05
-    HBM model (table-pinned on the llama presets) and suggests the
-    largest per-chip batch the chosen policy still fits.
+  * with `--remat`, `--remat-policy auto` picks a rung of
+    utils/remat.py's ladder against the SC05 HBM model (table-pinned on
+    the llama presets) and suggests the largest per-chip batch the
+    chosen rung still fits (tests/test_remat_ladder.py holds the model
+    to the compiler's peaks at the benchmark cells' shapes).
 """
 
 import dataclasses
@@ -456,74 +458,78 @@ def test_overlap_model_numbers():
 
 
 def test_remat_auto_table_pinned():
-    """The README worked example, pinned: policy decisions on the llama
-    presets against the v5e/v5p budgets (0.9 fraction, zero1)."""
+    """The README worked example, pinned: rungs chosen on the llama
+    presets under `--remat` against the v5e compiler's limit (zero1)."""
     from pyrecover_tpu.models.presets import PRESETS
     from pyrecover_tpu.utils.remat import resolve_remat_policy
 
-    def decide(preset, batch, kind, mesh):
-        mc = PRESETS[preset]()
+    def decide(preset, batch, kind, mesh, remat=True):
+        mc = dataclasses.replace(PRESETS[preset](), remat=remat)
         return resolve_remat_policy(
             mc, mesh, batch_size=batch, seq_len=mc.max_seq_len,
             device_kind=kind, optimizer_sharding="zero1",
         )
 
+    room = lambda d: d.limit_bytes - d.margin_bytes  # noqa: E731
     d = decide("llama-150m", 8, "v5e", {"data": 8})
-    assert d.policy == "none" and d.fits and not d.remat
+    assert d.rung == "none" and d.fits and not d.remat
     assert d.suggested_batch_per_chip == 16
-    assert d.suggested_total_bytes <= d.budget_bytes
+    assert d.suggested_total_bytes <= room(d)
 
     d = decide("llama-1b", 8, "v5e", {"data": 8})
-    assert d.policy == "none" and d.fits
+    assert d.rung == "none" and d.fits
     assert d.suggested_batch_per_chip == 1
 
     d = decide("llama-1b", 32, "v5e", {"data": 8})
-    assert d.policy == "save-attn" and d.fits and d.remat
-    assert d.remat_policy == "save-attn"
+    assert d.rung == "flash+qkv" and d.fits and d.remat
+    assert d.apply(PRESETS["llama-1b"]()).remat_save == d.saved_names
     assert d.suggested_batch_per_chip == 4
-    assert d.suggested_total_bytes <= d.budget_bytes
+    assert d.suggested_total_bytes <= room(d)
 
-    d = decide("llama-1b", 8, "v5p", {"data": 8})
-    assert d.policy == "none" and d.suggested_batch_per_chip == 16
-
-    # nothing fits: leanest policy chosen, loudly not-fitting — SC05
-    # keeps the last word at launch
+    # nothing fits: leanest rung chosen, loudly not-fitting — SC05 and
+    # the compiler keep the last word at launch
     d = decide("llama-8b", 8, "v5e", {"data": 8})
-    assert d.policy == "full" and d.fits is False and d.remat
+    assert d.rung == "full" and d.fits is False and d.remat
 
-    # unknown device kind: no budget to size against — no recompute,
-    # no batch advice
-    d = decide("llama-1b", 8, "", {"data": 8})
-    assert d.policy == "none" and d.fits is None
-    assert d.budget_bytes is None
-    assert d.suggested_batch_size == 8
+    # a device kind nobody asked the compiler about: nothing to size
+    # against — what `--remat` always was, no batch advice
+    for kind in ("v5p", ""):
+        d = decide("llama-1b", 8, kind, {"data": 8})
+        assert d.rung == "full" and d.fits is None
+        assert d.limit_bytes is None
+        assert d.suggested_batch_size == 8
+
+    # without --remat nothing is rematerialized, whatever would fit
+    d = decide("llama-8b", 8, "v5e", {"data": 8}, remat=False)
+    assert d.rung == "none" and d.fits is None and not d.remat
 
 
 def test_remat_auto_policy_ordering_and_env_override(monkeypatch):
     from pyrecover_tpu.utils.remat import (
-        REMAT_POLICIES,
+        RUNGS,
         modelled_total_bytes,
         resolve_remat_policy,
     )
 
-    mc = tiny_model()
-    # the policy walk is fastest-first and monotone in modelled HBM
-    assert [p for p, _, _ in REMAT_POLICIES] == ["none", "save-attn", "full"]
+    mc = dataclasses.replace(tiny_model(), remat=True)
+    # the walk is richest-first and monotone in modelled HBM, from no
+    # remat down to nothing kept, the explicit save-attn among the rungs
+    assert RUNGS[0] == "none" and RUNGS[-2:] == ("save-attn", "full")
     totals = [
         modelled_total_bytes(
-            mc, {"data": 2}, batch_size=8, seq_len=32, policy=p
+            mc, {"data": 2}, batch_size=8, seq_len=32, rung=rung
         )
-        for p, _, _ in REMAT_POLICIES
+        for rung in RUNGS
     ]
-    assert totals[0] >= totals[1] >= totals[2]
+    assert totals == sorted(totals, reverse=True)
     # $PYRECOVER_DEVICE_KIND beats the live/passed device kind (the
     # elastic-preflight convention): a CPU host sizes against v5e
     monkeypatch.setenv("PYRECOVER_DEVICE_KIND", "v5e")
     d = resolve_remat_policy(
         mc, {"data": 2}, batch_size=8, seq_len=32, device_kind="cpu"
     )
-    assert d.device_kind == "v5e" and d.budget_bytes is not None
-    assert d.as_event()["policy"] == d.policy
+    assert d.device_kind == "v5e" and d.limit_bytes is not None
+    assert d.as_event()["rung"] == d.rung
 
 
 # ---- driver-level: events + flag flips -------------------------------------
@@ -615,7 +621,7 @@ def test_grad_bucket_and_remat_autosize_events(tmp_path, monkeypatch):
         tmp_path, training_steps=2, checkpoint_frequency=-1,
         grad_allreduce="int8", grad_bucket_mb=0.05,
     )
-    cfg.model = dataclasses.replace(cfg.model, remat_policy="auto")
+    cfg.model = dataclasses.replace(cfg.model, remat=True)  # auto by default
     sink = telemetry.add_sink(telemetry.MemorySink())
     try:
         train(cfg)
@@ -630,8 +636,12 @@ def test_grad_bucket_and_remat_autosize_events(tmp_path, monkeypatch):
     assert e["max_bucket_bytes"] == max(e["bucket_bytes_f32"])
     remats = [e for e in sink.events if e["event"] == "remat_autosize"]
     assert len(remats) == 1
+    from pyrecover_tpu.utils.remat import RUNGS
+
     assert remats[0]["device_kind"] == "v5e"
-    assert remats[0]["policy"] in ("none", "save-attn", "full")
+    assert remats[0]["rung"] in RUNGS and remats[0]["fell_back"] == 0
+    assert set(remats[0]["modelled_bytes"]) == set(RUNGS)
+    assert remats[0]["compiled_peak_bytes"] > 0  # the step compiled once
 
 
 def test_summarizer_renders_wire_section():
@@ -650,16 +660,19 @@ def test_summarizer_renders_wire_section():
          "mode": "int8", "buckets": 7, "degenerate": False,
          "bucket_bytes_f32": [100, 200], "min_bucket_bytes": 100,
          "max_bucket_bytes": 200},
-        {"ts": 2.2, "event": "remat_autosize", "host": 0, "policy": "none",
-         "fits": True, "device_kind": "v5e", "budget_bytes": 15 << 30,
-         "suggested_batch_per_chip": 16},
+        {"ts": 2.2, "event": "remat_autosize", "host": 0,
+         "rung": "flash+qkv", "fits": True, "device_kind": "v5e",
+         "saved_names": ["flash_out", "flash_lse", "attn_q"],
+         "limit_bytes": 15 << 30, "compiled_peak_bytes": 13 << 30,
+         "fell_back": 0, "suggested_batch_per_chip": 16},
     ]
     agg = st.aggregate(events)
     assert agg["wire"]["grad_bucket"]["buckets"] == 7
-    assert agg["wire"]["remat_autosize"]["policy"] == "none"
+    assert agg["wire"]["remat_autosize"]["rung"] == "flash+qkv"
     assert agg["wire"]["grad_quantize"]["mode"] == "int8"
     out = io.StringIO()
     st.render(agg, out=out)
     text = out.getvalue()
     assert "grad buckets" in text and "7 @ cap 0.05" in text
-    assert "remat auto" in text and "v5e" in text
+    assert "rung flash+qkv on v5e" in text and "flash_lse" in text
+    assert "13.00 GiB of 15.00 GiB" in text

@@ -278,10 +278,13 @@ def aggregate(events):
     if remat:
         e = remat[-1]
         wire["remat_autosize"] = {
-            "policy": e.get("policy"),
+            "rung": e.get("rung"),
+            "saved_names": e.get("saved_names"),
             "fits": e.get("fits"),
             "device_kind": e.get("device_kind"),
-            "budget_bytes": e.get("budget_bytes"),
+            "limit_bytes": e.get("limit_bytes"),
+            "compiled_peak_bytes": e.get("compiled_peak_bytes"),
+            "fell_back": e.get("fell_back"),
             "suggested_batch_per_chip": e.get("suggested_batch_per_chip"),
         }
     agg["wire"] = wire
@@ -785,13 +788,15 @@ def render(agg, out=None):
                   f"backward\n")
         ra = wire.get("remat_autosize")
         if ra:
-            budget = (
-                f"{(ra.get('budget_bytes') or 0) / 2**30:.1f} GiB"
-                if ra.get("budget_bytes") else "unknown"
+            gib = lambda n: (  # noqa: E731
+                f"{n / 2**30:.2f} GiB" if n else "unknown"
             )
-            w(f"  remat auto         policy {ra['policy']} on "
-              f"{ra.get('device_kind') or '<unknown>'} (budget {budget}, "
-              f"suggested per-chip batch "
+            w(f"  remat              rung {ra['rung']} on "
+              f"{ra.get('device_kind') or '<unknown>'} keeps "
+              f"{', '.join(ra.get('saved_names') or []) or 'nothing'} "
+              f"(compiled peak {gib(ra.get('compiled_peak_bytes'))} of "
+              f"{gib(ra.get('limit_bytes'))}, stepped down "
+              f"{ra.get('fell_back') or 0}x, suggested per-chip batch "
               f"{ra.get('suggested_batch_per_chip')})\n")
     ap = agg.get("autopilot") or {}
     if ap.get("decisions"):
