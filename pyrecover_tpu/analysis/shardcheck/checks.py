@@ -198,6 +198,11 @@ def spec_findings(leaves, specs, mesh_shape, config=None, locus="config"):
 # ---- check 2: per-device memory model ---------------------------------------
 
 
+# bytes a token and channel of d_inner that one Mamba layer's mixer holds
+# in the backward sweep (memory_budget, hybrid stacks)
+MAMBA_MIXER_BYTES = 28
+
+
 def _bucket_of(path):
     if path.startswith(".params"):
         return "params"
@@ -270,11 +275,13 @@ def memory_budget(leaves, specs, mesh_shape, model_config, *, batch_size,
     # the cotangents, in model and FFN widths a token (fitted to the
     # compiler's peak at batch 2 and 4 of both cells)
     work = int(tokens * itemsize * (6.5 * cfg.dim + 4 * ffn // tensor))
+
+    def kept(names):
+        return carry + named_bytes(
+            cfg, names, tokens=tokens, itemsize=itemsize, tensor=tensor)
+
     if cfg.remat:
-        per_pass = carry + named_bytes(
-            cfg, saved_names(cfg), tokens=tokens, itemsize=itemsize,
-            tensor=tensor,
-        )
+        per_pass = kept(saved_names(cfg))
     else:
         # no remat: every pass keeps its whole set of intermediates, and
         # the backward's working set is one pass's cotangents
@@ -286,6 +293,27 @@ def memory_budget(leaves, specs, mesh_shape, model_config, *, batch_size,
             tensor=tensor,
         )
     rows["activations_bytes"] = per_pass * passes
+    if cfg.hybrid:
+        # two kinds of layer pass: an attention layer keeps the attention
+        # names of the save-set, a Mamba layer the mixer's, both the
+        # SwiGLU's; the backward sweep's working set is the larger kind's,
+        # a Mamba layer's mixer (u and z, the convolved u, the float32
+        # step, u, y and the gated y, each with its cotangent) counted in
+        # bytes a channel of d_inner (fitted, like the rest, to the v5e
+        # compiler's peak at the benchmark's shape at batch 1 and 2)
+        attn_passes = max(passes // cfg.attn_layer_period, 1)
+        names = saved_names(cfg) if cfg.remat else ()
+        ssm = tuple(n for n in names if n.startswith("ssm_"))
+        shared = tuple(n for n in names if n.startswith("ffn_"))
+        mixer = int(tokens * cfg.d_inner // tensor * MAMBA_MIXER_BYTES)
+        if cfg.remat:
+            attn_pass = kept(tuple(n for n in names if n not in ssm))
+            mamba_pass = kept(ssm + shared)
+            work += mixer
+        else:
+            attn_pass, mamba_pass = per_pass, per_pass + mixer
+        rows["activations_bytes"] = (
+            attn_pass * attn_passes + mamba_pass * (passes - attn_passes))
     if cfg.loop_steps > 1:
         # every pass's normed state is kept for the exit loss
         rows["activations_bytes"] += cfg.loop_steps * carry

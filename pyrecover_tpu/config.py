@@ -189,6 +189,18 @@ class TrainConfig:
             # both schedules: a stage would have to run loop_steps times a
             # step, which neither gpipe nor 1f1b (parallel/pipeline.py) does
             refuse_looped(self.model, "pipeline parallelism (--pp > 1)")
+        if self.model.hybrid:
+            from pyrecover_tpu.models.llama import refuse_hybrid
+
+            # where the model is built, in words, never inside a trace
+            if self.mesh.pipeline > 1:
+                refuse_hybrid(self.model, "pipeline parallelism (--pp > 1)")
+            if self.pack_sequences:
+                raise ValueError(
+                    "--pack-sequences cannot train a hybrid stack "
+                    "(--model-attn-period > 1): the selective scan carries "
+                    "its state across a document boundary (ROADMAP.md M3)"
+                )
         if self.grad_quant_block <= 0:
             raise ValueError(
                 f"--grad-quant-block must be positive, got "
@@ -414,6 +426,22 @@ def build_parser():
                         "cross-entropy over the exits less "
                         "--model-exit-beta x the exit entropy.")
     p.add_argument("--model-exit-beta", type=float, default=d.model.exit_beta)
+    p.add_argument("--model-attn-period", type=int,
+                   default=d.model.attn_layer_period,
+                   help="A hybrid stack: layer i is an attention layer where "
+                        "i %% period == --model-attn-offset and a Mamba-1 "
+                        "layer otherwise (Jamba's attn_layer_period; 1 = "
+                        "every layer attention; d_state 16, dt_rank dim/16, "
+                        "kernel 4, expansion 2 as published). Refused with "
+                        "--pp > 1, --pack-sequences, --moe-experts and the "
+                        "looped flags.")
+    p.add_argument("--model-attn-offset", type=int,
+                   default=d.model.attn_layer_offset)
+    p.add_argument("--model-no-rope", action="store_true",
+                   help="No rotary positions on q and k (a hybrid stack "
+                        "takes its positions from the recurrence).")
+    p.add_argument("--model-tie-embeddings", action="store_true",
+                   help="The head reads the embedding table (no output leaf).")
     p.add_argument("--vocab-size", type=int, default=d.model.vocab_size,
                    help="Used with synthetic data; with a tokenizer, its vocab size wins.")
     p.add_argument("--use_flash_attention", "--use-flash-attention",
@@ -600,6 +628,10 @@ def get_args(argv=None):
         post_norms=ns.model_post_norms,
         exit_gate=ns.model_exit_gate,
         exit_beta=ns.model_exit_beta,
+        attn_layer_period=ns.model_attn_period,
+        attn_layer_offset=ns.model_attn_offset,
+        rope=not ns.model_no_rope,
+        tie_embeddings=ns.model_tie_embeddings,
     )
     return TrainConfig(
         dataset=ns.dataset,

@@ -15,7 +15,7 @@ from pathlib import Path
 import jax
 
 from pyrecover_tpu.utils.logging import log_host0
-from pyrecover_tpu.utils.perf import get_num_flop_per_token, tpu_peak_flops
+from pyrecover_tpu.utils.perf import model_flop_per_token, tpu_peak_flops
 
 
 class LossCSVLogger:
@@ -94,15 +94,10 @@ class ThroughputMeter:
 
         # MoE: only the top-k active experts' FLOPs count toward MFU
         num_params -= inactive_expert_param_count(model_config)
-        # a looped model multiplies every non-embedding weight (layers,
-        # final norm, head, gate) loop_steps times a token: T * L layer
-        # passes and T head evaluations
-        self.flop_per_token = get_num_flop_per_token(
-            num_params * model_config.loop_steps,
-            model_config.layer_passes,
-            model_config.n_heads,
-            model_config.head_dim,
-            seq_len,
+        # layers of each kind, passes, a tied head, the recurrence: one
+        # place knows the model's shape (utils/perf.py)
+        self.flop_per_token = model_flop_per_token(
+            model_config, num_params, seq_len
         )
         self.peak_flops = tpu_peak_flops()
         self.n_devices = n_devices or jax.device_count()

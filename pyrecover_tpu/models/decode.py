@@ -33,6 +33,7 @@ import numpy as np
 from pyrecover_tpu.models.llama import (
     attn_residual,
     ffn_sublayer,
+    head_logits,
     qkv_proj,
     rms_norm,
 )
@@ -56,6 +57,11 @@ def init_kv_cache(config, batch_size, max_len, dtype=None):
     attention slices aligned KV blocks; the extra tail positions are
     always masked (callers' logical capacity is what they asked for)."""
     cfg = config
+    from pyrecover_tpu.models.llama import refuse_hybrid
+
+    # the cache is keys and values per layer: a Mamba layer's state (its
+    # convolution's last tokens and d_inner x d_state floats) has no place
+    refuse_hybrid(cfg, "the key/value-cached decoder (models/decode.py)")
     dt = resolve_dtype(dtype or cfg.compute_dtype)
     max_len = int(max_len)
     if max_len > _DECODE_BLOCK and max_len % _DECODE_BLOCK:
@@ -160,9 +166,11 @@ def decode_forward(params, cache, tokens, pos, config):
     hd = cfg.head_dim
     max_len = cache["k"].shape[2]
 
-    cos_all, sin_all = precompute_rope(hd, max_len, cfg.rope_theta)
-    cos = jax.lax.dynamic_slice_in_dim(cos_all, pos, c, axis=0)
-    sin = jax.lax.dynamic_slice_in_dim(sin_all, pos, c, axis=0)
+    cos = sin = None
+    if cfg.rope:
+        cos_all, sin_all = precompute_rope(hd, max_len, cfg.rope_theta)
+        cos = jax.lax.dynamic_slice_in_dim(cos_all, pos, c, axis=0)
+        sin = jax.lax.dynamic_slice_in_dim(sin_all, pos, c, axis=0)
     scale = 1.0 / (hd**0.5)
 
     x = params["tok_embed"].astype(cdt)[tokens]
@@ -202,11 +210,7 @@ def decode_forward(params, cache, tokens, pos, config):
     )
     new_k = new_k.reshape(cache["k"].shape)
     new_v = new_v.reshape(cache["v"].shape)
-    logits = jnp.einsum(
-        "bcd,dv->bcv", hidden, params["output"].astype(cdt),
-        preferred_element_type=jnp.float32,
-    )
-    return logits, {"k": new_k, "v": new_v}
+    return head_logits(params, hidden, cfg), {"k": new_k, "v": new_v}
 
 
 def generate_tokens(params, config, prompt_ids, max_new_tokens, *,

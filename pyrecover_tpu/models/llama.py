@@ -105,8 +105,56 @@ class ModelConfig:
     # (train_state.chunked_exit_loss)
     exit_gate: bool = False
     exit_beta: float = 0.1
+    # -- hybrid stack: a period of layer kinds (period 1 = every layer an
+    # attention layer, the plain decoder). Layer i is an attention layer
+    # where i % attn_layer_period == attn_layer_offset and a Mamba-1 layer
+    # otherwise (the names of AI21's Jamba configs); every layer keeps the
+    # SwiGLU feed-forward. The stack is scanned by period: the Mamba layers
+    # before the attention layer as one scan, the attention layer, the
+    # Mamba layers after it as another.
+    attn_layer_period: int = 1
+    attn_layer_offset: int = 0
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0  # 0 -> ceil(dim / 16)
+    # rotary positions on q and k (a hybrid stack takes its positions from
+    # the recurrence and publishes no rope)
+    rope: bool = True
+    # the head reads the embedding table: logits = h . tok_embed^T, one
+    # leaf fed gradients from both ends, no "output" leaf
+    tie_embeddings: bool = False
 
     def __post_init__(self):
+        if self.attn_layer_period < 1 or not (
+            0 <= self.attn_layer_offset < self.attn_layer_period
+        ):
+            raise ValueError(
+                f"attn_layer_period={self.attn_layer_period} / "
+                f"attn_layer_offset={self.attn_layer_offset}: the period "
+                "must be >= 1 and the offset inside it"
+            )
+        if self.hybrid and self.n_layers % self.attn_layer_period:
+            raise ValueError(
+                f"a hybrid stack is scanned by period: n_layers="
+                f"{self.n_layers} is not a multiple of attn_layer_period="
+                f"{self.attn_layer_period}"
+            )
+        if self.hybrid and self.n_experts > 0:
+            raise ValueError(
+                "a mixture of experts with Mamba layers is not supported "
+                "(--moe-experts with --model-attn-period > 1): the expert "
+                "layers of a hybrid stack have a period of their own, "
+                "which the stack does not describe (ROADMAP.md M3)"
+            )
+        if self.hybrid and (
+            self.loop_steps > 1 or self.post_norms or self.exit_gate
+        ):
+            raise ValueError(
+                "a looped, sandwich-normed or gated hybrid stack is not "
+                "supported (untested); drop --model-attn-period or the "
+                "--model-loop-* / --model-post-norms flags"
+            )
         if self.loop_steps < 1:
             raise ValueError(
                 f"loop_steps (--model-loop-steps) must be >= 1, got "
@@ -156,6 +204,44 @@ class ModelConfig:
         return self.dim // self.n_heads
 
     @property
+    def hybrid(self):
+        """Whether the stack holds Mamba layers beside attention layers."""
+        return self.attn_layer_period > 1
+
+    @property
+    def n_attn_layers(self):
+        return self.n_layers // self.attn_layer_period
+
+    @property
+    def n_mamba_layers(self):
+        return self.n_layers - self.n_attn_layers
+
+    @property
+    def d_inner(self):
+        return self.mamba_expand * self.dim
+
+    @property
+    def dt_rank(self):
+        return self.mamba_dt_rank or -(-self.dim // 16)
+
+    @property
+    def ssm_state_elems(self):
+        """Floats of recurrent state one token of one Mamba layer has."""
+        return self.d_inner * self.mamba_d_state
+
+    def layer_groups(self):
+        """The groups of stacked layer leaves a stack holds, in the order a
+        period runs them: ``[(name, kind, layers a period)]``; a stack of
+        one kind has the one group ``attn``. The Mamba layers of a period
+        lie in two groups, before and after its attention layer, so that
+        each is scanned without a slice."""
+        pre = self.attn_layer_offset
+        post = self.attn_layer_period - 1 - pre
+        groups = [("mamba_pre", "mamba", pre), ("attn", "attn", 1),
+                  ("mamba_post", "mamba", post)]
+        return [g for g in groups if g[2] > 0]
+
+    @property
     def layer_passes(self):
         """Layer evaluations a forward makes: the work and the saved
         activations scale with this, the parameters with ``n_layers``."""
@@ -197,6 +283,19 @@ def refuse_looped(config, path):
         )
 
 
+def refuse_hybrid(config, path):
+    """One sentence for the paths that hold a key/value cache per layer or
+    split a stack of one layer kind, and cannot hold a recurrent state or
+    split a period; called where the model or the engine is built."""
+    if config.hybrid:
+        raise ValueError(
+            f"{path} cannot run a hybrid stack (attn_layer_period="
+            f"{config.attn_layer_period}: {config.n_mamba_layers} Mamba "
+            "layers): it has no place for a recurrent state and splits "
+            "layers of one kind (ROADMAP.md M3, M7)"
+        )
+
+
 def _normal_init(key, shape, std, dtype):
     return (jax.random.normal(key, shape, dtype=jnp.float32) * std).astype(dtype)
 
@@ -208,55 +307,46 @@ def init_params(rng, config):
     projections (wo, w2) scaled by 1/sqrt(2*n_layers). (The reference leans
     on torch's nn.Linear defaults — init parity is not a capability, training
     stability is.)
+
+    ``layers`` holds one group of stacked leaves per entry of
+    ``cfg.layer_groups()``, a group's leading axis running over its layers
+    in stack order (period after period). A stack of one kind is the period
+    of one: its one group IS ``layers`` (the tree every checkpoint holds)
+    and draws from the ten keys it always drew from; a hybrid stack's
+    ``layers/mamba_pre``, ``layers/attn``, ``layers/mamba_post`` draw from
+    ``fold_in(rng, 100 + g)`` each, so that no group's draws move when
+    another's leaves change.
     """
+    from pyrecover_tpu.models.mamba import init_mamba_layers
+
     cfg = config
     pdt = resolve_dtype(cfg.param_dtype)
-    hd = cfg.head_dim
-    ffn = cfg.ffn_hidden_dim
-    L = cfg.n_layers
     std = 0.02
-    resid_std = std / (2 * L) ** 0.5
+    resid_std = std / (2 * cfg.n_layers) ** 0.5
 
     keys = jax.random.split(rng, 10)
-
-    def stacked(key, shape, s):
-        # one independent draw per layer, stacked on axis 0
-        ks = jax.random.split(key, L)
-        return jnp.stack([_normal_init(k, shape, s, pdt) for k in ks])
-
-    layers = {
-        "attn_norm": jnp.ones((L, cfg.dim), dtype=pdt),
-        "wq": stacked(keys[1], (cfg.dim, cfg.n_heads * hd), std),
-        "wk": stacked(keys[2], (cfg.dim, cfg.n_kv_heads * hd), std),
-        "wv": stacked(keys[3], (cfg.dim, cfg.n_kv_heads * hd), std),
-        "wo": stacked(keys[4], (cfg.n_heads * hd, cfg.dim), resid_std),
-        "ffn_norm": jnp.ones((L, cfg.dim), dtype=pdt),
-    }
-    if cfg.post_norms:
-        layers["attn_post_norm"] = jnp.ones((L, cfg.dim), dtype=pdt)
-        layers["ffn_post_norm"] = jnp.ones((L, cfg.dim), dtype=pdt)
-    if cfg.n_experts > 0:
-        E, F = cfg.n_experts, cfg.expert_hidden_dim
-        layers.update({
-            # router in f32 regardless of param dtype: routing decisions are
-            # discrete (top-k), so router precision moves token assignment
-            "router": stacked(keys[5], (cfg.dim, E), std).astype(jnp.float32),
-            "moe_w1": stacked(keys[6], (E, cfg.dim, F), std),
-            "moe_w3": stacked(keys[7], (E, cfg.dim, F), std),
-            "moe_w2": stacked(keys[9], (E, F, cfg.dim), resid_std),
-        })
-    else:
-        layers.update({
-            "w1": stacked(keys[5], (cfg.dim, ffn), std),
-            "w3": stacked(keys[6], (cfg.dim, ffn), std),
-            "w2": stacked(keys[7], (ffn, cfg.dim), resid_std),
-        })
+    groups = {}
+    for g, (name, kind, per_period) in enumerate(cfg.layer_groups()):
+        count = per_period * cfg.n_attn_layers
+        # jaxlint: disable-next=prng-key-reuse -- deliberate: fold_in
+        # derives one stream a group beside the ten split above
+        key = jax.random.fold_in(rng, 100 + g) if cfg.hybrid else None
+        if kind == "mamba":
+            groups[name] = init_mamba_layers(
+                key, cfg, count, pdt, std, resid_std)
+        else:
+            drawn = (jax.random.split(key, 7) if cfg.hybrid
+                     else (*keys[1:8], keys[9]))
+            groups[name] = _init_attn_layers(
+                drawn, cfg, count, pdt, std, resid_std)
     params = {
         "tok_embed": _normal_init(keys[0], (cfg.vocab_size, cfg.dim), std, pdt),
-        "layers": layers,
+        "layers": groups if cfg.hybrid else groups["attn"],
         "final_norm": jnp.ones((cfg.dim,), dtype=pdt),
-        "output": _normal_init(keys[8], (cfg.dim, cfg.vocab_size), std, pdt),
     }
+    if not cfg.tie_embeddings:
+        params["output"] = _normal_init(
+            keys[8], (cfg.dim, cfg.vocab_size), std, pdt)
     if cfg.exit_gate:
         # the gate's key is folded in BESIDE the ten: split(rng, 11)[:10]
         # is not split(rng, 10), and every other leaf must draw as before
@@ -266,6 +356,46 @@ def init_params(rng, config):
         params["exit_gate_w"] = _normal_init(gate_key, (cfg.dim, 1), std, pdt)
         params["exit_gate_b"] = jnp.zeros((1,), dtype=pdt)
     return params
+
+
+def _init_attn_layers(keys, cfg, count, pdt, std, resid_std):
+    """``count`` attention layers stacked on axis 0, one independent draw a
+    layer and leaf. ``keys``: wq, wk, wv, wo, then the feed-forward's (w1,
+    w3, w2; with experts router, moe_w1, moe_w3 and, eighth, moe_w2)."""
+    hd, ffn = cfg.head_dim, cfg.ffn_hidden_dim
+
+    def stacked(key, shape, s):
+        ks = jax.random.split(key, count)
+        return jnp.stack([_normal_init(k, shape, s, pdt) for k in ks])
+
+    layers = {
+        "attn_norm": jnp.ones((count, cfg.dim), dtype=pdt),
+        "wq": stacked(keys[0], (cfg.dim, cfg.n_heads * hd), std),
+        "wk": stacked(keys[1], (cfg.dim, cfg.n_kv_heads * hd), std),
+        "wv": stacked(keys[2], (cfg.dim, cfg.n_kv_heads * hd), std),
+        "wo": stacked(keys[3], (cfg.n_heads * hd, cfg.dim), resid_std),
+        "ffn_norm": jnp.ones((count, cfg.dim), dtype=pdt),
+    }
+    if cfg.post_norms:
+        layers["attn_post_norm"] = jnp.ones((count, cfg.dim), dtype=pdt)
+        layers["ffn_post_norm"] = jnp.ones((count, cfg.dim), dtype=pdt)
+    if cfg.n_experts > 0:
+        E, F = cfg.n_experts, cfg.expert_hidden_dim
+        layers.update({
+            # router in f32 regardless of param dtype: routing decisions are
+            # discrete (top-k), so router precision moves token assignment
+            "router": stacked(keys[4], (cfg.dim, E), std).astype(jnp.float32),
+            "moe_w1": stacked(keys[5], (E, cfg.dim, F), std),
+            "moe_w3": stacked(keys[6], (E, cfg.dim, F), std),
+            "moe_w2": stacked(keys[7], (E, F, cfg.dim), resid_std),
+        })
+    else:
+        layers.update({
+            "w1": stacked(keys[4], (cfg.dim, ffn), std),
+            "w3": stacked(keys[5], (cfg.dim, ffn), std),
+            "w2": stacked(keys[6], (ffn, cfg.dim), resid_std),
+        })
+    return layers
 
 
 def rms_norm(x, scale, eps):
@@ -314,6 +444,8 @@ def qkv_proj(h, layer, config, cos, sin):
     q = (h @ layer["wq"].astype(cdt)).reshape(b, s, cfg.n_heads, hd)
     k = (h @ layer["wk"].astype(cdt)).reshape(b, s, cfg.n_kv_heads, hd)
     v = (h @ layer["wv"].astype(cdt)).reshape(b, s, cfg.n_kv_heads, hd)
+    if not cfg.rope:
+        return q, k, v
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
@@ -399,7 +531,9 @@ def _stack(params, tokens, config, segment_ids):
     cdt = resolve_dtype(cfg.compute_dtype)
     seq_len = tokens.shape[1]
 
-    cos, sin = precompute_rope(cfg.head_dim, seq_len, cfg.rope_theta)
+    cos = sin = None
+    if cfg.rope:
+        cos, sin = precompute_rope(cfg.head_dim, seq_len, cfg.rope_theta)
     attn_fn = _attention_fn(cfg)
 
     x = params["tok_embed"].astype(cdt)[tokens]
@@ -427,13 +561,29 @@ def _stack(params, tokens, config, segment_ids):
         out = dict(carry, x=new_x, aux=carry["aux"] + aux)
         return out
 
-    if cfg.remat:
-        block_carry = jax.checkpoint(block_carry, policy=checkpoint_policy(cfg))
+    # one carry function a layer kind, each rematerialised alike
+    kinds = {"attn": block_carry}
+    if cfg.hybrid:
+        from pyrecover_tpu.models.mamba import mamba_block
 
-    # Under a mesh with a pipeline axis >1 this runs the microbatched
-    # ppermute schedule (stages hold layer slices); otherwise it reduces to
-    # a plain lax.scan over the stacked layers.
-    from pyrecover_tpu.parallel.pipeline import pipeline_blocks
+        if segment_ids is not None:
+            raise ValueError(
+                "packed sequences (--pack-sequences, segment_ids) cannot run "
+                "through a hybrid stack: the selective scan carries its "
+                "state across a document boundary (ROADMAP.md M3)"
+            )
+
+        def mamba_carry(carry, layer):
+            new_x, aux = mamba_block(carry["x"], layer, cfg)
+            return dict(carry, x=new_x, aux=carry["aux"] + aux)
+
+        kinds["mamba"] = mamba_carry
+    if cfg.remat:
+        policy = checkpoint_policy(cfg)
+        kinds = {
+            kind: jax.checkpoint(fn, policy=policy)
+            for kind, fn in kinds.items()
+        }
 
     carry = {
         "x": x,
@@ -441,14 +591,55 @@ def _stack(params, tokens, config, segment_ids):
     }
     if segment_ids is not None:
         carry["seg"] = segment_ids.astype(jnp.int32)
+    return carry, partial(_run_periods, params["layers"], cfg, kinds)
 
-    def run_stack(carry):
-        return pipeline_blocks(
-            params["layers"], carry, block_carry,
-            n_microbatches=cfg.pp_microbatches,
-        )
 
-    return carry, run_stack
+def _run_periods(layers, config, kinds, carry):
+    """The stack, period by period. Inside a period each group of
+    ``config.layer_groups()`` in turn: a group of several layers as one scan
+    (the Mamba layers before the attention layer, those after it), a group
+    of one layer applied as it is. The periods as a scan round that, none
+    for one period. A stack of one kind is the period of one attention
+    layer, ``layers`` its one group: the plain scan over the stacked layers
+    it always was. ``kinds`` maps a layer kind to its carry function."""
+    from pyrecover_tpu.parallel.pipeline import (
+        pipeline_axis_size,
+        pipeline_blocks,
+    )
+
+    cfg = config
+    if cfg.hybrid and pipeline_axis_size() > 1:
+        refuse_hybrid(cfg, "the pipeline schedule (--pp > 1)")
+    tmap = jax.tree_util.tree_map
+    periods = cfg.n_attn_layers
+    order = cfg.layer_groups()
+    groups = layers if cfg.hybrid else {"attn": layers}
+
+    def one_period(carry, leaves):
+        for name, kind, per_period in order:
+            if per_period == 1:
+                carry = kinds[kind](carry, leaves[name])
+            else:
+                carry, _ = jax.lax.scan(
+                    lambda c, layer, fn=kinds[kind]: (fn(c, layer), None),
+                    carry, leaves[name])
+        return carry
+
+    # (periods * n, ...) -> (periods, n, ...): a reshape, never a slice,
+    # which would copy a group's weights
+    leaves = {
+        name: groups[name] if n == 1 else tmap(
+            lambda a, n=n: a.reshape(periods, n, *a.shape[1:]), groups[name])
+        for name, _, n in order
+    }
+    if periods == 1:
+        return one_period(
+            carry, tmap(lambda a: a.reshape(a.shape[1:]), leaves))
+    # Under a mesh with a pipeline axis >1 this runs the microbatched
+    # ppermute schedule (stages hold layer slices); otherwise it reduces to
+    # a plain lax.scan over the periods.
+    return pipeline_blocks(
+        leaves, carry, one_period, n_microbatches=cfg.pp_microbatches)
 
 
 def exit_gate_logits(params, hidden, config):
@@ -518,14 +709,23 @@ def forward_hidden(params, tokens, config, segment_ids=None):
     return forward_hidden_with_aux(params, tokens, config, segment_ids)[0]
 
 
-def project_vocab(params, hidden, config):
-    """Untied vocab projection (reference model.py:367,394), fp32 logits."""
+def head_logits(params, hidden, config):
+    """fp32 logits of (batch, tokens, dim) states: through the ``output``
+    leaf, or with ``tie_embeddings`` through the embedding table read the
+    other way. Shared by the training head and the cached decoder."""
     cdt = resolve_dtype(config.compute_dtype)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", hidden, params["output"].astype(cdt),
-        preferred_element_type=jnp.float32,
-    )
-    return constrain(logits, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR)
+    leaf, product = (("tok_embed", "bsd,vd->bsv") if config.tie_embeddings
+                     else ("output", "bsd,dv->bsv"))
+    return jnp.einsum(product, hidden, params[leaf].astype(cdt),
+                      preferred_element_type=jnp.float32)
+
+
+def project_vocab(params, hidden, config):
+    """Vocab projection, fp32 logits: untied (reference model.py:367,394)
+    or, with ``tie_embeddings``, through the embedding table."""
+    return constrain(
+        head_logits(params, hidden, config),
+        (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR)
 
 
 def forward(params, tokens, config, segment_ids=None):

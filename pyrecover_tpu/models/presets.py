@@ -91,7 +91,10 @@ def analytic_param_count(cfg, exclude_embedding=False):
 
     ``exclude_embedding`` drops the token-embedding table (the reference's
     FLOPs-accounting convention, train.py:126-127); the untied output
-    projection stays, as it does in the reference.
+    projection stays, as it does in the reference. A tied head
+    (``tie_embeddings``) is the table itself and counts once; a hybrid
+    stack counts its attention layers and its Mamba layers each at their
+    own size.
     """
     hd = cfg.head_dim
     per_layer = (
@@ -107,12 +110,23 @@ def analytic_param_count(cfg, exclude_embedding=False):
         per_layer += 3 * cfg.dim * cfg.ffn_hidden_dim
     if cfg.post_norms:
         per_layer += 2 * cfg.dim
+    layers = cfg.n_attn_layers * per_layer
+    if cfg.hybrid:
+        # a Mamba layer (models/mamba.py): its mixer's leaves, the SwiGLU
+        # and two norms
+        import math
+
+        from pyrecover_tpu.models.mamba import mamba_leaf_shapes
+
+        layers += cfg.n_mamba_layers * sum(
+            math.prod(shape) for shape in mamba_leaf_shapes(cfg).values())
     embed = 0 if exclude_embedding else cfg.vocab_size * cfg.dim
+    head = 0 if cfg.tie_embeddings else cfg.dim * cfg.vocab_size
     return (
         embed
-        + cfg.n_layers * per_layer
+        + layers
         + cfg.dim
-        + cfg.dim * cfg.vocab_size
+        + head
         + (cfg.dim + 1 if cfg.exit_gate else 0)
     )
 

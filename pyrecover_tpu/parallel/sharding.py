@@ -56,6 +56,23 @@ _RULES = {
     "attn_post_norm": P(AXIS_PIPE, None),
     "ffn_post_norm": P(AXIS_PIPE, None),
     "final_norm": P(None),
+    # Mamba layers of a hybrid stack (models/mamba.py), stacked like the
+    # others: the two wide products split as the SwiGLU's are, the rest of
+    # a mixer (convolution, inner norms, step and state leaves) is small
+    # and replicated within a stage
+    "mixer_norm": P(AXIS_PIPE, None),
+    "in_proj": P(AXIS_PIPE, AXIS_FSDP, AXIS_TENSOR),
+    "out_proj": P(AXIS_PIPE, AXIS_TENSOR, AXIS_FSDP),
+    "conv_w": P(AXIS_PIPE, None, None),
+    "conv_b": P(AXIS_PIPE, None),
+    "x_proj": P(AXIS_PIPE, None, None),
+    "dt_norm": P(AXIS_PIPE, None),
+    "b_norm": P(AXIS_PIPE, None),
+    "c_norm": P(AXIS_PIPE, None),
+    "dt_proj": P(AXIS_PIPE, None, None),
+    "dt_bias": P(AXIS_PIPE, None),
+    "a_log": P(AXIS_PIPE, None, None),
+    "d_skip": P(AXIS_PIPE, None),
     # exit gate Linear(D -> 1) of a looped model: tiny, replicated
     "exit_gate_w": P(None, None),
     "exit_gate_b": P(None),

@@ -113,12 +113,19 @@ class BlockPool:
                 f"the pool needs >= 2 blocks (block 0 is reserved as the "
                 f"trash block), got {n_blocks}"
             )
-        from pyrecover_tpu.models.llama import refuse_looped
+        from pyrecover_tpu.models.llama import refuse_hybrid, refuse_looped
 
         # a block's footprint is priced per layer (kv_token_bytes) and the
         # paged sweep runs the layers once; ServingEngine, and with it the
         # fleet's replicas, build their pool here, so they refuse here too
         refuse_looped(config, "the paged serving engine (BlockPool)")
+        refuse_hybrid(config, "the paged serving engine (BlockPool)")
+        if config.tie_embeddings or not config.rope:
+            raise ValueError(
+                "the paged serving engine (BlockPool) sweeps layers with "
+                "rotary positions and reads an untied head: a model with "
+                "tie_embeddings or without rope is not served by it"
+            )
         self.config = config
         self.n_blocks = int(n_blocks)
         self.block_size = int(block_size)

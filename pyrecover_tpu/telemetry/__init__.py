@@ -12,7 +12,10 @@ Event envelope (every record):
     host    jax process index of the emitting host
 
 Core event names across the stack (fields beyond the envelope):
-    run_start         devices, device_kind, processes, mesh, params_m, ...
+    run_start         devices, device_kind, processes, mesh, params_m,
+                      loop_steps, layer_passes, mamba_layers, attn_layers,
+                      ssm_state_elems, scan_chunk (a hybrid stack's: 0
+                      Mamba layers and no state in a plain decoder), ...
     step_time         step, data_wait_s, dispatch_s
     train_sync        step, loss, steps, interval_s, iter_s, sync_s
     throughput        step, tokens_per_sec, mfu_pct, tflops, ...

@@ -26,6 +26,7 @@ from pyrecover_tpu.checkpoint.engine import open_engine
 from pyrecover_tpu.config import TrainConfig, get_args
 from pyrecover_tpu.data import DataLoader, StatefulSampler, SyntheticTextDataset
 from pyrecover_tpu.metrics import LossCSVLogger, ThroughputMeter, WallTimeTotals
+from pyrecover_tpu.ops import selective_scan
 from pyrecover_tpu.optim import build_optimizer
 from pyrecover_tpu.parallel.mesh import create_mesh, initialize_distributed
 from pyrecover_tpu.parallel.sharding import _leaf_rule
@@ -791,6 +792,14 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
         # the work and the saved carries scale with layer_passes
         loop_steps=model_config.loop_steps,
         layer_passes=model_config.layer_passes,
+        # a hybrid stack: layers of each kind, the floats of recurrent
+        # state a token has in one Mamba layer, the scan's chunk
+        attn_layers=model_config.n_attn_layers,
+        mamba_layers=model_config.n_mamba_layers,
+        ssm_state_elems=(
+            model_config.ssm_state_elems if model_config.hybrid else 0),
+        scan_chunk=(
+            selective_scan.SCAN_CHUNK if model_config.hybrid else 0),
         batch_size=config.batch_size,
         sequence_length=config.sequence_length,
         grad_accum_steps=config.grad_accumulation_steps,

@@ -518,6 +518,17 @@ def make_train_step(model_config, optimizer, donate=True, loss_chunk_size=0,
             "the gpipe schedule only; the 1f1b pipeline runs its own "
             "manual region"
         )
+    if model_config.pp_schedule == "1f1b" and (
+        model_config.hybrid or model_config.tie_embeddings
+        or not model_config.rope
+    ):
+        raise ValueError(
+            "--pp-schedule 1f1b hands the schedule an embedding, blocks of "
+            "one kind with rotary positions and an untied head as separate "
+            "pieces: a hybrid stack (--model-attn-period > 1), "
+            "--model-tie-embeddings and --model-no-rope train under the "
+            "gpipe schedule or without --pp"
+        )
     if model_config.pp_schedule == "1f1b" and A > 1:
         raise ValueError(
             "--grad-accumulation-steps composes with the gpipe pipeline "

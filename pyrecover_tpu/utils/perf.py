@@ -108,8 +108,31 @@ def get_num_flop_per_token(num_params, n_layers, n_heads, head_dim, seq_len):
 
     6N covers fwd+bwd matmul FLOPs on non-embedding params; the second term
     is the attention score/value FLOPs which scale with sequence length.
-    For a looped model the caller passes the weights a token is multiplied
-    with (``loop_steps`` x the held parameters) and ``layer_passes`` for
-    ``n_layers`` (metrics.ThroughputMeter).
+    ``model_flop_per_token`` feeds it a looped, hybrid or tied model's
+    counts (metrics.ThroughputMeter).
     """
     return 6 * num_params + 12 * n_layers * n_heads * head_dim * seq_len
+
+
+def model_flop_per_token(model_config, num_params, seq_len):
+    """``get_num_flop_per_token`` for a ``ModelConfig``: ``num_params`` the
+    non-embedding parameters a token is multiplied with in one pass
+    (inactive experts already taken out). A looped model multiplies every
+    weight ``loop_steps`` times; the attention term counts the ATTENTION
+    layers alone (a hybrid stack has ``n_attn_layers`` of them, not
+    ``n_layers``); a tied head multiplies the embedding table once, which
+    the non-embedding count left out; a Mamba layer adds its recurrence (6
+    operations a (channel, state) pair forward, 3 x that trained) and its
+    depthwise convolution, which are no parameters' products."""
+    cfg = model_config
+    if cfg.tie_embeddings:
+        num_params += cfg.vocab_size * cfg.dim
+    flops = get_num_flop_per_token(
+        num_params * cfg.loop_steps,
+        cfg.n_attn_layers * cfg.loop_steps,
+        cfg.n_heads, cfg.head_dim, seq_len,
+    )
+    if cfg.hybrid:
+        flops += 3 * cfg.n_mamba_layers * cfg.d_inner * (
+            6 * cfg.mamba_d_state + 2 * cfg.mamba_d_conv)
+    return flops

@@ -17,6 +17,12 @@ saves, and the values worth saving carry a ``checkpoint_name``:
                            repeated
     ffn_w1, ffn_w3         the two SwiGLU products before the activation
                            (``ffn_sublayer``, dense path)
+    ssm_in, ssm_conv,      a Mamba layer's mixer (models/mamba.py): the
+    ssm_dt, ssm_y          input product (u and z), the convolved and
+                           activated u, the float32 step, the scan's
+                           output. Named and on no rung yet: a hybrid
+                           stack is sized as it stands (``full`` at the
+                           benchmark's shape), PERF.md section 7
 
 ``LADDER`` lists the save-sets worth choosing between, richest first:
 ``none`` (no remat at all) down to ``full`` (nothing kept; every block
@@ -130,6 +136,12 @@ def named_bytes(model_config, names, *, tokens, itemsize, tensor=1):
         "attn_resid": cfg.dim * itemsize,
         "ffn_w1": cfg.ffn_hidden_dim // tensor * itemsize if dense else 0,
         "ffn_w3": cfg.ffn_hidden_dim // tensor * itemsize if dense else 0,
+        # a Mamba layer's mixer (models/mamba.py): u and z, the convolved
+        # u, the float32 step and the scan's float32 output
+        "ssm_in": 2 * cfg.d_inner // tensor * itemsize,
+        "ssm_conv": cfg.d_inner // tensor * itemsize,
+        "ssm_dt": cfg.d_inner // tensor * 4,
+        "ssm_y": cfg.d_inner // tensor * 4,
     }
     return tokens * sum(per_token[name] for name in names)
 

@@ -121,6 +121,14 @@ def main(argv=None):
                          "same names (custom shape only)")
     ap.add_argument("--model-post-norms", action="store_true")
     ap.add_argument("--model-exit-gate", action="store_true")
+    ap.add_argument("--model-tie-embeddings", action="store_true",
+                    help="the checkpoint's head is its embedding table")
+    ap.add_argument("--model-no-rope", action="store_true")
+    ap.add_argument("--model-attn-period", type=int, default=1,
+                    help="a hybrid checkpoint (Mamba layers): refused in "
+                         "words, the key/value cache holds no recurrent "
+                         "state")
+    ap.add_argument("--model-attn-offset", type=int, default=0)
     ap.add_argument("--prompt-ids", default="1",
                     help="comma-separated token ids; ';' separates a BATCH "
                          "of equal-length prompts decoded in lockstep "
@@ -152,6 +160,10 @@ def main(argv=None):
                 loop_steps=args.model_loop_steps,
                 post_norms=args.model_post_norms,
                 exit_gate=args.model_exit_gate,
+                tie_embeddings=args.model_tie_embeddings,
+                rope=not args.model_no_rope,
+                attn_layer_period=args.model_attn_period,
+                attn_layer_offset=args.model_attn_offset,
             )
         else:
             if any(shape_flags) or args.multiple_of:
