@@ -17,10 +17,11 @@ chunk from its boundary before it runs the recurrence's adjoint over
 them. One ``jax.custom_vjp`` holds that contract for both formulations:
 
 ``pallas``  the kernel pair ``ssm_scan_fwd`` / ``ssm_scan_bwd``: grid
-            (batch, channel block, chunk), the chunks innermost and
-            sequential with the state ``(d_state, channel block)`` in
-            VMEM scratch, channels on the lanes and states on the
-            sublanes, the tokens of a chunk in a loop of 8-token tiles.
+            (batch, chunk, channel block), the chunks sequential with
+            every block's state in VMEM scratch; a token's channel
+            block fills whole vector registers and every state has
+            registers of its own, the tokens of a chunk in a loop of
+            8-token tiles.
 ``xla``     a ``lax.scan`` over chunks round a ``lax.scan`` over tokens,
             the chunk's adjoint by ``jax.vjp`` of the inner scan. What
             the CPU runs, what a mesh of several devices runs (a Mosaic
@@ -72,9 +73,9 @@ def resolve_impl(impl, d, n, chunk):
     tiled = pallas_supported(d, n, chunk)
     if impl == "pallas" and not tiled:
         raise ValueError(
-            f"selective scan impl 'pallas': the kernels tile whole lane "
-            f"tiles of channels ({LANES}), whole sublane tiles of states "
-            f"({GROUP}) and chunks of whole lane tiles of tokens; got "
+            f"selective scan impl 'pallas': the kernels tile whole registers "
+            f"of channels ({REGISTER}), a power of two of states up to "
+            f"{LANES // 2} and chunks of whole {GROUP}-token tiles; got "
             f"d_inner={d}, d_state={n}, chunk={chunk}"
         )
     if impl != "auto":
@@ -170,7 +171,7 @@ def selective_scan(u, dt, a, b, c, d_skip, *, chunk=None, impl="auto"):
     not divide is padded with tokens of step nought, which leave the state
     as it is."""
     f32 = jnp.float32
-    u, dt, b, c = (x.astype(f32) for x in (u, dt, b, c))
+    dt, b, c = (x.astype(f32) for x in (dt, b, c))
     a, d_skip = a.astype(f32), d_skip.astype(f32)
     s = u.shape[1]
     chunk = max(min(int(chunk or SCAN_CHUNK), s), 1)
@@ -182,184 +183,334 @@ def selective_scan(u, dt, a, b, c, d_skip, *, chunk=None, impl="auto"):
     impl = resolve_impl(impl, u.shape[-1], a.shape[-1], chunk)
     with jax.named_scope("ssm_scan"):
         if impl == "pallas":
-            y = _scan_pallas(u, dt, b, c, a.T, chunk)
+            # the kernels read a bfloat16 u at its own width and widen it
+            wide = u if u.dtype == jnp.bfloat16 else u.astype(f32)
+            y = _scan_pallas(wide, dt, b, c, a.T, chunk)
         else:
-            y = _selective_scan_xla(u, dt, a, b, c, chunk)
-    return y[:, :s] + d_skip * u[:, :s]
+            y = _selective_scan_xla(u.astype(f32), dt, a, b, c, chunk)
+    return y[:, :s] + d_skip * u[:, :s].astype(f32)
 
 
 # ---- the kernel pair ---------------------------------------------------------
 #
-# Layout inside a kernel: channels on the 128 lanes, states on the
-# sublanes, so the state of a channel block is (n, block_d) and every
-# per-token row (u, dt, dy: (1, 128) a lane tile) broadcasts down the
-# sublanes. B and C come in spread over a lane tile, (s, n, 128), so that a
-# token's (n, 128) tile multiplies a lane tile of the state as it is: no
-# transpose and no lane broadcast inside the loop, at 8 KB a token of extra
-# reads. Tokens run in tiles of 8 (one float32 sublane tile): a tile of u,
-# dt is loaded, its 8 rows are walked in order, and the 8 result rows are
-# merged by sublane into one tile that is stored whole.
+# Layout inside a kernel: a token's channel block fills whole vector
+# registers and every state has registers of its own. With the channel axis
+# cut in lane tiles, a block of ``tiles`` lane tiles is one ``(tiles, 128)``
+# value a token for each of u, dt, dy, y (one float32 register at 1024
+# channels) and the state is ``d_state`` such values. So the token loop is
+# the recurrence and nothing else: dt, dt u and dy are registers as loaded,
+# the sums over states are adds of whole registers, a token's y, du, ddt are
+# stored whole. B and C enter at their own size, ``(chunk, d_state)``; the
+# cross-lane unit spreads a chunk of them over the lanes ONCE for all its
+# channel blocks (``_spread``: the grid walks the channel blocks innermost,
+# the state of every block in scratch) and a token's ``b[t, k]`` is then a
+# load that broadcasts one sublane.
+#
+# In HBM nothing moves for it. A ``(seq, d)`` array is held in tiles of 8
+# tokens by 128 channels (at 2 bytes too), so a token's lane tiles lie one
+# tile apart: ``_tiled`` relabels the array as (seq / 8, d / 128 * 8, 128),
+# rows (lane tile, token of the tile), which is the same bytes (XLA compiles
+# it to a bitcast), and a token's value is a load of every 8th row.
+#
+# The backward kernel's sums over CHANNELS (dB, dC: one number a token and
+# state, 32 whole registers a token to be summed) are what this layout has
+# to pay for. The token loop stores the products as they are; after ``TRIP``
+# tiles of 8 tokens, loads of every 4th row bring the tokens back onto the
+# sublanes, so that ONE cross-lane add sums a product of 8 tokens
+# (``_channel_sums``). What was measured on the v5e and shaped this
+# (PERF.md section 6): a cross-lane operation answers ~110 cycles late and
+# takes ~6 cycles of its unit, a rotate of the lanes is far dearer than the
+# one-operation add, strided loads and stores cost what plain ones do.
 
 LANES = 128
-GROUP = 8
-# channels a kernel instance holds: measured on the v5e at Jamba's widths
-# (tools/bench_selective_scan.py, PERF.md section 6: 256 / 512 / 1024 give
-# 9.59 / 8.15 / 7.19 ms forward + backward)
+GROUP = 8       # tokens a tile: the sublanes of a float32 tile in HBM
+REGISTER = GROUP * LANES  # channels that fill a float32 register a token
+# channels a kernel instance holds: 1024 fill a register a token; twice that
+# are two registers a token and twice the states in flight (Jamba's 5120 do
+# not divide by it). tools/bench_selective_scan.py, PERF.md section 6
 DEFAULT_BLOCK_D = 1024
 VMEM_LIMIT_BYTES = 64 * 2**20
+# tiles whose sums over channels the backward kernel takes together, their
+# chains side by side: 2 / 4 / 8 read 4.10 / 4.02 / 3.96 ms a call (section 6)
+TRIP = 8
+LOG2_E = 1.4426950408889634
+LN_2 = 0.6931471805599453
 
 
 def pallas_supported(d, n, chunk):
-    """Shapes the kernels tile: whole lane tiles of channels, whole sublane
-    tiles of states, chunks of whole lane tiles of tokens (the backward
-    kernel lays a chunk's dB, dC out by token on the lanes)."""
-    return d % LANES == 0 and n % GROUP == 0 and chunk % LANES == 0
+    """Shapes the kernels tile: channels that fill whole registers a token
+    (8 lane tiles), chunks of whole 8-token tiles, and a power of two of
+    states whose dB and dC fit the lanes of one register."""
+    return (d % REGISTER == 0 and chunk % GROUP == 0
+            and n & (n - 1) == 0 and 1 <= n <= LANES // 2)
+
+
+def _trip(chunk):
+    """Tiles of 8 tokens whose sums over channels the backward kernel takes
+    together."""
+    return next(t for t in (TRIP, 4, 2, 1) if chunk // GROUP % t == 0)
 
 
 def _block_d(d):
     bd = min(DEFAULT_BLOCK_D, d)
     while d % bd:
-        bd -= LANES
+        bd -= REGISTER
     return bd
 
 
-def _lane(j):
-    return slice(j * LANES, (j + 1) * LANES)
+def _tiled(x):
+    """(batch, s, d) -> (batch, s / 8, d / 128 * 8, 128), rows (lane tile,
+    token of the tile): the bytes XLA holds the array in, relabelled."""
+    bsz, s, d = x.shape
+    x = x.reshape(bsz, s // GROUP, GROUP, d // LANES, LANES)
+    return jnp.swapaxes(x, 2, 3).reshape(bsz, s // GROUP, d // LANES * GROUP, LANES)
 
 
-def _advance(h, j, dt_r, u_r, a_ref, bt):
-    """One token of one lane tile: the state after it."""
-    return (jnp.exp(dt_r * a_ref[:, _lane(j)]) * h
-            + (dt_r * u_r) * bt)
+def _untiled(x):
+    bsz, groups, rows, _ = x.shape
+    x = x.reshape(bsz, groups, rows // GROUP, GROUP, LANES)
+    return jnp.swapaxes(x, 2, 3).reshape(bsz, groups * GROUP, rows // GROUP * LANES)
 
 
-def _fwd_kernel(u_ref, dt_ref, bx_ref, cx_ref, a_ref, y_ref, hb_ref, h_scr,
-                *, chunk, tiles):
+def _token(ref, g, i, tiles):
+    """Token ``i`` of tile ``g``: its ``tiles`` lane tiles as one value."""
     from jax.experimental import pallas as pl
 
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        h_scr[...] = jnp.zeros_like(h_scr)
+    return ref[g, pl.ds(i, tiles, stride=GROUP), :]
 
-    hb_ref[...] = h_scr[...]  # the state this chunk starts from
-    sub = jax.lax.broadcasted_iota(jnp.int32, (GROUP, LANES), 0)
+
+def _token_bf16(ref, g, i, tiles):
+    """The same of a bfloat16 array, whose 32-bit rows hold tokens 2j and
+    2j + 1 of a tile in their low and high halves; widened."""
+    from jax.experimental import pallas as pl
+
+    rows = pl.ds(i // 2, tiles, stride=GROUP // 2)
+    w = ref.bitcast(jnp.uint32)[0, g, rows, :]
+    w = w << 16 if i % 2 == 0 else w & jnp.uint32(0xFFFF0000)
+    return jax.lax.bitcast_convert_type(w, jnp.float32)
+
+
+def _put_token(ref, g, i, tiles, value):
+    from jax.experimental import pallas as pl
+
+    ref[g, pl.ds(i, tiles, stride=GROUP), :] = value
+
+
+def _spread(ref, scr, chunk):
+    """A chunk of B or C, (chunk, n), over the lanes: row 8 k + i of
+    ``scr[g]`` holds ``x[8 g + i, k]`` in every lane. The cross-lane unit
+    does it, 8 tokens a broadcast, once a chunk for all its channel blocks."""
+    from jax.experimental import pallas as pl
+
+    def tile(g, carry):
+        rows = ref[pl.ds(pl.multiple_of(g * GROUP, GROUP), GROUP), :]
+        for k in range(ref.shape[1]):
+            scr[g, pl.ds(k * GROUP, GROUP), :] = jnp.broadcast_to(
+                rows[:, k:k + 1], (GROUP, LANES))
+        return carry
+
+    jax.lax.fori_loop(0, chunk // GROUP, tile, 0)
+
+
+def _splat(scr, k, g, i, tiles):
+    """``x[8 g + i, k]`` of the chunk spread in ``scr`` in every place of a
+    token's value (a load that broadcasts one sublane, at a fixed distance
+    from the tile's first row)."""
+    from jax.experimental import pallas as pl
+
+    return jnp.broadcast_to(scr[g, pl.ds(k * GROUP + i, 1), :], (tiles, LANES))
+
+
+def _advance(h, dt, dtu, a2, bs_scr, k, g, i, tiles):
+    """State ``k`` after token ``i`` of tile ``g``: exp(dt a) as 2^(dt a2),
+    ``a2`` = a log2 e (the product with log2 e once a chunk, not a token)."""
+    return jnp.exp2(dt * a2) * h + dtu * _splat(bs_scr, k, g, i, tiles)
+
+
+def _fwd_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, hb_ref,
+                h_scr, bs_scr, cs_scr, *, chunk, tiles, token):
+    from jax.experimental import pallas as pl
+
+    n = a_ref.shape[0]
+    di = pl.program_id(2)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        h_scr[di] = jnp.zeros(h_scr.shape[1:], jnp.float32)
+
+    @pl.when(di == 0)
+    def _():
+        _spread(b_ref, bs_scr, chunk)
+        _spread(c_ref, cs_scr, chunk)
+
+    hb_ref[...] = h_scr[di]  # the state this chunk starts from
+    a2 = [a_ref[k] * LOG2_E for k in range(n)]
 
     def group(g, h):
-        t0 = pl.multiple_of(g * GROUP, GROUP)
-        u8 = u_ref[pl.ds(t0, GROUP), :]
-        dt8 = dt_ref[pl.ds(t0, GROUP), :]
         h = list(h)
-        y8 = [jnp.zeros((GROUP, LANES), jnp.float32)] * tiles
         for i in range(GROUP):
-            bt, ct = bx_ref[t0 + i], cx_ref[t0 + i]
-            for j in range(tiles):
-                dt_r, u_r = dt8[i:i + 1, _lane(j)], u8[i:i + 1, _lane(j)]
-                h[j] = _advance(h[j], j, dt_r, u_r, a_ref, bt)
-                y_r = jnp.sum(ct * h[j], axis=0, keepdims=True)
-                y8[j] = jnp.where(sub == i, y_r, y8[j])
-        for j in range(tiles):
-            y_ref[pl.ds(t0, GROUP), _lane(j)] = y8[j]
+            u, dt = token(u_ref, g, i, tiles), _token(dt_ref, g, i, tiles)
+            dtu = dt * u
+            y = None
+            for k in range(n):
+                h[k] = _advance(h[k], dt, dtu, a2[k], bs_scr, k, g, i, tiles)
+                y_k = h[k] * _splat(cs_scr, k, g, i, tiles)
+                y = y_k if y is None else y + y_k
+            _put_token(y_ref, g, i, tiles, y)
         return tuple(h)
 
     h = jax.lax.fori_loop(
-        0, chunk // GROUP, group,
-        tuple(h_scr[:, _lane(j)] for j in range(tiles)))
-    for j in range(tiles):
-        h_scr[:, _lane(j)] = h[j]
+        0, chunk // GROUP, group, tuple(h_scr[di, k] for k in range(n)))
+    for k in range(n):
+        h_scr[di, k] = h[k]
 
 
-def _bwd_kernel(u_ref, dt_ref, bx_ref, cx_ref, a_ref, hb_ref, dy_ref,
-                du_ref, ddt_ref, dbt_ref, dct_ref, da_ref, hs_scr, dh_scr,
-                *, chunk, tiles):
+def _put_product(p_scr, j, m, i, value):
+    """Token ``i``'s product ``m`` in tile ``j`` of the trip (a token's
+    value, to be summed over its channels), its registers added into one."""
+    from jax.experimental import pallas as pl
+
+    regs = value.reshape(-1, GROUP, LANES)
+    p_scr[j, m, pl.ds(i * GROUP, GROUP), :] = _tree_sum(
+        [regs[k] for k in range(regs.shape[0])])
+
+
+def _channel_sums(p_scr, j, dbc_ref, g):
+    """The sums over channels of the products in tile ``j`` of the trip,
+    into tile ``g``'s rows of ``dbc_ref``. A load of every 4th row brings 4
+    tokens' lane tiles s and s + 4 onto sublanes 2t and 2t + 1, four such
+    loads added hold their two half sums each; a sublane's neighbour added,
+    the even sublanes hold tokens 0-3 and the odd ones, from the other half,
+    tokens 4-7: one register a product, whose lanes the cross-lane unit adds
+    in ONE operation (it takes ~6 cycles of the unit, so a register is
+    filled first), the answer in every lane. A tree of selects puts product
+    m's in lane m. Rows (t, 4 + t) for t = 0..3: XLA reads them back. All
+    products at once, as (products, 8, 128) arrays: the trace stays short."""
+    from jax.experimental import pallas as pl
+
+    count = p_scr.shape[1]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (count, GROUP, LANES), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (GROUP, LANES), 1)
+
+    def tokens(half):  # 4 tokens: (token, half sum) on the sublanes
+        x = _tree_sum([
+            p_scr[j, :, pl.ds(half * 4 * GROUP + s, GROUP, stride=4), :]
+            for s in range(4)])
+        # a token's whole sum: tokens 0-3 on the even sublanes (the half sum
+        # below comes up), tokens 4-7 on the odd ones (the one above down)
+        return x + _roll(x, 1 if half else -1, 1)
+
+    both = jnp.where((sub & 1) == 0, tokens(0), tokens(1))
+    sums = jnp.sum(both, axis=2, keepdims=True)
+    totals = [sums[m] for m in range(count)]
+    bit = 1
+    while len(totals) > 1:  # bit b of the lane picks at level b
+        totals = [jnp.where((lane & bit) == 0, a, b)
+                  for a, b in zip(totals[::2], totals[1::2])]
+        bit *= 2
+    at = pl.multiple_of(g * GROUP, GROUP)
+    dbc_ref[pl.ds(at, GROUP), :] = jnp.broadcast_to(totals[0], (GROUP, LANES))
+
+
+def _roll(x, shift, axis):
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.roll(x, shift % x.shape[axis], axis)
+
+
+def _tree_sum(terms):
+    terms = list(terms)
+    while len(terms) > 1:
+        terms = [a + b for a, b in zip(terms[::2], terms[1::2])] + (
+            [terms[-1]] if len(terms) % 2 else [])
+    return terms[0]
+
+
+def _bwd_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, hb_ref, dy_ref,
+                du_ref, ddt_ref, dbc_ref, da_ref,
+                hs_scr, dh_scr, bs_scr, cs_scr, p_scr, *, chunk, tiles, token):
     """Chunks arrive last to first. ``hs_scr[t + 1]`` is the state after
     token t of the chunk (``hs_scr[0]`` the boundary), recomputed here;
     ``dh_scr`` carries the state's adjoint into the chunk before."""
     from jax.experimental import pallas as pl
 
-    f32 = jnp.float32
     n = a_ref.shape[0]
+    groups = chunk // GROUP
+    di = pl.program_id(2)
 
-    @pl.when(pl.program_id(2) == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _():
-        dh_scr[...] = jnp.zeros_like(dh_scr)
+        dh_scr[di] = jnp.zeros(dh_scr.shape[1:], jnp.float32)
+
+    @pl.when((pl.program_id(1) == 0) & (di == 0))
+    def _():
         da_ref[...] = jnp.zeros_like(da_ref)
 
+    @pl.when(di == 0)
+    def _():
+        _spread(b_ref, bs_scr, chunk)
+        _spread(c_ref, cs_scr, chunk)
+
+    a2 = [a_ref[k] * LOG2_E for k in range(n)]
     hs_scr[0] = hb_ref[...]
 
     def recompute(g, h):
-        t0 = pl.multiple_of(g * GROUP, GROUP)
-        u8 = u_ref[pl.ds(t0, GROUP), :]
-        dt8 = dt_ref[pl.ds(t0, GROUP), :]
         h = list(h)
         for i in range(GROUP):
-            bt = bx_ref[t0 + i]
-            for j in range(tiles):
-                dt_r, u_r = dt8[i:i + 1, _lane(j)], u8[i:i + 1, _lane(j)]
-                h[j] = _advance(h[j], j, dt_r, u_r, a_ref, bt)
-                hs_scr[t0 + i + 1, :, _lane(j)] = h[j]
+            t = g * GROUP + i
+            u, dt = token(u_ref, g, i, tiles), _token(dt_ref, g, i, tiles)
+            dtu = dt * u
+            for k in range(n):
+                h[k] = _advance(h[k], dt, dtu, a2[k], bs_scr, k, g, i, tiles)
+                hs_scr[t + 1, k] = h[k]
         return tuple(h)
 
-    jax.lax.fori_loop(
-        0, chunk // GROUP, recompute,
-        tuple(hb_ref[:, _lane(j)] for j in range(tiles)))
+    jax.lax.fori_loop(0, groups, recompute, tuple(hb_ref[k] for k in range(n)))
 
-    sub = jax.lax.broadcasted_iota(jnp.int32, (GROUP, LANES), 0)
-    lane_id = jax.lax.broadcasted_iota(jnp.int32, (n, LANES), 1)
-    groups = LANES // GROUP
-    dh = tuple(dh_scr[:, _lane(j)] for j in range(tiles))
-    for q in reversed(range(chunk // LANES)):
+    trip = p_scr.shape[0]
 
-        def group(k, carry, q=q):
-            dh, dbt, dct = carry
-            g = groups - 1 - k
-            t0 = pl.multiple_of(q * LANES + g * GROUP, GROUP)
-            u8 = u_ref[pl.ds(t0, GROUP), :]
-            dt8 = dt_ref[pl.ds(t0, GROUP), :]
-            dy8 = dy_ref[pl.ds(t0, GROUP), :]
-            dh = list(dh)
-            du8 = [jnp.zeros((GROUP, LANES), f32)] * tiles
-            ddt8 = [jnp.zeros((GROUP, LANES), f32)] * tiles
-            for i in reversed(range(GROUP)):
-                t = t0 + i
-                bt, ct = bx_ref[t], cx_ref[t]
-                db_acc = jnp.zeros((n, LANES), f32)
-                dc_acc = jnp.zeros((n, LANES), f32)
-                for j in range(tiles):
-                    sl = _lane(j)
-                    dt_r, u_r = dt8[i:i + 1, sl], u8[i:i + 1, sl]
-                    dy_r = dy8[i:i + 1, sl]
-                    a_j = a_ref[:, sl]
-                    # adjoint of the state after token t, all its uses in
-                    dh_j = dh[j] + ct * dy_r
-                    dc_acc = dc_acc + hs_scr[t + 1, :, sl] * dy_r
-                    decay = jnp.exp(dt_r * a_j)
-                    through = dh_j * hs_scr[t, :, sl] * decay
-                    da_ref[:, sl] += through * dt_r
-                    fed = jnp.sum(dh_j * bt, axis=0, keepdims=True)
-                    ddt_r = jnp.sum(
-                        through * a_j, axis=0, keepdims=True) + fed * u_r
-                    db_acc = db_acc + dh_j * (dt_r * u_r)
-                    dh[j] = decay * dh_j
-                    du8[j] = jnp.where(sub == i, fed * dt_r, du8[j])
-                    ddt8[j] = jnp.where(sub == i, ddt_r, ddt8[j])
-                col = g * GROUP + i  # the token's lane in its 128-token tile
-                dbt = jnp.where(
-                    lane_id == col,
-                    jnp.sum(db_acc, axis=1, keepdims=True), dbt)
-                dct = jnp.where(
-                    lane_id == col,
-                    jnp.sum(dc_acc, axis=1, keepdims=True), dct)
-            for j in range(tiles):
-                du_ref[pl.ds(t0, GROUP), _lane(j)] = du8[j]
-                ddt_ref[pl.ds(t0, GROUP), _lane(j)] = ddt8[j]
-            return tuple(dh), dbt, dct
+    def group(j, carry, q):
+        g = groups - 1 - (q * trip + j)
+        dh, da = (list(x) for x in carry)
+        for i in reversed(range(GROUP)):
+            t = g * GROUP + i
+            u, dt = token(u_ref, g, i, tiles), _token(dt_ref, g, i, tiles)
+            dy = _token(dy_ref, g, i, tiles)
+            dtu = dt * u
+            fed, through_a = [], []
+            for k in range(n):
+                # adjoint of the state after token t, all its uses in
+                dh_k = dh[k] + _splat(cs_scr, k, g, i, tiles) * dy
+                _put_product(p_scr, j, k, i, dh_k * dtu)                # dB
+                _put_product(p_scr, j, n + k, i, hs_scr[t + 1, k] * dy)  # dC
+                dh[k] = dh_k * jnp.exp2(dt * a2[k])
+                through = dh[k] * hs_scr[t, k]
+                da[k] = da[k] + through * dt
+                fed.append(dh_k * _splat(bs_scr, k, g, i, tiles))
+                through_a.append(through * a2[k])
+            fed = _tree_sum(fed)
+            _put_token(du_ref, g, i, tiles, fed * dt)
+            _put_token(ddt_ref, g, i, tiles,
+                       _tree_sum(through_a) * LN_2 + fed * u)
+        return tuple(dh), tuple(da)
 
-        zero = jnp.zeros((n, LANES), f32)
-        dh, dbt, dct = jax.lax.fori_loop(0, groups, group, (dh, zero, zero))
-        dbt_ref[q] = dbt
-        dct_ref[q] = dct
-    for j in range(tiles):
-        dh_scr[:, _lane(j)] = dh[j]
+    def tiles_of_a_trip(q, carry):
+        # the cross-lane unit answers ~110 cycles late: the sums over
+        # channels of `trip` tiles are taken together, their chains side by
+        # side, where one tile's alone would leave the loop waiting
+        carry = jax.lax.fori_loop(
+            0, trip, functools.partial(group, q=q), carry)
+        for j in range(trip):
+            _channel_sums(p_scr, j, dbc_ref, groups - 1 - (q * trip + j))
+        return carry
+
+    zero = jnp.zeros((tiles, LANES), jnp.float32)
+    dh, da = jax.lax.fori_loop(
+        0, groups // trip, tiles_of_a_trip,
+        (tuple(dh_scr[di, k] for k in range(n)), (zero,) * n))
+    for k in range(n):
+        dh_scr[di, k] = dh[k]
+        da_ref[k, pl.ds(pl.multiple_of(di * tiles, tiles), tiles), :] += da[k]
 
 
 def _interpret():
@@ -372,102 +523,118 @@ def _compiler_params():
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=VMEM_LIMIT_BYTES,
-    )
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
-def _spread(x):
-    """(batch, s, n) -> (batch, s, n, 128): a token's B or C as a lane tile."""
-    return jnp.broadcast_to(x[..., None], (*x.shape, LANES))
+def _operands(u, dt, b, c, a_t, chunk, order):
+    """What both calls share: the operands every call starts with (u at its
+    own width, dt, B, C, A by lane tile), their blocks, the block of one
+    more token array and of a chunk's state, a token array's shape, and
+    the reader of a token of u. ``order`` maps the grid's chunk index to the chunk (the backward call
+    walks them last to first). Grid (batch, chunk, channel block)."""
+    from jax.experimental import pallas as pl
+
+    n, d = a_t.shape
+    tiles = _block_d(d) // LANES
+    narrow = u.dtype == jnp.bfloat16
+
+    def rows(lead=None):
+        return pl.BlockSpec(
+            (lead, chunk // GROUP, tiles * GROUP, LANES),
+            lambda bi, ci, di: (bi, order(ci), di, 0))
+
+    bc = pl.BlockSpec((None, chunk, n), lambda bi, ci, di: (bi, order(ci), 0))
+    state = pl.BlockSpec((None, None, n, tiles, LANES),
+                         lambda bi, ci, di: (bi, order(ci), 0, di, 0))
+    specs = [
+        # a bfloat16 block keeps its batch axis: a ref is bitcast with the
+        # rank it has
+        rows(1) if narrow else rows(), rows(), bc, bc,
+        pl.BlockSpec((n, tiles, LANES), lambda bi, ci, di: (0, di, 0)),
+    ]
+    arrays = (_tiled(u), _tiled(dt), b, c, a_t.reshape(n, d // LANES, LANES))
+    tokens = jax.ShapeDtypeStruct(arrays[1].shape, jnp.float32)
+    return (arrays, specs, rows(), state, tokens,
+            _token_bf16 if narrow else _token)
 
 
 def _pallas_fwd_call(u, dt, b, c, a_t, chunk):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bsz, s, d = u.shape
+    bsz, s, d = dt.shape
     n = a_t.shape[0]
-    bd = _block_d(d)
-    nc, nd = s // chunk, d // bd
-    rows = pl.BlockSpec((None, chunk, bd), lambda bi, di, ci: (bi, ci, di))
-    tiles_bc = pl.BlockSpec(
-        (None, chunk, n, LANES), lambda bi, di, ci: (bi, ci, 0, 0))
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, chunk=chunk, tiles=bd // LANES),
-        grid=(bsz, nd, nc),
-        in_specs=[
-            rows, rows, tiles_bc, tiles_bc,
-            pl.BlockSpec((n, bd), lambda bi, di, ci: (0, di)),
-        ],
-        out_specs=[
-            rows,
-            pl.BlockSpec((None, None, n, bd),
-                         lambda bi, di, ci: (bi, ci, 0, di)),
-        ],
+    tiles = _block_d(d) // LANES
+    nc, nd = s // chunk, d // (tiles * LANES)
+    f32 = jnp.float32
+    arrays, specs, rows, state, tokens, token = _operands(
+        u, dt, b, c, a_t, chunk, lambda ci: ci)
+    y, bounds = pl.pallas_call(
+        functools.partial(_fwd_kernel, chunk=chunk, tiles=tiles, token=token),
+        grid=(bsz, nc, nd),
+        in_specs=specs,
+        out_specs=[rows, state],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, s, d), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, nc, n, d), jnp.float32),
+            tokens,
+            jax.ShapeDtypeStruct((bsz, nc, n, d // LANES, LANES), f32),
         ],
-        scratch_shapes=[pltpu.VMEM((n, bd), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((nd, n, tiles, LANES), f32),
+            pltpu.VMEM((chunk // GROUP, n * GROUP, LANES), f32),
+            pltpu.VMEM((chunk // GROUP, n * GROUP, LANES), f32),
+        ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
         name="ssm_scan_fwd",
-    )(u, dt, _spread(b), _spread(c), a_t)
+    )(*arrays)
+    return _untiled(y), bounds
 
 
 def _pallas_bwd_call(u, dt, b, c, a_t, bounds, dy, chunk):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bsz, s, d = u.shape
+    bsz, s, d = dt.shape
     n = a_t.shape[0]
-    bd = _block_d(d)
-    nc, nd = s // chunk, d // bd
-    last = nc - 1
-    rows = pl.BlockSpec(
-        (None, chunk, bd), lambda bi, di, ci: (bi, last - ci, di))
-    tiles_bc = pl.BlockSpec(
-        (None, chunk, n, LANES), lambda bi, di, ci: (bi, last - ci, 0, 0))
-    by_token = pl.BlockSpec(
-        (None, None, chunk // LANES, n, LANES),
-        lambda bi, di, ci: (bi, di, last - ci, 0, 0))
+    tiles = _block_d(d) // LANES
+    last, nd = s // chunk - 1, d // (tiles * LANES)
     f32 = jnp.float32
-    du, ddt, dbt, dct, da = pl.pallas_call(
-        functools.partial(_bwd_kernel, chunk=chunk, tiles=bd // LANES),
-        grid=(bsz, nd, nc),
-        in_specs=[
-            rows, rows, tiles_bc, tiles_bc,
-            pl.BlockSpec((n, bd), lambda bi, di, ci: (0, di)),
-            pl.BlockSpec((None, None, n, bd),
-                         lambda bi, di, ci: (bi, last - ci, 0, di)),
-            rows,
-        ],
+    arrays, specs, rows, state, tokens, token = _operands(
+        u, dt, b, c, a_t, chunk, lambda ci: last - ci)
+    du, ddt, dbc, da = pl.pallas_call(
+        functools.partial(_bwd_kernel, chunk=chunk, tiles=tiles, token=token),
+        grid=(bsz, last + 1, nd),
+        in_specs=[*specs, state, rows],
         out_specs=[
-            rows, rows, by_token, by_token,
-            pl.BlockSpec((None, n, bd), lambda bi, di, ci: (bi, 0, di)),
+            rows, rows,
+            pl.BlockSpec((None, None, chunk, LANES),
+                         lambda bi, ci, di: (bi, di, last - ci, 0)),
+            pl.BlockSpec((None, n, d // LANES, LANES),
+                         lambda bi, ci, di: (bi, 0, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, s, d), f32),
-            jax.ShapeDtypeStruct((bsz, s, d), f32),
-            jax.ShapeDtypeStruct((bsz, nd, s // LANES, n, LANES), f32),
-            jax.ShapeDtypeStruct((bsz, nd, s // LANES, n, LANES), f32),
-            jax.ShapeDtypeStruct((bsz, n, d), f32),
+            tokens, tokens,
+            jax.ShapeDtypeStruct((bsz, nd, s, LANES), f32),
+            jax.ShapeDtypeStruct((bsz, n, d // LANES, LANES), f32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((chunk + 1, n, bd), f32),
-            pltpu.VMEM((n, bd), f32),
+            pltpu.VMEM((chunk + 1, n, tiles, LANES), f32),
+            pltpu.VMEM((nd, n, tiles, LANES), f32),
+            pltpu.VMEM((chunk // GROUP, n * GROUP, LANES), f32),
+            pltpu.VMEM((chunk // GROUP, n * GROUP, LANES), f32),
+            pltpu.VMEM((_trip(chunk), 2 * n, GROUP * GROUP, LANES), f32),
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
         name="ssm_scan_bwd",
-    )(u, dt, _spread(b), _spread(c), a_t, bounds, dy)
-
-    def by_row(x):  # (batch, nd, s/128, n, 128) -> (batch, s, n)
-        x = jnp.sum(x, axis=1)
-        return jnp.swapaxes(x, 2, 3).reshape(bsz, s, n)
-
-    return du, ddt, by_row(dbt), by_row(dct), jnp.sum(da, axis=0)
+    )(*arrays, bounds, _tiled(dy))
+    # a token's 2n sums over a block's channels, product m in lane m, a
+    # tile's rows in the order (t, 4 + t) `_channel_sums` leaves them in
+    dbc = jnp.sum(dbc, axis=1).reshape(bsz, s // GROUP, GROUP // 2, 2, LANES)
+    dbc = jnp.swapaxes(dbc, 2, 3).reshape(bsz, s, LANES)
+    return (_untiled(du).astype(u.dtype), _untiled(ddt), dbc[..., :n],
+            dbc[..., n:2 * n], jnp.sum(da, axis=0).reshape(n, d))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))

@@ -97,10 +97,10 @@ def recurrence_token_by_token(u, dt, a, b, c, d_skip):
     return jnp.moveaxis(y, 0, 1)
 
 
-def scan_operands(seed, b=2, s=37, d=24, n=4):
+def scan_operands(seed, b=2, s=37, d=24, n=4, u_dtype=jnp.float32):
     k = jax.random.split(jax.random.key(seed), 7)
     return (
-        jax.random.normal(k[0], (b, s, d)),
+        jax.random.normal(k[0], (b, s, d)).astype(u_dtype),
         jax.nn.softplus(jax.random.normal(k[1], (b, s, d)) - 1.0),
         -jnp.exp(jax.random.normal(k[2], (d, n))),
         jax.random.normal(k[3], (b, s, n)), jax.random.normal(k[4], (b, s, n)),
@@ -127,27 +127,49 @@ def test_chunked_scan_matches_the_token_by_token_recurrence(chunk):
         assert float(jnp.max(jnp.abs(got - want))) <= 5e-6 * scale, name
 
 
-@pytest.mark.parametrize("s,chunk", [(256, 128), (300, 256)],
-                         ids=["divides", "padded"])
-def test_kernel_pair_matches_the_xla_formulation(monkeypatch, s, chunk):
-    """``ssm_scan_fwd`` / ``ssm_scan_bwd`` in the Pallas interpreter, two
-    channel blocks and more than one chunk, against the XLA formulation
-    under the same custom rule."""
+@pytest.mark.parametrize("s,chunk,d,n,block,u_dtype", [
+    (128, 64, 2048, 8, 1024, jnp.float32),
+    (150, 128, 1024, 8, 1024, jnp.float32),
+    (64, 32, 1024, 16, 1024, jnp.float32),
+    (64, 32, 2048, 8, 2048, jnp.float32),
+    (64, 32, 1024, 8, 1024, jnp.bfloat16),
+    (100, 48, 1024, 8, 1024, jnp.bfloat16),
+], ids=["divides", "padded", "jamba-states", "two-registers-a-token",
+        "bf16-u", "bf16-u-padded"])
+def test_kernel_pair_matches_the_xla_formulation(
+        monkeypatch, s, chunk, d, n, block, u_dtype):
+    """``ssm_scan_fwd`` / ``ssm_scan_bwd`` in the Pallas interpreter against
+    the XLA formulation under the same custom rule, more than one chunk in
+    every case: two channel blocks of a register a token (8 lane tiles), a
+    sequence the chunk does not divide, Jamba's own 16 states, a block of
+    two registers a token, u as the model holds it (bfloat16, widened in
+    the kernels), and 100 tokens in chunks of 48 in that layout. The
+    backward kernel sums over channels 8, 4 and 2 tiles at a time in them."""
     import pyrecover_tpu.ops.selective_scan as ss
 
     monkeypatch.setenv("PYRECOVER_PALLAS_INTERPRET", "1")
-    monkeypatch.setattr(ss, "DEFAULT_BLOCK_D", 128)
-    ops, w = scan_operands(1, b=2, s=s, d=256, n=8)
-    y, g = jax.value_and_grad(
-        lambda *xs: jnp.sum(selective_scan(*xs, chunk=chunk, impl="pallas") * w),
-        argnums=range(6))(*ops)
-    y0, g0 = jax.value_and_grad(
-        lambda *xs: jnp.sum(selective_scan(*xs, chunk=32, impl="xla") * w),
-        argnums=range(6))(*ops)
-    assert abs(y - y0) <= 2e-6 * abs(y0)
+    monkeypatch.setattr(ss, "DEFAULT_BLOCK_D", block)
+    ops, w = scan_operands(1, b=2, s=s, d=d, n=n, u_dtype=u_dtype)
+
+    def weighed(impl, chunk):
+        def f(*xs):
+            y = selective_scan(*xs, chunk=chunk, impl=impl)
+            return jnp.sum(y * w), y
+        return jax.value_and_grad(f, argnums=range(6), has_aux=True)(*ops)
+
+    (_, y), g = weighed("pallas", chunk)
+    (_, y0), g0 = weighed("xla", 32)
+    # every y, not their weighed sum: a million terms cancel to a sum whose
+    # own rounding is over the tolerance at these widths
+    assert float(jnp.max(jnp.abs(y - y0))) <= 2e-6 * float(jnp.max(jnp.abs(y0)))
     for got, want, name in zip(g, g0, ("u", "dt", "a", "b", "c", "d")):
+        assert got.dtype == want.dtype, name
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
         scale = float(jnp.max(jnp.abs(want)))
-        assert float(jnp.max(jnp.abs(got - want))) <= 5e-6 * scale, name
+        # a bfloat16 u's cotangent is rounded to bfloat16 on both sides: two
+        # float32 values 1e-7 apart may round to neighbours (2^-8 apart)
+        tol = 2.0**-8 if (name == "u" and u_dtype == jnp.bfloat16) else 5e-6
+        assert float(jnp.max(jnp.abs(got - want))) <= tol * scale, name
 
 
 def test_kernels_leave_shapes_they_do_not_tile_to_xla(monkeypatch):
@@ -155,8 +177,11 @@ def test_kernels_leave_shapes_they_do_not_tile_to_xla(monkeypatch):
 
     monkeypatch.delenv("PYRECOVER_PALLAS_INTERPRET", raising=False)
     assert pallas_supported(5120, 16, 256)
+    assert pallas_supported(5120, 16, 48)       # whole 8-token tiles
     assert not pallas_supported(24, 4, 16)      # the tests' toy width
-    assert not pallas_supported(5120, 16, 64)   # a chunk under a lane tile
+    assert not pallas_supported(5120 + 512, 16, 256)  # half a register a token
+    assert not pallas_supported(5120, 16, 36)   # half a tile of tokens
+    assert not pallas_supported(5120, 12, 256)  # states no power of two
     assert resolve_impl("auto", 5120, 16, 256) == "xla"   # the CPU
     monkeypatch.setenv("PYRECOVER_PALLAS_INTERPRET", "1")
     assert resolve_impl("auto", 5120, 16, 256) == "pallas"
@@ -166,7 +191,7 @@ def test_kernels_leave_shapes_they_do_not_tile_to_xla(monkeypatch):
     # kernels asked for by name on shapes they do not tile: an error, not
     # another formulation in silence
     ops, _ = scan_operands(0)
-    with pytest.raises(ValueError, match="whole lane tiles of channels"):
+    with pytest.raises(ValueError, match="whole registers of channels"):
         selective_scan(*ops, chunk=16, impl="pallas")
 
 

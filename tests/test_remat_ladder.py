@@ -44,10 +44,12 @@ COMPILER_PEAKS = {
     # resolves it; the XLA formulation reads 15629611008 under full).
     # One attention layer of fourteen: what it keeps does not move the peak,
     # which a Mamba layer's backward sets
+    # PR 34: the kernels read B and C at their own size and u at 2 bytes
+    # (no lane-spread copies, no float32 u among a layer's temporaries):
+    # 15420156416 / 15425399296 before
     "jamba2-3b.steady": {
-        "flash+qkv": 15420156416,  # the shipped program (channel block 1024)
-        # read at a channel block of 512, where flash+qkv read the same
-        "qkv": 15425399296, "save-attn": 15425399296, "full": 15425399296,
+        "flash+qkv": 15278599168,  # the shipped program
+        "qkv": 15278599168, "save-attn": 15278599168, "full": 15278599168,
     },
     # chip_smoke.py's shape: llama-1b widths, 20 layers, 8 x 2048
     "llama-1b": {"qkv": 15823074304, "save-attn": 14502003712,
