@@ -13,12 +13,29 @@ dq / dk / dv in separate kernels (dk/dv with a kv-major grid so each block
 is written once). All softmax math in fp32; matmuls hit the MXU with
 ``preferred_element_type=float32``.
 
-Layout: grid (batch, q_heads, q_blocks, kv_blocks), kv innermost so VMEM
-scratch (running max / denominator / accumulator) persists across the kv
-sweep of one q block — TPU grids execute sequentially, which is what makes
-this accumulator pattern legal. GQA is expressed in the BlockSpec index
-maps (kv head = q head // group) so repeated KV heads are never
-materialized (unlike the reference's repeat_kv, model.py:130-139).
+Layout: grid (batch, q_heads, pairs). The two block axes are folded into
+ONE axis over the (q block, kv block) pairs a call needs (``flash_plan``),
+q block outermost and kv innermost, so VMEM scratch (running max /
+denominator / accumulator) persists across the kv sweep of one q block —
+TPU grids execute sequentially, which is what makes this accumulator
+pattern legal. Under ``causal`` a pair wholly above the diagonal is not in
+the plan: it costs no grid step and no block copy (at sequence 4096 under
+1024 x 1024 blocks six pairs of sixteen; in ``flash_dkv`` such a step used
+to fetch q, do, o and the lse block, which the chip pads from 8 lanes to
+128: 512 KB for 32 KB of numbers). The pair -> (q block, kv block, flags)
+tables reach the index maps and the body as prefetched scalars
+(``pltpu.PrefetchScalarGridSpec``; ``_step_tables``); the flags say where a
+row's run begins and ends (init / finalize) and which of two bodies the
+step runs: an *interior* step, every position of whose tile passes every
+positional condition, builds no iota, no compare and (without segment ids)
+no ``where``; an *edge* step (the diagonal, a ragged tail) runs the masked
+body. The two differ in nothing else, and a ``where`` whose mask is all
+true returns its operand: the outputs are the masked-everywhere kernels' to
+the last bit. ``flash_dkv`` walks the same pairs kv block outermost, with
+the q heads of the kv head's group as the innermost grid axis. GQA is
+expressed in the BlockSpec index maps (kv head = q head // group) so
+repeated KV heads are never materialized (unlike the reference's
+repeat_kv, model.py:130-139).
 
 The kernel is TOTAL over shapes: non-divisible sequence lengths get masked
 tail blocks (the ragged edge is iota-masked exactly like the causal
@@ -36,7 +53,9 @@ slowdown under the kernel's name.
 
 import functools
 import math
+import operator
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +92,138 @@ LSE_LANES = 8
 # forward kernel a second time)
 
 
+class FlashPlan(NamedTuple):
+    """What one (batch row, head) of a call walks: ``flash_plan``."""
+
+    steps_visited: int   # grid steps taken: interior + edge
+    steps_interior: int  # every position passes every positional condition
+    steps_edge: int      # the diagonal or a ragged kv tail crosses the tile
+    steps_above: int     # wholly above the diagonal: no step, no copy
+    pairs: tuple         # ((q block, kv block, interior), ...) in q-major order
+
+    def counts(self):
+        """The four numbers, as the ``flash_plan`` event carries them."""
+        return {n: getattr(self, n) for n in self._fields if n != "pairs"}
+
+
+@functools.lru_cache(maxsize=64)
+def flash_plan(seq_q, seq_kv, block_q, block_kv, causal):
+    """The (q block, kv block) pairs a call needs, q block outermost and kv
+    ascending inside it (the order of the forward's accumulation). Under
+    ``causal`` a pair whose kv block begins after the q block's last row is
+    *above* and is not in the plan at all. A pair is *interior* when the kv
+    block ends at or before the q block's first row (or the call is not
+    causal) and the kv block holds no ragged tail: no position of the tile
+    needs a positional mask. Every other pair is an *edge*. Pure and static:
+    the kernels' grids and step tables (``_step_tables``) are built from it,
+    and the ``flash_plan`` telemetry event carries its four counts."""
+    bq, bk = min(block_q, seq_q), min(block_kv, seq_kv)
+    nq, nk = -(-seq_q // bq), -(-seq_kv // bk)
+    pairs = []
+    for iq in range(nq):
+        for ik in range(nk):
+            if causal and ik * bk > iq * bq + bq - 1:
+                break  # this kv block and every later one lie above
+            interior = (ik + 1) * bk <= seq_kv and (
+                not causal or (ik + 1) * bk - 1 <= iq * bq
+            )
+            pairs.append((iq, ik, interior))
+    interior = sum(p[2] for p in pairs)
+    return FlashPlan(
+        steps_visited=len(pairs), steps_interior=interior,
+        steps_edge=len(pairs) - interior, steps_above=nq * nk - len(pairs),
+        pairs=tuple(pairs),
+    )
+
+
+# what a grid step is, as its entry of a kernel's flag table says it
+FIRST, LAST, INTERIOR, EDGE = 1, 2, 4, 8
+
+
+def _step_tables(plan, seq_q, block_q, num_kv_blocks, *, kv_major):
+    """``(q block, kv block, flags)`` int32 tables of a kernel's folded
+    pair axis, one entry a grid step, handed to the index maps and the body
+    as prefetched scalars; and the OR of all the flags (which bodies a
+    kernel has to hold). ``flash_fwd`` / ``flash_dq`` walk the plan as it
+    stands; ``flash_dkv`` (``kv_major``) walks it kv block outermost, q
+    blocks ascending inside, and accumulates over q ROWS, so there a tile on
+    a ragged q tail is an edge too. FIRST / LAST mark the ends of a row's
+    run (init / finalize). A kv block no q block needs (causal, more keys
+    than queries) still has its zeros written: one step that is both ends
+    and runs neither body."""
+    steps = [(iq, ik, INTERIOR if interior else EDGE)
+             for iq, ik, interior in plan.pairs]
+    if kv_major:
+        q_tail = (seq_q - 1) // block_q if seq_q % block_q else -1
+        rows = {ik: [] for ik in range(num_kv_blocks)}
+        for iq, ik, kind in steps:
+            rows[ik].append((iq, ik, EDGE if iq == q_tail else kind))
+        steps = [s for ik, row in rows.items() for s in (row or [(0, ik, 0)])]
+    row = 1 if kv_major else 0  # the block a run of steps accumulates for
+    flags = [
+        kind
+        | (FIRST if i == 0 or steps[i - 1][row] != step[row] else 0)
+        | (LAST if i + 1 == len(steps) or steps[i + 1][row] != step[row] else 0)
+        for i, (*step, kind) in enumerate(steps)
+    ]
+    tables = tuple(
+        np.asarray(col, np.int32)
+        for col in ([s[0] for s in steps], [s[1] for s in steps], flags)
+    )
+    return tables, functools.reduce(operator.or_, flags)
+
+
+def _index_maps(q_head, kv_head):
+    """Index maps of a kernel's q-side blocks, kv-side blocks and their
+    segment ids, over a grid (batch, head, step, *inner axes) followed by
+    the three step tables. ``q_head`` / ``kv_head`` take the grid's head and
+    inner axes to the operand's head (GQA lives here)."""
+    def q_map(bi, hi, t, *rest):
+        *inner, iq, _, _ = rest
+        return bi, q_head(hi, *inner), iq[t], 0
+
+    def kv_map(bi, hi, t, *rest):
+        *inner, _, ik, _ = rest
+        return bi, kv_head(hi, *inner), ik[t], 0
+
+    def seg_q_map(bi, hi, t, *rest):
+        return bi, 0, rest[-3][t]
+
+    def seg_k_map(bi, hi, t, *rest):
+        return bi, 0, rest[-2][t]
+
+    return q_map, kv_map, seg_q_map, seg_k_map
+
+
+def _run_step(flags, kinds, compute):
+    """One grid step's work: ``compute(False)`` where the tables call the
+    step interior, ``compute(True)`` where an edge crosses it. The two
+    bodies differ in the positional mask alone; one the plan never asks for
+    is not traced."""
+    for kind, positional in ((INTERIOR, False), (EDGE, True)):
+        if kinds & kind:
+            pl.when((flags & kind) != 0)(
+                functools.partial(compute, positional)
+            )
+
+
+_plans_told = set()
+
+
+def _tell_plan(plan, **call):
+    """The ``flash_plan`` telemetry event: the plan's four counts with the
+    call's blocks and shape, once a traced shape and process — at trace
+    time, so nothing of it is in a step. A shape traced before any sink
+    listened is told when it is traced again."""
+    key = tuple(call.items())
+    if key in _plans_told:
+        return
+    from pyrecover_tpu import telemetry
+
+    if telemetry.emit("flash_plan", **plan.counts(), **call) is not None:
+        _plans_told.add(key)
+
+
 def _interpret():
     on = os.environ.get("PYRECOVER_PALLAS_INTERPRET", "0") == "1"
     backend = jax.default_backend()
@@ -86,16 +237,20 @@ def _interpret():
 
 
 def _score_mask(iq, ik, *, block_q, block_kv, causal, seq_q, seq_kv,
-                sq_ref, sk_ref, mask_q_bound):
+                sq_ref, sk_ref, mask_q_bound, positional):
     """(block_q, block_kv) boolean mask of VALID score positions, or None
     when statically every position in the block is valid. Folds together
     the causal boundary, the ragged sequence tails (when block size does
     not divide the length), and packed-sequence segment equality. The
     q-bound term is only needed where out-of-range q rows would CONTRIBUTE
     to an accumulation (the dk/dv kernel) — elsewhere their garbage stays
-    in rows whose stores Mosaic drops."""
+    in rows whose stores Mosaic drops. ``positional`` False is an interior
+    step's mask: the step tables vouch for every position's place, so no
+    iota and no compare is built and the segment equality alone is left."""
     conds = []
-    if causal or seq_kv % block_kv or (mask_q_bound and seq_q % block_q):
+    if positional and (
+        causal or seq_kv % block_kv or (mask_q_bound and seq_q % block_q)
+    ):
         qpos = iq * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_kv), 0
         )
@@ -136,35 +291,30 @@ def _zero_oob_rows(x, block_start, valid_len, block):
 # =========================== forward kernel ================================
 
 
-def _fwd_kernel(*args, scale, block_q, block_kv, causal, num_kv_blocks,
-                seq_q, seq_kv, has_segments):
+def _fwd_kernel(iq_tab, ik_tab, flag_tab, *args, scale, block_q, block_kv,
+                causal, seq_q, seq_kv, has_segments, kinds):
     if has_segments:
         (q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, lse_ref,
          m_scr, l_scr, acc_scr) = args
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = args
         sq_ref = sk_ref = None
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    t = pl.program_id(2)  # the folded (q block, kv block) pair axis
+    iq, ik, flags = iq_tab[t], ik_tab[t], flag_tab[t]
 
-    @pl.when(ik == 0)
+    @pl.when((flags & FIRST) != 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal: skip kv blocks strictly above the diagonal band
-    run = True
-    if causal:
-        run = ik * block_kv <= iq * block_q + block_q - 1
-
-    @pl.when(run)
-    def _compute():
+    def _compute(positional):
         q = q_ref[0, 0].astype(jnp.float32)  # (bq, d)
         k = k_ref[0, 0].astype(jnp.float32)  # (bk, d)
         v = v_ref[0, 0].astype(jnp.float32)  # (bk, d)
-        # v feeds the p·v contraction over kv rows: zero its ragged tail
-        v = _zero_oob_rows(v, ik * block_kv, seq_kv, block_kv)
+        if positional:
+            # v feeds the p·v contraction over kv rows: zero its ragged tail
+            v = _zero_oob_rows(v, ik * block_kv, seq_kv, block_kv)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -173,7 +323,7 @@ def _fwd_kernel(*args, scale, block_q, block_kv, causal, num_kv_blocks,
         mask = _score_mask(
             iq, ik, block_q=block_q, block_kv=block_kv, causal=causal,
             seq_q=seq_q, seq_kv=seq_kv, sq_ref=sq_ref, sk_ref=sk_ref,
-            mask_q_bound=False,
+            mask_q_bound=False, positional=positional,
         )
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
@@ -191,7 +341,9 @@ def _fwd_kernel(*args, scale, block_q, block_kv, causal, num_kv_blocks,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ik == num_kv_blocks - 1)
+    _run_step(flags, kinds, _compute)
+
+    @pl.when((flags & LAST) != 0)
     def _finalize():
         l = l_scr[:, :1]
         l_safe = jnp.where(l > 0.0, l, 1.0)
@@ -208,9 +360,11 @@ def _fwd(q, k, v, seg, *, causal, scale, block_q, block_kv):
     group = hq // hkv
     bq = min(block_q, s)
     bk = min(block_kv, sk)
-    nq = pl.cdiv(s, bq)
-    nk = pl.cdiv(sk, bk)
     has_segments = seg is not None
+    tables, kinds = _step_tables(
+        flash_plan(s, sk, bq, bk, causal), s, bq, pl.cdiv(sk, bk),
+        kv_major=False,
+    )
 
     # (b, h, s, d) layout for clean 2D blocks
     qt = q.transpose(0, 2, 1, 3)
@@ -219,15 +373,15 @@ def _fwd(q, k, v, seg, *, causal, scale, block_q, block_kv):
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, block_q=bq, block_kv=bk,
-        causal=causal, num_kv_blocks=nk, seq_q=s, seq_kv=sk,
-        has_segments=has_segments,
+        causal=causal, seq_q=s, seq_kv=sk,
+        has_segments=has_segments, kinds=kinds,
     )
+    q_map, kv_map, seg_q_map, seg_k_map = _index_maps(
+        lambda hi: hi, lambda hi: hi // group)
     in_specs = [
-        pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, bk, d),
-                     lambda bi, hi, qi, ki, g=group: (bi, hi // g, ki, 0)),
-        pl.BlockSpec((1, 1, bk, d),
-                     lambda bi, hi, qi, ki, g=group: (bi, hi // g, ki, 0)),
+        pl.BlockSpec((1, 1, bq, d), q_map),
+        pl.BlockSpec((1, 1, bk, d), kv_map),
+        pl.BlockSpec((1, 1, bk, d), kv_map),
     ]
     inputs = [qt, kt, vt]
     if has_segments:
@@ -237,39 +391,41 @@ def _fwd(q, k, v, seg, *, causal, scale, block_q, block_kv):
         # middle axis makes the trailing block dims (1, bq) legal.
         seg3 = seg.reshape(b, 1, seg.shape[1])
         in_specs += [
-            pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki: (bi, 0, qi)),
-            pl.BlockSpec((1, 1, bk), lambda bi, hi, qi, ki: (bi, 0, ki)),
+            pl.BlockSpec((1, 1, bq), seg_q_map),
+            pl.BlockSpec((1, 1, bk), seg_k_map),
         ]
         inputs += [seg3, seg3]
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b, hq, nq, nk),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, bq, LSE_LANES),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=(b, hq, len(tables[0])),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, bq, d), q_map),
+                pl.BlockSpec((1, 1, bq, LSE_LANES), q_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, d), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
             jax.ShapeDtypeStruct((b, hq, s, LSE_LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
         interpret=_interpret(),
         name="flash_fwd",
-    )(*inputs)
+    )(*tables, *inputs)
     return out.transpose(0, 2, 1, 3), lse
 
 
 # =========================== backward kernels ==============================
 
 
-def _bwd_dq_kernel(*args, scale, block_q, block_kv, causal, num_kv_blocks,
-                   seq_q, seq_kv, has_segments):
+def _bwd_dq_kernel(iq_tab, ik_tab, flag_tab, *args, scale, block_q, block_kv,
+                   causal, seq_q, seq_kv, has_segments, kinds):
     if has_segments:
         (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, sq_ref, sk_ref,
          dq_ref, acc_scr, delta_scr) = args
@@ -277,10 +433,10 @@ def _bwd_dq_kernel(*args, scale, block_q, block_kv, causal, num_kv_blocks,
         (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
          dq_ref, acc_scr, delta_scr) = args
         sq_ref = sk_ref = None
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    t = pl.program_id(2)  # the folded (q block, kv block) pair axis
+    iq, ik, flags = iq_tab[t], ik_tab[t], flag_tab[t]
 
-    @pl.when(ik == 0)
+    @pl.when((flags & FIRST) != 0)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
         # delta_i = rowsum(do·out): same for every kv block of this q block
@@ -290,20 +446,16 @@ def _bwd_dq_kernel(*args, scale, block_q, block_kv, causal, num_kv_blocks,
             jnp.sum(do * o, axis=-1, keepdims=True), delta_scr.shape
         )
 
-    run = True
-    if causal:
-        run = ik * block_kv <= iq * block_q + block_q - 1
-
-    @pl.when(run)
-    def _compute():
+    def _compute(positional):
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        # k and v feed contractions over kv rows (ds·k and do·v): zero
-        # their ragged tails so 0-probability NaN products can't leak in
-        k = _zero_oob_rows(k, ik * block_kv, seq_kv, block_kv)
-        v = _zero_oob_rows(v, ik * block_kv, seq_kv, block_kv)
+        if positional:
+            # k and v feed contractions over kv rows (ds·k and do·v): zero
+            # their ragged tails so 0-probability NaN products can't leak in
+            k = _zero_oob_rows(k, ik * block_kv, seq_kv, block_kv)
+            v = _zero_oob_rows(v, ik * block_kv, seq_kv, block_kv)
         lse = lse_ref[0, 0][:, :1]
         delta = delta_scr[:, :1]
 
@@ -313,7 +465,7 @@ def _bwd_dq_kernel(*args, scale, block_q, block_kv, causal, num_kv_blocks,
         mask = _score_mask(
             iq, ik, block_q=block_q, block_kv=block_kv, causal=causal,
             seq_q=seq_q, seq_kv=seq_kv, sq_ref=sq_ref, sk_ref=sk_ref,
-            mask_q_bound=False,
+            mask_q_bound=False, positional=positional,
         )
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
@@ -326,13 +478,15 @@ def _bwd_dq_kernel(*args, scale, block_q, block_kv, causal, num_kv_blocks,
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(ik == num_kv_blocks - 1)
+    _run_step(flags, kinds, _compute)
+
+    @pl.when((flags & LAST) != 0)
     def _finalize():
         dq_ref[0, 0] = acc_scr[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*args, scale, block_q, block_kv, causal, num_q_blocks,
-                    group, seq_q, seq_kv, has_segments):
+def _bwd_dkv_kernel(iq_tab, ik_tab, flag_tab, *args, scale, block_q, block_kv,
+                    causal, group, seq_q, seq_kv, has_segments, kinds):
     if has_segments:
         (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, sq_ref, sk_ref,
          dk_ref, dv_ref, dk_scr, dv_scr) = args
@@ -340,30 +494,28 @@ def _bwd_dkv_kernel(*args, scale, block_q, block_kv, causal, num_q_blocks,
         (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
          dk_ref, dv_ref, dk_scr, dv_scr) = args
         sq_ref = sk_ref = None
-    ik = pl.program_id(2)  # kv-major: kv block is the outer loop dim
-    t = pl.program_id(3)  # sweeps (q_block, group member): iq = t // group
-    iq = t // group
+    # kv-major: the folded pair axis walks a kv block's q blocks, and the
+    # innermost axis the members of the kv head's group inside each pair
+    t = pl.program_id(2)
+    member = pl.program_id(3)
+    iq, ik, flags = iq_tab[t], ik_tab[t], flag_tab[t]
 
-    @pl.when(t == 0)
+    @pl.when(((flags & FIRST) != 0) & (member == 0))
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    run = True
-    if causal:
-        run = ik * block_kv <= iq * block_q + block_q - 1
-
-    @pl.when(run)
-    def _compute():
+    def _compute(positional):
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
         o = o_ref[0, 0].astype(jnp.float32)
-        # q and do feed the dk/dv contractions over q rows: zero their
-        # ragged tails (a zeroed p alone cannot kill 0·NaN products)
-        q = _zero_oob_rows(q, iq * block_q, seq_q, block_q)
-        do = _zero_oob_rows(do, iq * block_q, seq_q, block_q)
+        if positional:
+            # q and do feed the dk/dv contractions over q rows: zero their
+            # ragged tails (a zeroed p alone cannot kill 0·NaN products)
+            q = _zero_oob_rows(q, iq * block_q, seq_q, block_q)
+            do = _zero_oob_rows(do, iq * block_q, seq_q, block_q)
         lse = lse_ref[0, 0][:, :1]
         delta = jnp.sum(do * o, axis=-1, keepdims=True)  # (bq, 1)
 
@@ -377,7 +529,7 @@ def _bwd_dkv_kernel(*args, scale, block_q, block_kv, causal, num_q_blocks,
         mask = _score_mask(
             iq, ik, block_q=block_q, block_kv=block_kv, causal=causal,
             seq_q=seq_q, seq_kv=seq_kv, sq_ref=sq_ref, sk_ref=sk_ref,
-            mask_q_bound=True,
+            mask_q_bound=True, positional=positional,
         )
         p = jnp.exp(s - lse)  # (bq, bk)
         if mask is not None:
@@ -395,7 +547,9 @@ def _bwd_dkv_kernel(*args, scale, block_q, block_kv, causal, num_q_blocks,
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(t == num_q_blocks * group - 1)
+    _run_step(flags, kinds, _compute)
+
+    @pl.when(((flags & LAST) != 0) & (member == group - 1))
     def _finalize():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
@@ -411,9 +565,8 @@ def _bwd(causal, scale, block_q, block_kv, res, g):
     group = hq // hkv
     bq = min(block_q, s)
     bk = min(block_kv, sk)
-    nq = pl.cdiv(s, bq)
-    nk = pl.cdiv(sk, bk)
     has_segments = seg is not None
+    plan = flash_plan(s, sk, bq, bk, causal)
 
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -421,94 +574,99 @@ def _bwd(causal, scale, block_q, block_kv, res, g):
     dot = do.transpose(0, 2, 1, 3)
     outt = out.transpose(0, 2, 1, 3)
 
+    tables, kinds = _step_tables(plan, s, bq, pl.cdiv(sk, bk), kv_major=False)
     dq_kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, block_q=bq, block_kv=bk,
-        causal=causal, num_kv_blocks=nk, seq_q=s, seq_kv=sk,
-        has_segments=has_segments,
+        causal=causal, seq_q=s, seq_kv=sk,
+        has_segments=has_segments, kinds=kinds,
     )
+    q_map, kv_map, seg_q_map, seg_k_map = _index_maps(
+        lambda hi: hi, lambda hi: hi // group)
     dq_in_specs = [
-        pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, bk, d),
-                     lambda bi, hi, qi, ki, g=group: (bi, hi // g, ki, 0)),
-        pl.BlockSpec((1, 1, bk, d),
-                     lambda bi, hi, qi, ki, g=group: (bi, hi // g, ki, 0)),
-        pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, bq, LSE_LANES),
-                     lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        pl.BlockSpec((1, 1, bq, d), q_map),
+        pl.BlockSpec((1, 1, bk, d), kv_map),
+        pl.BlockSpec((1, 1, bk, d), kv_map),
+        pl.BlockSpec((1, 1, bq, d), q_map),
+        pl.BlockSpec((1, 1, bq, d), q_map),
+        pl.BlockSpec((1, 1, bq, LSE_LANES), q_map),
     ]
     dq_inputs = [qt, kt, vt, dot, outt, lse]
     if has_segments:
         # (b, 1, s) for Mosaic block-shape legality — see _fwd
         seg3 = seg.reshape(b, 1, seg.shape[1])
         dq_in_specs += [
-            pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki: (bi, 0, qi)),
-            pl.BlockSpec((1, 1, bk), lambda bi, hi, qi, ki: (bi, 0, ki)),
+            pl.BlockSpec((1, 1, bq), seg_q_map),
+            pl.BlockSpec((1, 1, bk), seg_k_map),
         ]
         dq_inputs += [seg3, seg3]
     dq = pl.pallas_call(
         dq_kernel,
-        grid=(b, hq, nq, nk),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=(b, hq, len(tables[0])),
+            in_specs=dq_in_specs,
+            out_specs=pl.BlockSpec((1, 1, bq, d), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((bq, d), jnp.float32),
+                pltpu.VMEM((bq, LANES), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-        ],
         interpret=_interpret(),
         name="flash_dq",
-    )(*dq_inputs)
+    )(*tables, *dq_inputs)
 
-    # dk/dv: grid dim 3 sweeps (q_block × GQA group member) so the whole
-    # group's contribution accumulates in VMEM scratch and each output
-    # block is written once, directly at kv-head granularity — no
-    # (b, q_heads, s, d) f32 intermediates (2×2.1G at the 1B bench point)
+    # dk/dv: per kv block the grid sweeps (needed q block × GQA group
+    # member) so the whole group's contribution accumulates in VMEM scratch
+    # and each output block is written once, directly at kv-head
+    # granularity — no (b, q_heads, s, d) f32 intermediates (2×2.1G at the
+    # 1B bench point)
+    tables, kinds = _step_tables(plan, s, bq, pl.cdiv(sk, bk), kv_major=True)
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, block_q=bq, block_kv=bk,
-        causal=causal, num_q_blocks=nq, group=group, seq_q=s, seq_kv=sk,
-        has_segments=has_segments,
+        causal=causal, group=group, seq_q=s, seq_kv=sk,
+        has_segments=has_segments, kinds=kinds,
     )
-    qhead = lambda hi, t, g=group: hi * g + t % g  # noqa: E731
-    qblock = lambda t, g=group: t // g  # noqa: E731
+    # the grid's head is the kv head, its innermost axis the group member
+    q_map, kv_map, seg_q_map, seg_k_map = _index_maps(
+        lambda hi, m: hi * group + m, lambda hi, m: hi)
     dkv_in_specs = [
-        pl.BlockSpec((1, 1, bq, d),
-                     lambda bi, hi, ki, t: (bi, qhead(hi, t), qblock(t), 0)),
-        pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki, t: (bi, hi, ki, 0)),
-        pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki, t: (bi, hi, ki, 0)),
-        pl.BlockSpec((1, 1, bq, d),
-                     lambda bi, hi, ki, t: (bi, qhead(hi, t), qblock(t), 0)),
-        pl.BlockSpec((1, 1, bq, d),
-                     lambda bi, hi, ki, t: (bi, qhead(hi, t), qblock(t), 0)),
-        pl.BlockSpec((1, 1, bq, LSE_LANES),
-                     lambda bi, hi, ki, t: (bi, qhead(hi, t), qblock(t), 0)),
+        pl.BlockSpec((1, 1, bq, d), q_map),
+        pl.BlockSpec((1, 1, bk, d), kv_map),
+        pl.BlockSpec((1, 1, bk, d), kv_map),
+        pl.BlockSpec((1, 1, bq, d), q_map),
+        pl.BlockSpec((1, 1, bq, d), q_map),
+        pl.BlockSpec((1, 1, bq, LSE_LANES), q_map),
     ]
     dkv_inputs = [qt, kt, vt, dot, outt, lse]
     if has_segments:
         dkv_in_specs += [
-            pl.BlockSpec((1, 1, bq), lambda bi, hi, ki, t: (bi, 0, qblock(t))),
-            pl.BlockSpec((1, 1, bk), lambda bi, hi, ki, t: (bi, 0, ki)),
+            pl.BlockSpec((1, 1, bq), seg_q_map),
+            pl.BlockSpec((1, 1, bk), seg_k_map),
         ]
         dkv_inputs += [seg3, seg3]
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(b, hkv, nk, nq * group),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki, t: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bi, hi, ki, t: (bi, hi, ki, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=(b, hkv, len(tables[0]), group),
+            in_specs=dkv_in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, bk, d), kv_map),
+                pl.BlockSpec((1, 1, bk, d), kv_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, d), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, sk, d), k.dtype),
             jax.ShapeDtypeStruct((b, hkv, sk, d), v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
         interpret=_interpret(),
         name="flash_dkv",
-    )(*dkv_inputs)
+    )(*tables, *dkv_inputs)
 
     return (
         dq.transpose(0, 2, 1, 3),
@@ -631,6 +789,11 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
         segment_ids = segment_ids.astype(jnp.int32)
     bq = min(block_q, s)
     bk = min(block_kv, sk)
+    _tell_plan(
+        flash_plan(s, sk, bq, bk, causal), seq_q=s, seq_kv=sk, block_q=bq,
+        block_kv=bk, causal=bool(causal), batch=b, heads=hq, kv_heads=hkv,
+        head_dim=d, segments=segment_ids is not None,
+    )
 
     def local(q, k, v, seg):
         return _flash(q, k, v, seg, causal, scale, bq, bk, slim_lse)

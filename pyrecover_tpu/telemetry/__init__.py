@@ -103,6 +103,17 @@ Core event names across the stack (fields beyond the envelope):
                       the SC05 model's bytes per rung against the
                       compiler's limit for the device kind, how many
                       rungs the compiler refused, and its own peak)
+    flash_plan        steps_visited, steps_interior, steps_edge,
+                      steps_above, block_q, block_kv, seq_q, seq_kv,
+                      causal, batch, heads, kv_heads, head_dim, segments
+                      (once a traced shape, at TRACE time and never from
+                      the step loop: the (q block, kv block) pairs one
+                      (batch row, head) of the flash kernels walks —
+                      interior steps build no positional mask, edge
+                      steps (the diagonal, a ragged tail) run the masked
+                      body, pairs above the causal diagonal are neither
+                      fetched nor stepped; ops/flash_attention.py
+                      `flash_plan`)
     request_admitted  rid, prompt_tokens, max_new_tokens, blocks, slot,
                       queue_s (the serving scheduler admitted a request:
                       a decode slot plus its WHOLE KV-block footprint

@@ -12,7 +12,13 @@ import numpy as np
 import pytest
 
 from pyrecover_tpu.ops.attention import sdpa_attention
-from pyrecover_tpu.ops.flash_attention import flash_attention
+from pyrecover_tpu.ops.flash_attention import (
+    NEG_INF,
+    _bwd,
+    _fwd,
+    flash_attention,
+    flash_plan,
+)
 
 
 def make_qkv(b=1, s=256, hq=4, hkv=2, d=128, dtype=jnp.float32, seed=0):
@@ -24,9 +30,12 @@ def make_qkv(b=1, s=256, hq=4, hkv=2, d=128, dtype=jnp.float32, seed=0):
 
 
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("hq,hkv", [(2, 2), (4, 2)], ids=["mha", "gqa"])
-def test_forward_matches_sdpa(causal, hq, hkv):
-    q, k, v = make_qkv(hq=hq, hkv=hkv)
+@pytest.mark.parametrize(
+    "hq,hkv,s", [(2, 2, 256), (4, 2, 256), (20, 1, 512)],
+    ids=["mha", "gqa", "gqa20-multiblock"],
+)
+def test_forward_matches_sdpa(causal, hq, hkv, s):
+    q, k, v = make_qkv(hq=hq, hkv=hkv, s=s)
     out_flash = flash_attention(q, k, v, causal=causal, block_q=128, block_kv=128)
     out_ref = sdpa_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(
@@ -43,9 +52,245 @@ def test_multi_block_and_rectangular_blocks():
     )
 
 
+@pytest.mark.parametrize("args,want", [
+    # (seq_q, seq_kv, block_q, block_kv, causal) -> visited, interior, edge, above
+    ((4096, 4096, 1024, 1024, True), (10, 6, 4, 6)),    # the benchmark cells
+    ((512, 512, 128, 128, True), (10, 6, 4, 6)),        # the same geometry on the CPU
+    ((1024, 1024, 256, 512, True), (6, 2, 4, 2)),       # rectangular, kv wider
+    ((4096, 4096, 1024, 2048, True), (6, 2, 4, 2)),     # the v6e row's shape
+    ((1024, 1024, 512, 256, True), (6, 2, 4, 2)),       # rectangular, q wider
+    ((300, 300, 128, 128, True), (6, 3, 3, 3)),         # ragged: the kv tail is an edge
+    ((256, 512, 128, 128, True), (3, 1, 2, 5)),         # more keys than queries
+    ((512, 256, 128, 128, True), (7, 5, 2, 1)),         # more queries than keys
+    ((128, 128, 512, 512, True), (1, 0, 1, 0)),         # one block: blocks clamp to the length
+    ((4096, 4096, 1024, 1024, False), (16, 16, 0, 0)),  # non-causal: nothing above, nothing masked
+    ((300, 300, 128, 128, False), (9, 6, 3, 0)),        # non-causal ragged: the tail column alone
+    ((512, 256, 128, 128, False), (8, 8, 0, 0)),
+])
+def test_flash_plan(args, want):
+    """The pure counter the three wrappers build their grids from: pairs
+    above the diagonal are not visited at all, and of the visited ones only
+    those an edge crosses (the diagonal, a ragged kv tail) pay for a mask."""
+    plan = flash_plan(*args)
+    assert plan[:4] == want
+    assert plan.steps_visited == plan.steps_interior + plan.steps_edge
+    assert len(plan.pairs) == plan.steps_visited
+    # q-major, kv ascending inside a q block: the order of the accumulation
+    assert list(plan.pairs) == sorted(plan.pairs, key=lambda p: p[:2])
+    seq_q, seq_kv, bq, bk, causal = args
+    bq, bk = min(bq, seq_q), min(bk, seq_kv)
+    for iq, ik, interior in plan.pairs:
+        rows = range(iq * bq, min((iq + 1) * bq, seq_q))
+        cols = range(ik * bk, (ik + 1) * bk)
+        # a pair in the plan holds a valid position; an interior one only those
+        assert not causal or cols[0] <= rows[-1]
+        assert interior == (cols[-1] < seq_kv and (not causal or cols[-1] <= rows[0]))
+
+
+def test_step_tables_keep_the_order_and_write_every_block():
+    """The folded pair axis: ``flash_fwd`` / ``flash_dq`` walk the plan as it
+    stands, ``flash_dkv`` kv block outermost with its q blocks ascending;
+    FIRST / LAST fence each row's run; a tile on a ragged q tail is an edge
+    for dk/dv alone; a kv block no query needs still gets one step (its
+    zeros are written) that runs neither body."""
+    from pyrecover_tpu.ops.flash_attention import (
+        EDGE, FIRST, INTERIOR, LAST, _step_tables,
+    )
+
+    plan = flash_plan(512, 512, 128, 128, True)
+    (iq, ik, fl), kinds = _step_tables(plan, 512, 128, 4, kv_major=False)
+    assert list(zip(iq, ik)) == [p[:2] for p in plan.pairs]
+    assert [int(f) for f in fl[:3]] == [FIRST | LAST | EDGE, FIRST | INTERIOR, LAST | EDGE]
+    assert kinds == FIRST | LAST | INTERIOR | EDGE
+    (iq, ik, fl), _ = _step_tables(plan, 512, 128, 4, kv_major=True)
+    assert list(zip(ik, iq)) == sorted((p[1], p[0]) for p in plan.pairs)
+    assert [int(f) for f in fl[:4]] == [FIRST | EDGE, INTERIOR, INTERIOR, LAST | INTERIOR]
+    assert int(fl[-1]) == FIRST | LAST | EDGE
+    # non-causal over whole blocks: the masked body is never traced
+    _, kinds = _step_tables(
+        flash_plan(256, 256, 128, 128, False), 256, 128, 2, kv_major=False)
+    assert kinds & INTERIOR and not kinds & EDGE
+    # a ragged q tail (300 = 2 x 128 + 44): interior for dq, an edge for dk/dv
+    plan = flash_plan(300, 512, 128, 128, False)
+    (iq, _, fl), _ = _step_tables(plan, 300, 128, 4, kv_major=False)
+    assert all(f & INTERIOR for f in fl)
+    (iq, _, fl), _ = _step_tables(plan, 300, 128, 4, kv_major=True)
+    assert [bool(f & EDGE) for f in fl] == [q == 2 for q in iq]
+    # causal with more keys than queries: kv blocks 2 and 3 have no pair
+    plan = flash_plan(256, 512, 128, 128, True)
+    (iq, ik, fl), _ = _step_tables(plan, 256, 128, 4, kv_major=True)
+    assert list(ik) == [0, 0, 1, 2, 3]
+    assert [int(f) for f in fl[-2:]] == [FIRST | LAST, FIRST | LAST]
+
+
+# ---- bit-equality with a masked-everywhere blockwise oracle -----------------
+# The oracle visits every (q block, kv block) pair at or below the diagonal in
+# the kernels' order and masks EVERY one of them (what the kernels did before
+# they told interior steps from edges). A `where` whose mask is all true
+# returns its operand and a block never visited was never read, so the
+# kernels must agree with it to the last bit. Its steps are jitted so that XLA
+# contracts the same multiply-adds as in the interpreted kernel body.
+
+_F32 = jnp.float32
+
+
+def _dot(a, b, ca, cb):
+    return jax.lax.dot_general(
+        a, b, (((ca,), (cb,)), ((), ())), preferred_element_type=_F32)
+
+
+def _oracle_mask(iq, ik, sq, sk):
+    bq, bk = sq.shape[0], sk.shape[0]
+    qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    return (qpos >= kpos) & (sq.reshape(bq, 1) == sk.reshape(1, bk))
+
+
+@jax.jit
+def _oracle_fwd_step(carry, q, k, v, sq, sk, iq, ik, scale):
+    m, l, acc = carry
+    q, k, v = (x.astype(_F32) for x in (q, k, v))
+    s = jnp.where(_oracle_mask(iq, ik, sq, sk), _dot(q, k, 1, 1) * scale, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    l = l * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc = acc * corr + _dot(p, v, 1, 0)
+    return m_new, l, acc
+
+
+@jax.jit
+def _oracle_fwd_end(m, l, acc):
+    l_safe = jnp.where(l > 0.0, l, 1.0)
+    return acc / l_safe, (m + jnp.log(l_safe))[:, 0]
+
+
+@jax.jit
+def _oracle_delta(do, o):
+    return jnp.sum(do.astype(_F32) * o.astype(_F32), axis=-1, keepdims=True)
+
+
+@jax.jit
+def _oracle_dq_step(acc, q, k, v, do, lse, delta, sq, sk, iq, ik, scale):
+    q, k, v, do = (x.astype(_F32) for x in (q, k, v, do))
+    s = jnp.where(_oracle_mask(iq, ik, sq, sk), _dot(q, k, 1, 1) * scale, NEG_INF)
+    p = jnp.exp(s - lse.reshape(-1, 1))
+    ds = p * (_dot(do, v, 1, 1) - delta) * scale
+    return acc + _dot(ds, k, 1, 0)
+
+
+@jax.jit
+def _oracle_dkv_step(carry, q, k, v, do, o, lse, sq, sk, iq, ik, scale):
+    dk, dv = carry
+    q, k, v, do, o = (x.astype(_F32) for x in (q, k, v, do, o))
+    delta = jnp.sum(do * o, axis=-1, keepdims=True)
+    mask = _oracle_mask(iq, ik, sq, sk)
+    p = jnp.exp(_dot(q, k, 1, 1) * scale - lse.reshape(-1, 1))
+    p = jnp.where(mask, p, 0.0)
+    dv = dv + _dot(p, do, 0, 0)
+    ds = jnp.where(mask, p * (_dot(do, v, 1, 1) - delta) * scale, 0.0)
+    return dk + _dot(ds, q, 0, 0), dv
+
+
+def _masked_everywhere_oracle(q, k, v, seg, do, *, bq, bk, scale):
+    """out, lse, dq, dk, dv of causal attention over (1, s, h, d) operands,
+    one (head, q block, kv block) step at a time."""
+    _, s, hq, d = q.shape
+    hkv = k.shape[2]
+    group = hq // hkv
+    nq, nk = s // bq, s // bk
+    seg = jnp.zeros((s,), jnp.int32) if seg is None else seg[0]
+    needed = [(iq, ik) for iq in range(nq) for ik in range(nk)
+              if ik * bk <= iq * bq + bq - 1]
+
+    def tile(x, h, i, blk):
+        return x[0, i * blk:(i + 1) * blk, h]
+
+    def sg(i, blk):
+        return seg[i * blk:(i + 1) * blk]
+
+    out = np.zeros(q.shape, np.float32)
+    lse = np.zeros((hq, s), np.float32)
+    dq = np.zeros(q.shape, np.float32)
+    dk, dv = np.zeros(k.shape, np.float32), np.zeros(v.shape, np.float32)
+    for h in range(hq):
+        for iq in range(nq):
+            carry = (jnp.full((bq, 1), NEG_INF, _F32), jnp.zeros((bq, 1), _F32),
+                     jnp.zeros((bq, d), _F32))
+            for ik in [ik for i, ik in needed if i == iq]:
+                carry = _oracle_fwd_step(
+                    carry, tile(q, h, iq, bq), tile(k, h // group, ik, bk),
+                    tile(v, h // group, ik, bk), sg(iq, bq), sg(ik, bk),
+                    iq, ik, scale)
+            o, ls = _oracle_fwd_end(*carry)
+            out[0, iq * bq:(iq + 1) * bq, h] = o.astype(q.dtype)
+            lse[h, iq * bq:(iq + 1) * bq] = ls
+    o_j, lse_j = jnp.asarray(out).astype(q.dtype), jnp.asarray(lse)
+    for h in range(hq):
+        for iq in range(nq):
+            delta = _oracle_delta(tile(do, h, iq, bq), tile(o_j, h, iq, bq))
+            acc = jnp.zeros((bq, d), _F32)
+            for ik in [ik for i, ik in needed if i == iq]:
+                acc = _oracle_dq_step(
+                    acc, tile(q, h, iq, bq), tile(k, h // group, ik, bk),
+                    tile(v, h // group, ik, bk), tile(do, h, iq, bq),
+                    lse_j[h, iq * bq:(iq + 1) * bq], delta, sg(iq, bq),
+                    sg(ik, bk), iq, ik, scale)
+            dq[0, iq * bq:(iq + 1) * bq, h] = acc.astype(q.dtype)
+    for hk in range(hkv):
+        for ik in range(nk):
+            carry = (jnp.zeros((bk, d), _F32), jnp.zeros((bk, d), _F32))
+            # q blocks ascending, the group's members inside each
+            for iq in [iq for iq, i in needed if i == ik]:
+                for h in range(hk * group, (hk + 1) * group):
+                    carry = _oracle_dkv_step(
+                        carry, tile(q, h, iq, bq), tile(k, hk, ik, bk),
+                        tile(v, hk, ik, bk), tile(do, h, iq, bq),
+                        tile(o_j, h, iq, bq), lse_j[h, iq * bq:(iq + 1) * bq],
+                        sg(iq, bq), sg(ik, bk), iq, ik, scale)
+            dk[0, ik * bk:(ik + 1) * bk, hk] = carry[0].astype(k.dtype)
+            dv[0, ik * bk:(ik + 1) * bk, hk] = carry[1].astype(v.dtype)
+    return out, lse[None], dq, dk, dv
+
+
+@pytest.mark.parametrize("segments", [False, True], ids=["plain", "packed"])
+@pytest.mark.parametrize("hq,hkv", [(2, 2), (4, 1), (20, 1)],
+                         ids=["group1", "group4", "group20"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_bit_equal_to_the_masked_everywhere_oracle(dtype, hq, hkv, segments):
+    """Sequence 512 under 128 x 128 blocks walks 6 interior, 4 edge and 6
+    unvisited pairs a head: out, lse, dq, dk and dv equal, bit for bit, an
+    oracle that masks all ten visited pairs and takes them in the same
+    order. Skipping the mask where it is all true and the blocks above the
+    diagonal changes no rounding."""
+    s, d, bq, bk = 512, 64, 128, 128
+    assert flash_plan(s, s, bq, bk, True)[:4] == (10, 6, 4, 6)
+    ks = jax.random.split(jax.random.key(hq), 4)
+    q, do = (jax.random.normal(kk, (1, s, hq, d), dtype) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (1, s, hkv, d), dtype) for kk in ks[2:])
+    # documents that end inside blocks: interior steps keep the comparison
+    seg = jnp.asarray(np.concatenate(
+        [np.zeros(200), np.ones(150), np.full(162, 2)]
+    )[None].astype(np.int32)) if segments else None
+    scale = 1.0 / d**0.5
+    out, lse = _fwd(q, k, v, seg, causal=True, scale=scale,
+                    block_q=bq, block_kv=bk)
+    dq, dk, dv = _bwd(True, scale, bq, bk, (q, k, v, seg, out, lse), (do, None))
+    want = _masked_everywhere_oracle(q, k, v, seg, do, bq=bq, bk=bk, scale=scale)
+    # every lane of the kernel's lse block holds the row's one number
+    assert np.array_equal(np.asarray(lse), np.asarray(lse[..., :1] + 0 * lse))
+    for name, got, w in zip(("out", "lse", "dq", "dk", "dv"),
+                            (out, lse[..., 0], dq, dk, dv), want):
+        assert np.array_equal(np.asarray(got, np.float32), w), name
+
+
 @pytest.mark.parametrize("causal", [True, False])
-def test_gradients_match_sdpa(causal):
-    q, k, v = make_qkv(s=256, hq=4, hkv=2)
+@pytest.mark.parametrize(
+    "hq,hkv,s", [(4, 2, 256), (20, 1, 512)], ids=["gqa", "gqa20-multiblock"]
+)
+def test_gradients_match_sdpa(causal, hq, hkv, s):
+    q, k, v = make_qkv(s=s, hq=hq, hkv=hkv)
 
     def loss_flash(q, k, v):
         o = flash_attention(q, k, v, causal=causal, block_q=128, block_kv=128)

@@ -68,6 +68,51 @@ def test_remove_sink_stops_delivery():
     assert not telemetry.enabled()
 
 
+def test_flash_plan_event_once_a_traced_shape_never_from_the_loop(monkeypatch):
+    """The flash kernels' plan (grid steps visited / interior / edge / above
+    the diagonal, with the blocks and the shape) is told at TRACE time, once
+    a shape: the compiled step that runs in the loop emits nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from pyrecover_tpu.ops import flash_attention as fa
+
+    monkeypatch.setenv("PYRECOVER_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(fa, "_plans_told", set())
+
+    def attend(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, block_q=128,
+                                  block_kv=128)
+
+    q = jnp.ones((1, 512, 4, 64), jnp.float32)
+    k = v = jnp.ones((1, 512, 2, 64), jnp.float32)
+    # traced while no sink listens: nothing is lost, the next trace tells it
+    jax.jit(attend).lower(q, k, v)
+    assert fa._plans_told == set()
+
+    sink = telemetry.add_sink(telemetry.MemorySink())
+    step = jax.jit(jax.grad(lambda q, k, v: attend(q, k, v).sum(), (0, 1, 2)))
+    for _ in range(3):  # the loop: one trace, three runs of the program
+        jax.block_until_ready(step(q, k, v))
+    jax.jit(attend)(q, k, v)  # the same shape traced again
+    plans = [e for e in sink.events if e["event"] == "flash_plan"]
+    assert len(plans) == 1
+    e = plans[0]
+    assert [e[n] for n in ("steps_visited", "steps_interior", "steps_edge",
+                           "steps_above")] == [10, 6, 4, 6]
+    assert {n: e[n] for n in (
+        "seq_q", "seq_kv", "block_q", "block_kv", "causal", "batch", "heads",
+        "kv_heads", "head_dim", "segments",
+    )} == {"seq_q": 512, "seq_kv": 512, "block_q": 128, "block_kv": 128,
+           "causal": True, "batch": 1, "heads": 4, "kv_heads": 2,
+           "head_dim": 64, "segments": False}
+    # another shape is another event: one block, the diagonal in it
+    jax.jit(attend)(q[:, :128], k[:, :128], v[:, :128])
+    plans = [e for e in sink.events if e["event"] == "flash_plan"]
+    assert [p["steps_visited"] for p in plans] == [10, 1]
+    assert (plans[1]["steps_edge"], plans[1]["steps_above"]) == (1, 0)
+
+
 # ---- JSONL sink -------------------------------------------------------------
 
 
