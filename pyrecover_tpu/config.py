@@ -170,6 +170,7 @@ class TrainConfig:
     profile: bool = False
     profile_step_start: int = 10
     profile_step_end: int = 12
+    # where --profile writes; relative = under the experiment directory
     profile_dir: str = "profiles/"
 
     def __post_init__(self):
@@ -606,7 +607,9 @@ def build_parser():
     p.add_argument("--profile", action="store_true")
     p.add_argument("--profile-step-start", type=int, default=d.profile_step_start)
     p.add_argument("--profile-step-end", type=int, default=d.profile_step_end)
-    p.add_argument("--profile-dir", type=str, default=d.profile_dir)
+    p.add_argument("--profile-dir", type=str, default=d.profile_dir,
+                   help="where --profile writes its trace; a relative path "
+                        "lies under the experiment directory")
     return p
 
 

@@ -30,6 +30,15 @@ from jax.ad_checkpoint import checkpoint_name
 from pyrecover_tpu.ops.attention import sdpa_attention
 from pyrecover_tpu.ops.rope import apply_rope, precompute_rope
 from pyrecover_tpu.parallel.mesh import AXIS_DATA, AXIS_FSDP, AXIS_SEQ, AXIS_TENSOR, constrain
+from pyrecover_tpu.telemetry.stepscopes import (
+    ATTN,
+    EMBED,
+    FFN,
+    FLASH_ATTENTION,
+    LAYERS,
+    LOOP_PASS,
+    LOSS_HEAD,
+)
 from pyrecover_tpu.utils.dtypes import resolve_dtype
 from pyrecover_tpu.utils.remat import FLASH_LSE, checkpoint_policy, saved_names
 
@@ -406,12 +415,18 @@ def rms_norm(x, scale, eps):
     return (normed * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+def _flash_scoped(q, k, v, **kw):
+    """The flash kernels' call under its scope (the backward's two kernels
+    inherit it from the forward's equation)."""
+    from pyrecover_tpu.ops.flash_attention import flash_attention
+
+    with jax.named_scope(FLASH_ATTENTION):
+        return flash_attention(q, k, v, **kw)
+
+
 def _attention_fn(config):
     if config.attention_impl == "flash":
-        from pyrecover_tpu.ops.flash_attention import (
-            default_blocks,
-            flash_attention,
-        )
+        from pyrecover_tpu.ops.flash_attention import default_blocks
 
         bq, bk = config.flash_block_q, config.flash_block_kv
         if bq <= 0 or bk <= 0:
@@ -423,9 +438,7 @@ def _attention_fn(config):
         # the kernel's row statistics are kept slim (lane 0) where a remat
         # policy saves them; every other program is the one it was
         slim = config.remat and FLASH_LSE in saved_names(config)
-        return partial(
-            flash_attention, block_q=bq, block_kv=bk, slim_lse=slim
-        )
+        return partial(_flash_scoped, block_q=bq, block_kv=bk, slim_lse=slim)
     if config.attention_impl == "ring":
         from pyrecover_tpu.ops.ring_attention import ring_attention
 
@@ -465,23 +478,25 @@ def ffn_sublayer(x, layer, config):
     the training forward and the KV-cached decoder."""
     cfg = config
     cdt = resolve_dtype(cfg.compute_dtype)
-    h = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
-    if cfg.n_experts > 0:
-        from pyrecover_tpu.models.moe import moe_ffn
+    with jax.named_scope(FFN):
+        h = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
+        if cfg.n_experts > 0:
+            from pyrecover_tpu.models.moe import moe_ffn
 
-        y, aux = moe_ffn(
-            h, layer["router"], layer["moe_w1"], layer["moe_w3"],
-            layer["moe_w2"], cfg,
-        )
-        return x + y, aux
-    # the two products before the activation carry names a remat policy
-    # can keep (utils/remat.py); the MoE path has none
-    gate = jax.nn.silu(checkpoint_name(h @ layer["w1"].astype(cdt), "ffn_w1"))
-    up = checkpoint_name(h @ layer["w3"].astype(cdt), "ffn_w3")
-    y = (gate * up) @ layer["w2"].astype(cdt)
-    if cfg.post_norms:
-        y = rms_norm(y, layer["ffn_post_norm"], cfg.norm_eps)
-    return x + y, jnp.zeros((x.shape[0],), dtype=jnp.float32)
+            y, aux = moe_ffn(
+                h, layer["router"], layer["moe_w1"], layer["moe_w3"],
+                layer["moe_w2"], cfg,
+            )
+            return x + y, aux
+        # the two products before the activation carry names a remat policy
+        # can keep (utils/remat.py); the MoE path has none
+        gate = jax.nn.silu(
+            checkpoint_name(h @ layer["w1"].astype(cdt), "ffn_w1"))
+        up = checkpoint_name(h @ layer["w3"].astype(cdt), "ffn_w3")
+        y = (gate * up) @ layer["w2"].astype(cdt)
+        if cfg.post_norms:
+            y = rms_norm(y, layer["ffn_post_norm"], cfg.norm_eps)
+        return x + y, jnp.zeros((x.shape[0],), dtype=jnp.float32)
 
 
 def _block(x, layer, cos, sin, config, attn_fn, segment_ids=None):
@@ -496,27 +511,30 @@ def _block(x, layer, cos, sin, config, attn_fn, segment_ids=None):
     hd = cfg.head_dim
 
     # --- attention sublayer ---
-    h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q, k, v = qkv_proj(h, layer, cfg, cos, sin)
-    # named AFTER rope and the constraint: the very arrays that enter the
-    # attention call, so the kernel's residual tuple holds the named values
-    q = checkpoint_name(
-        constrain(q, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR, None),
-        "attn_q")
-    k = checkpoint_name(
-        constrain(k, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR, None),
-        "attn_k")
-    v = checkpoint_name(
-        constrain(v, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR, None),
-        "attn_v")
-    if segment_ids is None:
-        attn = attn_fn(q, k, v, causal=True)
-    else:
-        attn = attn_fn(q, k, v, causal=True, segment_ids=segment_ids)
-    attn = attn.reshape(b, s, cfg.n_heads * hd)
-    x = attn_residual(x, attn, layer, cfg)
-    x = checkpoint_name(
-        constrain(x, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, None), "attn_resid")
+    with jax.named_scope(ATTN):
+        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        q, k, v = qkv_proj(h, layer, cfg, cos, sin)
+        # named AFTER rope and the constraint: the very arrays that enter
+        # the attention call, so the kernel's residual tuple holds the
+        # named values
+        q = checkpoint_name(
+            constrain(q, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR, None),
+            "attn_q")
+        k = checkpoint_name(
+            constrain(k, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR, None),
+            "attn_k")
+        v = checkpoint_name(
+            constrain(v, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, AXIS_TENSOR, None),
+            "attn_v")
+        if segment_ids is None:
+            attn = attn_fn(q, k, v, causal=True)
+        else:
+            attn = attn_fn(q, k, v, causal=True, segment_ids=segment_ids)
+        attn = attn.reshape(b, s, cfg.n_heads * hd)
+        x = attn_residual(x, attn, layer, cfg)
+        x = checkpoint_name(
+            constrain(x, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, None),
+            "attn_resid")
 
     # --- FFN sublayer ---
     x, aux = ffn_sublayer(x, layer, cfg)
@@ -533,10 +551,10 @@ def _stack(params, tokens, config, segment_ids):
 
     cos = sin = None
     if cfg.rope:
-        cos, sin = precompute_rope(cfg.head_dim, seq_len, cfg.rope_theta)
+        with jax.named_scope(ATTN):
+            cos, sin = precompute_rope(cfg.head_dim, seq_len, cfg.rope_theta)
     attn_fn = _attention_fn(cfg)
 
-    x = params["tok_embed"].astype(cdt)[tokens]
     # Stage the post-gather reshard: the gather's natural output is
     # model-dim-sharded (the table is (None, tensor×fsdp)); jumping straight
     # to the batch/seq-sharded activation layout makes GSPMD emit its
@@ -545,8 +563,10 @@ def _stack(params, tokens, config, segment_ids):
     # transition into all-gather (dim) + local slice (batch/seq) — the same
     # bytes, proper collectives, no fallback. Cost: one B·S·D all-gather at
     # the model entry only.
-    x = constrain(x, None, None, None)
-    x = constrain(x, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, None)
+    with jax.named_scope(EMBED):
+        x = params["tok_embed"].astype(cdt)[tokens]
+        x = constrain(x, None, None, None)
+        x = constrain(x, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, None)
 
     block = partial(_block, cos=cos, sin=sin, config=cfg, attn_fn=attn_fn)
 
@@ -591,7 +611,12 @@ def _stack(params, tokens, config, segment_ids):
     }
     if segment_ids is not None:
         carry["seg"] = segment_ids.astype(jnp.int32)
-    return carry, partial(_run_periods, params["layers"], cfg, kinds)
+
+    def run_stack(carry):
+        with jax.named_scope(LAYERS):
+            return _run_periods(params["layers"], cfg, kinds, carry)
+
+    return carry, run_stack
 
 
 def _run_periods(layers, config, kinds, carry):
@@ -669,15 +694,18 @@ def forward_passes_with_aux(params, tokens, config, segment_ids=None):
     carry, run_stack = _stack(params, tokens, cfg, segment_ids)
 
     def one_pass(carry, _):
-        with jax.named_scope("loop_pass"):
+        with jax.named_scope(LOOP_PASS):
             carry = run_stack(carry)
             h = rms_norm(carry["x"], params["final_norm"], cfg.norm_eps)
             gate = exit_gate_logits(params, h, cfg) if cfg.exit_gate else None
         return dict(carry, x=h), (h, gate)
 
-    carry, (hiddens, gates) = jax.lax.scan(
-        one_pass, carry, None, length=cfg.loop_steps
-    )
+    # (the scan over passes is the stack's outer loop: what it stacks and
+    # carries is the layer scan's plumbing as much as the inner scan's)
+    with jax.named_scope(LAYERS):
+        carry, (hiddens, gates) = jax.lax.scan(
+            one_pass, carry, None, length=cfg.loop_steps
+        )
     return hiddens, gates, jnp.mean(carry["aux"])
 
 
@@ -700,7 +728,8 @@ def forward_hidden_with_aux(params, tokens, config, segment_ids=None):
         return hiddens[-1], aux
     carry, run_stack = _stack(params, tokens, cfg, segment_ids)
     carry = run_stack(carry)
-    hidden = rms_norm(carry["x"], params["final_norm"], cfg.norm_eps)
+    with jax.named_scope(LOSS_HEAD):
+        hidden = rms_norm(carry["x"], params["final_norm"], cfg.norm_eps)
     return hidden, jnp.mean(carry["aux"])
 
 

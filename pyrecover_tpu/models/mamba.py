@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from pyrecover_tpu.ops.selective_scan import causal_conv1d, selective_scan
+from pyrecover_tpu.telemetry.stepscopes import MAMBA_MIXER
 from pyrecover_tpu.utils.dtypes import resolve_dtype
 
 # the mixer's intermediates a remat policy may keep (utils/remat.py names
@@ -133,8 +134,8 @@ def mamba_block(x, layer, config):
         AXIS_DATA, AXIS_FSDP, AXIS_SEQ, constrain,
     )
 
-    h = rms_norm(x, layer["mixer_norm"], config.norm_eps)
-    with jax.named_scope("mamba_mixer"):
+    with jax.named_scope(MAMBA_MIXER):
+        h = rms_norm(x, layer["mixer_norm"], config.norm_eps)
         x = x + mamba_mixer(h, layer, config)
     x = constrain(x, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, None)
     x, aux = ffn_sublayer(x, layer, config)

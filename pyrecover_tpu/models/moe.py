@@ -68,6 +68,7 @@ from pyrecover_tpu.parallel.mesh import (
     AXIS_TENSOR,
     constrain,
 )
+from pyrecover_tpu.telemetry.stepscopes import MOE_FFN
 
 
 _warned_grouped_sp = False  # once-per-process guard for the sp>1 warning
@@ -141,6 +142,11 @@ def moe_ffn(h, router_w, w1, w3, w2, config):
       (y, aux): y (B, S, D) same dtype as h; aux (B,) f32 per-row
       load-balance loss (caller scales by ``moe_aux_weight``).
     """
+    with jax.named_scope(MOE_FFN):
+        return _moe_ffn_dispatch(h, router_w, w1, w3, w2, config)
+
+
+def _moe_ffn_dispatch(h, router_w, w1, w3, w2, config):
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is not None and not mesh.empty:
         from pyrecover_tpu.parallel.mesh import nonmanual_axes

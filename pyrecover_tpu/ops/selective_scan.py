@@ -39,6 +39,8 @@ import os
 import jax
 import jax.numpy as jnp
 
+from pyrecover_tpu.telemetry.stepscopes import SSM_SCAN
+
 IMPLS = ("auto", "xla", "pallas")
 # tokens held at once: the backward sweep keeps one state a chunk and
 # recomputes inside it. 128 / 256 / 512 read within 2 % on the v5e at
@@ -181,7 +183,7 @@ def selective_scan(u, dt, a, b, c, d_skip, *, chunk=None, impl="auto"):
             jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in (u, dt, b, c)
         )
     impl = resolve_impl(impl, u.shape[-1], a.shape[-1], chunk)
-    with jax.named_scope("ssm_scan"):
+    with jax.named_scope(SSM_SCAN):
         if impl == "pallas":
             # the kernels read a bfloat16 u at its own width and widen it
             wide = u if u.dtype == jnp.bfloat16 else u.astype(f32)

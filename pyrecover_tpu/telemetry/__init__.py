@@ -103,6 +103,25 @@ Core event names across the stack (fields beyond the envelope):
                       the SC05 model's bytes per rung against the
                       compiler's limit for the device kind, how many
                       rungs the compiler refused, and its own peak)
+    step_scopes       path, module, instructions, by_phase{}, by_scope{},
+                      unscoped, build_s (once per run, when the train
+                      step has compiled — before its first call, with
+                      --remat or without — and only while a sink is
+                      registered: stepscopes.py read the compiled
+                      module's metadata and wrote <exp_dir>/
+                      step_scopes.json, a table from instruction name to
+                      [phase, scopes, root opcode, product]; the event
+                      carries the file's path and COUNTS of instructions
+                      by phase (fwd / remat / bwd / update / none) and by
+                      sublayer, how many have neither, and the seconds
+                      the table took — never the table itself. A step
+                      wrapped in something that is no jitted function
+                      has no table and no event. The executable's
+                      metadata is that of the compile that MADE it: a
+                      persistent compile cache keyed without metadata
+                      can hand back an executable compiled before a
+                      scope was renamed — `unscoped` then reads high;
+                      clear the cache)
     flash_plan        steps_visited, steps_interior, steps_edge,
                       steps_above, block_q, block_kv, seq_q, seq_kv,
                       causal, batch, heads, kv_heads, head_dim, segments
@@ -270,6 +289,15 @@ own thread; Orbax's commit thread, which waits for it and for the writes,
 records a retroactive
 ``ckpt_write_background`` (engine) when it ends: its start to the commit.
 Each feeds a ``ckpt_sharded_<phase>_s`` histogram.
+
+Device-side scopes (``stepscopes.py``; README "Tracing & trace
+analysis"): the jitted step opens ``jax.named_scope``s from ONE vocabulary
+— sublayers ``embed``, ``attn``, ``ffn``, ``moe_ffn``, ``mamba_mixer``,
+``loss_head`` / ``exit_head_loss``, ``optimizer``; kernels
+``flash_attention``, ``ssm_scan``; groups ``layers``, ``loop_pass`` — which
+reach a profile only through the compiled module's metadata; the
+``step_scopes`` table is what joins a profile's operations to them
+(``tools/step_scopes.py``; the benchmark's ``step_*_ms`` metrics).
 
 Tracing + metrics events (``spans.py`` / ``metrics.py``; see README
 "Tracing & trace analysis" for the span catalog):

@@ -987,18 +987,19 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
                 grad_bucket_mb=config.grad_bucket_mb,
             )
 
-        if remat_decision is None:
-            step_fn = build_step(model_config)
-        else:
-            from pyrecover_tpu.utils.remat import CompiledOnce
+        from pyrecover_tpu.telemetry import stepscopes
+        from pyrecover_tpu.utils.remat import CompiledOnce
 
-            # compiled before its first call, one rung leaner where the
-            # compiler refuses the chosen one for memory
-            step_fn = CompiledOnce(
-                build_step, model_config, remat_decision,
-                on_ready=lambda decision: telemetry.emit(
-                    "remat_autosize", **decision.as_event()),
-            )
+        # compiled before its first call: under remat one rung leaner
+        # where the compiler refuses the chosen one for memory, and in
+        # every run the compiled step's operations by the program's own
+        # scopes, for a profile's reader (tools/step_scopes.py)
+        step_fn = CompiledOnce(
+            build_step, model_config, remat_decision,
+            on_ready=lambda decision: telemetry.emit(
+                "remat_autosize", **decision.as_event()),
+            scopes_path=exp_dir / stepscopes.FILE_NAME,
+        )
         if config.grad_bucket_mb > 0:
             # one host-side record of the overlap configuration: the
             # bucket layout the step was built to issue (the same
@@ -1160,11 +1161,15 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
                 ):
                     # span wraps the whole profiler window so the JSONL
                     # trace and the jax profile correlate on the timeline
+                    # a relative --profile-dir lies under the experiment
+                    # directory, beside the step_scopes.json that reads it
+                    # (tools/step_scopes.py); an absolute one is as given
+                    profile_dir = exp_dir / config.profile_dir
                     prof_span = telemetry.spans.begin(
-                        "jax_profile", dir=str(config.profile_dir),
+                        "jax_profile", dir=str(profile_dir),
                         start_step=step,
                     )
-                    jax.profiler.start_trace(config.profile_dir)
+                    jax.profiler.start_trace(str(profile_dir))
                     profiling = True
 
                 # fault seam: `sigterm_at_step N` delivers its signal as

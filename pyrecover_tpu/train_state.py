@@ -21,6 +21,12 @@ import jax.numpy as jnp
 import optax
 
 from pyrecover_tpu.models.llama import forward
+from pyrecover_tpu.telemetry.stepscopes import (
+    EMBED,
+    EXIT_HEAD_LOSS,
+    LOSS_HEAD,
+    OPTIMIZER,
+)
 
 IGNORE_INDEX = -100  # label mask value (reference dataset.py:50-55)
 
@@ -126,30 +132,32 @@ def chunked_ce_sum(params, hidden, labels, model_config, chunk_size):
     from pyrecover_tpu.models.llama import project_vocab
 
     b, s, d = hidden.shape
-    if chunk_size <= 0 or s % chunk_size or s == chunk_size:
-        logits = project_vocab(params, hidden, model_config)
-        return masked_ce_sum(logits, labels)
+    with jax.named_scope(LOSS_HEAD):
+        if chunk_size <= 0 or s % chunk_size or s == chunk_size:
+            logits = project_vocab(params, hidden, model_config)
+            return masked_ce_sum(logits, labels)
 
-    n = s // chunk_size
-    h_chunks = jnp.moveaxis(hidden.reshape(b, n, chunk_size, d), 1, 0)
-    l_chunks = jnp.moveaxis(labels.reshape(b, n, chunk_size), 1, 0)
+        n = s // chunk_size
+        h_chunks = jnp.moveaxis(hidden.reshape(b, n, chunk_size, d), 1, 0)
+        l_chunks = jnp.moveaxis(labels.reshape(b, n, chunk_size), 1, 0)
 
-    # remat per chunk: without it the scanned backward SAVES each chunk's
-    # f32 logits/logprobs — i.e. the full (b, s, vocab) cost the chunking
-    # exists to avoid (observed: +8G HBM at the 1B bench point). Recompute
-    # is one extra (chunk, d)x(d, vocab) matmul per chunk.
-    @jax.checkpoint
-    def per_chunk(args):
-        h, lab = args
-        logits = project_vocab(params, h, model_config)
-        valid = lab != IGNORE_INDEX
-        safe = jnp.where(valid, lab, 0)
-        logprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        ll = _token_logprob(logprobs, safe)
-        return -jnp.sum(jnp.where(valid, ll, 0.0)), jnp.sum(valid)
+        # remat per chunk: without it the scanned backward SAVES each
+        # chunk's f32 logits/logprobs — i.e. the full (b, s, vocab) cost
+        # the chunking exists to avoid (observed: +8G HBM at the 1B bench
+        # point). Recompute is one extra (chunk, d)x(d, vocab) matmul per
+        # chunk.
+        @jax.checkpoint
+        def per_chunk(args):
+            h, lab = args
+            logits = project_vocab(params, h, model_config)
+            valid = lab != IGNORE_INDEX
+            safe = jnp.where(valid, lab, 0)
+            logprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            ll = _token_logprob(logprobs, safe)
+            return -jnp.sum(jnp.where(valid, ll, 0.0)), jnp.sum(valid)
 
-    sums, counts = jax.lax.map(per_chunk, (h_chunks, l_chunks))
-    return jnp.sum(sums), jnp.sum(counts)
+        sums, counts = jax.lax.map(per_chunk, (h_chunks, l_chunks))
+        return jnp.sum(sums), jnp.sum(counts)
 
 
 def chunked_ce(params, hidden, labels, model_config, chunk_size):
@@ -205,7 +213,7 @@ def chunked_exit_loss(params, hiddens, gate_logits, labels, model_config,
     from pyrecover_tpu.models.llama import project_vocab
 
     T, b, s, d = hiddens.shape
-    with jax.named_scope("exit_head_loss"):
+    with jax.named_scope(EXIT_HEAD_LOSS):
         valid = labels != IGNORE_INDEX
         n_valid = jnp.sum(valid)
         p, log_p = exit_distribution(gate_logits)
@@ -343,10 +351,11 @@ def _pipelined_1f1b_value_and_grad(params, batch, model_config,
     # partitioner); the schedule hands the input-carry cotangents back and
     # the embedding vjp closes the chain here, under full-auto GSPMD.
     def embed_all(ep):
-        x = ep["tok_embed"].astype(cdt)[batch["inputs"]]
-        # same staged reshard waypoints as forward_hidden_with_aux
-        x = constrain(x, None, None, None)
-        x = constrain(x, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, None)
+        with jax.named_scope(EMBED):
+            x = ep["tok_embed"].astype(cdt)[batch["inputs"]]
+            # same staged reshard waypoints as forward_hidden_with_aux
+            x = constrain(x, None, None, None)
+            x = constrain(x, (AXIS_DATA, AXIS_FSDP), AXIS_SEQ, None)
         return {
             "x": x.reshape(M, B // M, seq_len, -1),
             "aux": jnp.zeros((M, B // M), jnp.float32),
@@ -363,7 +372,8 @@ def _pipelined_1f1b_value_and_grad(params, batch, model_config,
         block_fn = jax.checkpoint(block_fn, policy=checkpoint_policy(cfg))
 
     def head_fn(hp, carry, d):
-        hidden = rms_norm(carry["x"], hp["final_norm"], cfg.norm_eps)
+        with jax.named_scope(LOSS_HEAD):
+            hidden = rms_norm(carry["x"], hp["final_norm"], cfg.norm_eps)
         ce, n = chunked_ce(
             {"output": hp["output"]}, hidden, d["labels"], cfg,
             loss_chunk_size,
@@ -732,10 +742,12 @@ def make_train_step(model_config, optimizer, donate=True, loss_chunk_size=0,
             # quantizing (grad_error_feedback=False is the test-only
             # ablation knob proving the mechanism matters)
             use_feedback = res is not None and grad_error_feedback
-            if layout is None:
-                g_red, deficit = sync_whole(g, res, manual, use_feedback)
-            else:
-                g_red, deficit = sync_bucketed(g, res, manual, use_feedback)
+            with jax.named_scope(OPTIMIZER):
+                if layout is None:
+                    g_red, deficit = sync_whole(g, res, manual, use_feedback)
+                else:
+                    g_red, deficit = sync_bucketed(
+                        g, res, manual, use_feedback)
             if manual:
                 ce_sum = jax.lax.psum(ce_sum, AXIS_DATA)
                 n_valid = jax.lax.psum(n_valid, AXIS_DATA)
@@ -768,7 +780,7 @@ def make_train_step(model_config, optimizer, donate=True, loss_chunk_size=0,
             )
         return outs
 
-    def step_fn(state, batch):
+    def train_step(state, batch):
         from pyrecover_tpu.parallel.collectives import (
             param_leaf_order,
             resolve_bucket_layout,
@@ -881,14 +893,17 @@ def make_train_step(model_config, optimizer, donate=True, loss_chunk_size=0,
         # (optim.zero1_wrap, placed after global-norm clipping so the norm
         # reduction keeps the unsharded shape — the bit-exactness anchor);
         # nothing to do here beyond the wiring check in make_train_step
-        updates, new_opt_state = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        new_params = optax.apply_updates(state.params, updates)
-        grad_norm = optax.global_norm(grads)
-        new_rng = jax.random.key_data(
-            jax.random.fold_in(jax.random.wrap_key_data(state.rng), 1)
-        )
+        # (the scope holds the whole of the update: the gradient's norm
+        # and clip, the moments, the new weights, the rng's fold)
+        with jax.named_scope(OPTIMIZER):
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
+            grad_norm = optax.global_norm(grads)
+            new_rng = jax.random.key_data(
+                jax.random.fold_in(jax.random.wrap_key_data(state.rng), 1)
+            )
         new_state = TrainState(
             params=new_params,
             opt_state=new_opt_state,
@@ -911,7 +926,7 @@ def make_train_step(model_config, optimizer, donate=True, loss_chunk_size=0,
         return new_state, metrics
 
     donate_argnums = (0,) if donate else ()
-    return jax.jit(step_fn, donate_argnums=donate_argnums)
+    return jax.jit(train_step, donate_argnums=donate_argnums)
 
 
 def eval_loss_fn(model_config):

@@ -342,15 +342,22 @@ class CompiledOnce:
 
     The compile is the one the first call would have made (jit finds the
     executable again), so reading the compiler's peak costs nothing.
-    ``on_ready(decision)`` is called once, with the final decision.
+    ``on_ready(decision)`` is called once, with the final decision; a run
+    without remat has no decision (``None``), nothing to step down to and
+    no such call. While a telemetry sink is registered the compiled
+    step's table of scopes (``telemetry/stepscopes.py``) is written to
+    ``scopes_path`` and named by ONE ``step_scopes`` event.
     """
 
-    def __init__(self, build, model_config, decision, on_ready):
+    def __init__(self, build, model_config, decision, on_ready,
+                 scopes_path=None):
         self.build = build
         self.model_config = model_config
         self.decision = decision
         self.on_ready = on_ready
-        self.fn = build(decision.apply(model_config))
+        self.scopes_path = scopes_path
+        self.fn = build(
+            decision.apply(model_config) if decision else model_config)
         self._compiled = False
 
     def __getattr__(self, name):
@@ -360,7 +367,10 @@ class CompiledOnce:
         from pyrecover_tpu.utils.logging import log_host0
 
         # an explicit policy is the user's: refused, it fails in words
-        may_step_down = self.model_config.remat_policy == "auto"
+        may_step_down = (
+            self.decision is not None
+            and self.model_config.remat_policy == "auto"
+        )
         compiled = None
         # (a wrapper round the step that is no jitted function, as a test
         # plants, has nothing to compile ahead: its first call compiles)
@@ -383,6 +393,10 @@ class CompiledOnce:
                     refused, self.decision.rung, level=30,
                 )
                 self.fn = self.build(self.decision.apply(self.model_config))
+        if compiled is not None:
+            self._write_scopes(compiled)
+        if self.decision is None:
+            return
         peak = None
         if compiled is not None:
             analysis = compiled.memory_analysis()
@@ -391,6 +405,22 @@ class CompiledOnce:
             self.decision, compiled_peak_bytes=peak
         )
         self.on_ready(self.decision)
+
+    def _write_scopes(self, compiled):
+        from pyrecover_tpu import telemetry
+        from pyrecover_tpu.telemetry import stepscopes
+        from pyrecover_tpu.utils.logging import log_host0
+
+        if not (self.scopes_path and telemetry.enabled()
+                and jax.process_index() == 0):
+            return
+        try:
+            stepscopes.write(compiled, self.scopes_path)
+        except Exception as err:  # a profile reader's aid must not stop a run
+            log_host0(
+                "step_scopes: no table of the compiled step (%s: %s)",
+                type(err).__name__, err, level=30,
+            )
 
     def __call__(self, state, batch):
         if not self._compiled:
