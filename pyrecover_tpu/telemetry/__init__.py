@@ -133,6 +133,11 @@ Core event names across the stack (fields beyond the envelope):
                       body, pairs above the causal diagonal are neither
                       fetched nor stepped; ops/flash_attention.py
                       `flash_plan`)
+    rope_plan         form, heads, head_dim, seq, batch_dims (once a
+                      traced shape, at TRACE time and never from the step
+                      loop: which form rotated q or k — since PR 39
+                      `pair_swap_product`, the partner lane from one exact
+                      product with a constant permutation; ops/rope.py)
     request_admitted  rid, prompt_tokens, max_new_tokens, blocks, slot,
                       queue_s (the serving scheduler admitted a request:
                       a decode slot plus its WHOLE KV-block footprint

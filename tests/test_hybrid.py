@@ -398,16 +398,20 @@ def test_runner_walks_the_layers_in_stack_order(batch, period, offset):
 
 # ---- the plain decoder is what it was --------------------------------------------
 
-FORWARD_DIGESTS = {  # computed on the parent commit (PR 31): logits, gradients
+# logits: computed on the parent commit of PR 32 (PR 31). Gradients: since
+# PR 39, whose RoPE backward is the interleaved formula's arithmetic lane by
+# lane (tests/test_rope.py, op by op) but which XLA's CPU backend fuses into
+# one multiply-add rounding where the interleaved form's scatters kept two
+FORWARD_DIGESTS = {
     "dense": (
         "894387a677f91833e39ae22ed395c63ee4a171efd855eefda4a3048ec47c0f1c",
-        "72517d06e61bff3f0717684d1a235f81474ff94875135bb199ecd5c92afdf2ff"),
+        "2510333c5f2db1cadb5ffbda2a3096b118c0c7301cee900801d046dbaf9e4e30"),
     "dense_bf16_remat": (
         "f2c88a3d976b694970a0287218b837d58115771e1351d890246c1ec70d3dab44",
-        "e9559bf48ecd9dbcf5b70552da817f9daaea69720631bd7384212a69cad16eff"),
+        "5cc2d65416a3fbe66473951dd0a34c437ee2df03eaabfc9659f8b461046e7485"),
     "moe": (
         "c12f4459b81cfc8cdd73beba5f69030cb777a3b3293df4b870259d1881141495",
-        "03de0e1c5987791047b94075eed994576ef946d1fd3f4e9c3c689ceeaf565535"),
+        "c7ee5e62791fce0c9798655fcea73393ae383660a741d9e36f694312e0223dc6"),
 }
 
 
@@ -420,7 +424,8 @@ FORWARD_DIGESTS = {  # computed on the parent commit (PR 31): logits, gradients
 ])
 def test_homogeneous_stack_is_bit_for_bit_the_parents(name, kw):
     """A stack of one layer kind is the period of one: its logits and the
-    gradient of its loss, byte for byte what the parent commit computed."""
+    gradient of its loss, byte for byte what the commits named above
+    computed."""
     def digest(*arrays):
         h = hashlib.sha256()
         for a in arrays:
