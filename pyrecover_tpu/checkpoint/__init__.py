@@ -1,3 +1,13 @@
+"""The checkpoint engines and their shared naming.
+
+A lazy package (PEP 562): importing it loads ``registry`` alone, and each
+name below imports its engine module on first access, so a run loads
+Orbax, Flax and an engine's module only when it opens that engine
+(``engine.open_engine`` / ``engine_for_path``).
+"""
+
+import importlib
+
 from pyrecover_tpu.checkpoint.registry import (
     checkpoint_path,
     engine_of,
@@ -5,25 +15,24 @@ from pyrecover_tpu.checkpoint.registry import (
     list_checkpoints,
     prune_checkpoints,
 )
-from pyrecover_tpu.checkpoint.vanilla import load_ckpt_vanilla, save_ckpt_vanilla
-from pyrecover_tpu.checkpoint.sharded import (
-    ShardedCheckpointer,
-    load_ckpt_sharded,
-    precheck_ckpt_sharded,
-    save_ckpt_sharded,
-)
-from pyrecover_tpu.checkpoint.elastic import (
-    TopologyMismatchError,
-    compute_reshard_plan,
-    preflight_elastic,
-    read_saved_meta,
-    topologies_differ,
-)
-from pyrecover_tpu.checkpoint.zerostall import (
-    load_ckpt_zerostall,
-    precheck_ckpt_zerostall,
-    save_ckpt_zerostall,
-)
+
+# name -> the submodule that defines it, imported on first access
+_LAZY = {
+    "save_ckpt_vanilla": "vanilla",
+    "load_ckpt_vanilla": "vanilla",
+    "ShardedCheckpointer": "sharded",
+    "save_ckpt_sharded": "sharded",
+    "load_ckpt_sharded": "sharded",
+    "precheck_ckpt_sharded": "sharded",
+    "TopologyMismatchError": "elastic",
+    "compute_reshard_plan": "elastic",
+    "preflight_elastic": "elastic",
+    "read_saved_meta": "elastic",
+    "topologies_differ": "elastic",
+    "save_ckpt_zerostall": "zerostall",
+    "load_ckpt_zerostall": "zerostall",
+    "precheck_ckpt_zerostall": "zerostall",
+}
 
 __all__ = [
     "checkpoint_path",
@@ -31,18 +40,18 @@ __all__ = [
     "get_latest_checkpoint",
     "list_checkpoints",
     "prune_checkpoints",
-    "save_ckpt_vanilla",
-    "load_ckpt_vanilla",
-    "ShardedCheckpointer",
-    "save_ckpt_sharded",
-    "load_ckpt_sharded",
-    "precheck_ckpt_sharded",
-    "TopologyMismatchError",
-    "compute_reshard_plan",
-    "preflight_elastic",
-    "read_saved_meta",
-    "topologies_differ",
-    "save_ckpt_zerostall",
-    "load_ckpt_zerostall",
-    "precheck_ckpt_zerostall",
+    *_LAZY,
 ]
+
+
+def __getattr__(name):
+    if name not in _LAZY:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    module = importlib.import_module(f"{__name__}.{_LAZY[name]}")
+    value = getattr(module, name)
+    globals()[name] = value
+    return value
+
+
+def __dir__():
+    return sorted({*globals(), *__all__})

@@ -13,6 +13,7 @@ with, ``engine_for_path(path)`` for the engine that wrote a checkpoint.
 """
 
 import re
+import sys
 import time
 
 from pyrecover_tpu import telemetry
@@ -188,6 +189,17 @@ def _engine_class(name):
     from pyrecover_tpu.checkpoint.vanilla import VanillaEngine
 
     return VanillaEngine
+
+
+# the engines' modules and the libraries behind them, by import name
+ENGINE_MODULES = ("orbax.checkpoint", "flax") + tuple(
+    f"pyrecover_tpu.checkpoint.{name}" for name in ("elastic", *ENGINES))
+
+
+def loaded_engine_modules():
+    """The names of ``ENGINE_MODULES`` this process has imported, sorted:
+    none until a run opens an engine or reads a checkpoint."""
+    return sorted(m for m in ENGINE_MODULES if m in sys.modules)
 
 
 def open_engine(config):

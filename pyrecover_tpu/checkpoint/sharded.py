@@ -59,6 +59,8 @@ from pyrecover_tpu.checkpoint.engine import (
     nest_params,
 )
 from pyrecover_tpu.checkpoint.registry import prune_checkpoints
+# the digests' hash, imported with the engine: a save imports nothing
+from pyrecover_tpu.checkpoint.zerostall import chunkstore
 from pyrecover_tpu.resilience import faults
 from pyrecover_tpu.utils.logging import log_host0
 
@@ -199,9 +201,7 @@ def _host_leaf(leaf):  # jaxlint: host-only
 
 
 def _leaf_digests(host_leaves):  # jaxlint: host-only
-    from pyrecover_tpu.checkpoint.zerostall.chunkstore import leaf_digest
-
-    return {key: leaf_digest(arr) for key, arr in host_leaves}
+    return {key: chunkstore.leaf_digest(arr) for key, arr in host_leaves}
 
 
 class _MetaHandler(ocp.JsonCheckpointHandler):
@@ -489,8 +489,6 @@ class ShardedCheckpointer(CheckpointEngine):
         raw read detects NO tensor corruption of its own — measured: a
         flipped tensorstore byte loads silently) — a mismatch raises
         before any placement."""
-        from pyrecover_tpu.checkpoint.zerostall.chunkstore import leaf_digest
-
         path = Path(path)
         with ocp.Checkpointer(ocp.PyTreeCheckpointHandler()) as ckptr:
             tree = ckptr.restore(path / "state")
@@ -509,7 +507,7 @@ class ShardedCheckpointer(CheckpointEngine):
             key = PARAMS_PREFIX + jax.tree_util.keystr(p)
             arr = np.asarray(leaf)
             expected = digests.get(key)
-            if expected is not None and leaf_digest(arr) != expected:
+            if expected is not None and chunkstore.leaf_digest(arr) != expected:
                 raise CheckpointIntegrityError(
                     f"checkpoint {path.name}: leaf {key} fails its "
                     "recorded content digest — tensorstore file tampered "

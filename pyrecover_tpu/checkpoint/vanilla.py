@@ -38,7 +38,6 @@ from pathlib import Path
 
 import jax
 import numpy as np
-from flax.serialization import msgpack_restore
 
 from pyrecover_tpu import telemetry
 from pyrecover_tpu.checkpoint.engine import (
@@ -542,6 +541,8 @@ def _decode_ckpt_bytes(data, *, check_version=True):
         paths = meta.get("paths") or [f"leaf{i}" for i in range(len(leaves))]
         return meta, paths, leaves
     # legacy v1: flat msgpack of {"meta": json, "leaves": {i: array}}
+    from flax.serialization import msgpack_restore
+
     raw = msgpack_restore(data)
     meta = json.loads(raw["meta"])
     if check_version and meta["format"] not in SUPPORTED_FORMATS:

@@ -15,7 +15,12 @@ Core event names across the stack (fields beyond the envelope):
     run_start         devices, device_kind, processes, mesh, params_m,
                       loop_steps, layer_passes, mamba_layers, attn_layers,
                       ssm_state_elems, scan_chunk (a hybrid stack's: 0
-                      Mamba layers and no state in a plain decoder), ...
+                      Mamba layers and no state in a plain decoder),
+                      import_s (seconds from the process's start to the
+                      end of ``pyrecover_tpu.train``'s import; None where
+                      /proc is unreadable), ckpt_modules (Orbax, Flax and
+                      the engine modules loaded by then: an engine's come
+                      in at ``open_engine``), ...
     step_time         step, data_wait_s, dispatch_s
     train_sync        step, loss, steps, interval_s, iter_s, sync_s
     throughput        step, tokens_per_sec, mfu_pct, tflops, ...

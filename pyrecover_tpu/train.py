@@ -11,8 +11,10 @@ Run:  python -m pyrecover_tpu.train --training-steps 100 ...
 
 import contextlib
 import dataclasses
+import os
 import sys
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -21,8 +23,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from pyrecover_tpu import telemetry
 from pyrecover_tpu.telemetry import detectors
-from pyrecover_tpu.checkpoint import checkpoint_path, list_checkpoints
-from pyrecover_tpu.checkpoint.engine import open_engine
+from pyrecover_tpu.checkpoint.registry import checkpoint_path, list_checkpoints
+from pyrecover_tpu.checkpoint.engine import loaded_engine_modules, open_engine
 from pyrecover_tpu.config import TrainConfig, get_args
 from pyrecover_tpu.data import DataLoader, StatefulSampler, SyntheticTextDataset
 from pyrecover_tpu.metrics import LossCSVLogger, ThroughputMeter, WallTimeTotals
@@ -805,6 +807,10 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
         grad_accum_steps=config.grad_accumulation_steps,
         training_steps=config.training_steps,
         resume=resume_requested,
+        # what importing this module cost, and which checkpoint modules
+        # it had loaded by then (an engine's come in at open_engine)
+        import_s=IMPORT_S,
+        ckpt_modules=CKPT_MODULES,
     )
     # a declared accelerator ($PYRECOVER_EXPECT_ACCELERATOR) that resolved
     # to cpu is an error: platform_fallback event, then raise
@@ -1404,6 +1410,22 @@ def _train_impl(config, totals, t_entry, owned_sinks, status):
 def main(argv=None):
     config = get_args(argv)
     train(config)
+
+
+def _process_age():
+    """Seconds this process has lived (``/proc/self/stat``), or None."""
+    try:
+        stat = Path("/proc/self/stat").read_text()
+        ticks = int(stat.rsplit(")", 1)[1].split()[19])
+        up = float(Path("/proc/uptime").read_text().split()[0])
+    except (OSError, ValueError, IndexError):
+        return None
+    return round(max(up - ticks / os.sysconf("SC_CLK_TCK"), 0.0), 3)
+
+
+# the last statements of the module body: the instant its import ends
+IMPORT_S = _process_age()
+CKPT_MODULES = loaded_engine_modules()
 
 
 if __name__ == "__main__":
